@@ -9,8 +9,7 @@ JSON line.
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 python benchmarks/pipeline_bench.py
 
-Memory mode (VERDICT r3 task 5 — the pipeline's activation-memory
-accounting): ``BENCH_MODE=memory`` compiles the *train step* (grad through
+Memory mode (the pipeline's activation-memory accounting): ``BENCH_MODE=memory`` compiles the *train step* (grad through
 ``pipeline_apply`` over real transformer EncoderLayer stages) for each
 schedule — GPipe-ordered autodiff plain vs ``remat`` stage_fn, V=1 vs 2 —
 and reports **XLA's own per-device peak temp allocation**
@@ -259,7 +258,7 @@ def memory_mode():
     # temp at 2M against v2_remat's at M compares the two bubble-reduction
     # strategies (interleave chunks vs raise M under a flat-memory
     # schedule) at equal pipeline efficiency. This is the measured case
-    # for keeping V>1 on the scanned schedule only (VERDICT r4 task 6):
+    # for keeping V>1 on the scanned schedule only:
     # doubling M under 1F1B costs ~one extra cot_out buffer; interleaving
     # under scan-autodiff costs the whole tick-state save.
     mb2 = np.zeros((2 * M, B_mb, S, D), np.float32)

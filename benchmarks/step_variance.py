@@ -31,8 +31,8 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     # adag measures the fused bf16 delta-window wire (round-2 comparable);
     # aeasgd measures the round-4 delta-encoded elastic exchange
-    # (bit-identical bf16 mirrors both sides — VERDICT r4 task 4 asks for
-    # the async column to track the wire that actually changed).
+    # (bit-identical bf16 mirrors both sides — the async column tracks
+    # the wire that actually changed).
     ap.add_argument("--protocol", default="adag", choices=["adag", "aeasgd"])
     args = ap.parse_args()
 

@@ -15,8 +15,6 @@ kept in float32.
 
 from __future__ import annotations
 
-from distkeras_tpu.utils.platform import axis_size as _axis_size
-
 import functools
 
 import jax
@@ -215,7 +213,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
         # shard_map caller contiguous semantics on striped inputs.
         raise ValueError("stripe=True only changes causal masking; "
                          "non-causal rings are already balanced")
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     S_local = q.shape[1]
     scale = q.shape[-1] ** -0.5
@@ -265,9 +263,7 @@ def ring_self_attention(q, k, v, mesh, seq_axis: str = "sp",
     ``seq_axis`` and the batch over ``dp`` if present. ``stripe=True``
     expects inputs in the striped token layout
     (:func:`distkeras_tpu.ops.ring_flash.stripe_shard`)."""
-    from distkeras_tpu.utils.platform import get_shard_map
-
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     if stripe and not causal:
         raise ValueError("stripe=True only changes causal masking")

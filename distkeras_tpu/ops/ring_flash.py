@@ -24,9 +24,6 @@ passes.
 
 from __future__ import annotations
 
-from distkeras_tpu.utils.platform import axis_size as _axis_size
-from distkeras_tpu.utils.platform import pcast as _pcast
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -131,14 +128,14 @@ def _make_ring(axis_name, causal, block_q, interpret, stripe=False):
         return o
 
     def _ring_fwd_impl(q, k, v):
-        p = _axis_size(axis_name)
+        p = lax.axis_size(axis_name)
         my = lax.axis_index(axis_name)
         perm = [(i, (i + 1) % p) for i in range(p)]
         bh, s, d = q.shape
         o0 = jnp.zeros((bh, s, d), jnp.float32)
         lse0 = jnp.full((bh, s, 1), -jnp.inf, jnp.float32)
-        o0 = _pcast(o0, axis_name, to="varying")
-        lse0 = _pcast(lse0, axis_name, to="varying")
+        o0 = lax.pcast(o0, axis_name, to="varying")
+        lse0 = lax.pcast(lse0, axis_name, to="varying")
 
         def hop(carry, step):
             o, lse, k_cur, v_cur = carry
@@ -161,7 +158,7 @@ def _make_ring(axis_name, causal, block_q, interpret, stripe=False):
 
     def bwd(res, do):
         q, k, v, o, lse = res
-        p = _axis_size(axis_name)
+        p = lax.axis_size(axis_name)
         my = lax.axis_index(axis_name)
         perm = [(i, (i + 1) % p) for i in range(p)]
         delta = jnp.sum(
@@ -170,9 +167,9 @@ def _make_ring(axis_name, causal, block_q, interpret, stripe=False):
         dq0 = jnp.zeros_like(q, jnp.float32)
         dk0 = jnp.zeros_like(k, jnp.float32)
         dv0 = jnp.zeros_like(v, jnp.float32)
-        dq0 = _pcast(dq0, axis_name, to="varying")
-        dk0 = _pcast(dk0, axis_name, to="varying")
-        dv0 = _pcast(dv0, axis_name, to="varying")
+        dq0 = lax.pcast(dq0, axis_name, to="varying")
+        dk0 = lax.pcast(dk0, axis_name, to="varying")
+        dv0 = lax.pcast(dv0, axis_name, to="varying")
 
         def hop(carry, step):
             dq, dk_cur, dv_cur, k_cur, v_cur = carry
@@ -247,9 +244,7 @@ def ring_flash_attention(
     if stripe and not causal:
         raise ValueError("stripe=True only changes causal masking; "
                          "non-causal rings are already balanced")
-    from distkeras_tpu.utils.platform import get_shard_map
-
-    shard_map = get_shard_map()
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:

@@ -28,8 +28,6 @@ Short sequences / many heads → Ulysses; extreme context / few heads → ring.
 
 from __future__ import annotations
 
-from distkeras_tpu.utils.platform import axis_size as _axis_size
-
 import functools
 
 import jax
@@ -52,7 +50,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     local head group ``[B, S, H/p, D]``; defaults to
     :func:`dot_product_attention`.
     """
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % p != 0:
         raise ValueError(
@@ -84,9 +82,7 @@ def ulysses_self_attention(q, k, v, mesh, seq_axis: str = "sp",
     Mirrors :func:`distkeras_tpu.ops.attention.ring_self_attention` so the
     two strategies are drop-in interchangeable at the model layer.
     """
-    from distkeras_tpu.utils.platform import get_shard_map
-
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     from distkeras_tpu.ops.attention import sp_batch_spec
 

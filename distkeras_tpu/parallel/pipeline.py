@@ -47,9 +47,6 @@ near-flat in M — and which composes with MoE/ep since round 5.)
 
 from __future__ import annotations
 
-from distkeras_tpu.utils.platform import axis_size as _axis_size
-from distkeras_tpu.utils.platform import pcast as _pcast
-
 from functools import partial
 
 import jax
@@ -150,7 +147,7 @@ def _pipeline_local(
     path).
     """
     d = lax.axis_index(axis_name)
-    num_devices = _axis_size(axis_name)
+    num_devices = lax.axis_size(axis_name)
     V = virtual_stages
     M, B = microbatches.shape[0], microbatches.shape[1]
     feat_shape = microbatches.shape[2:]
@@ -167,13 +164,13 @@ def _pipeline_local(
     # over any axis the microbatches are sharded on (dp io sharding makes
     # the ingested state dp-varying too).
     zeros = jnp.zeros((B, *feat_shape), microbatches.dtype)
-    state = _pcast(zeros, (axis_name, *varying_axes), to="varying")
-    out_buf = _pcast(
+    state = lax.pcast(zeros, (axis_name, *varying_axes), to="varying")
+    out_buf = lax.pcast(
         jnp.zeros((M, B, *feat_shape), microbatches.dtype),
         (axis_name, *varying_axes),
         to="varying",
     )
-    aux_acc = _pcast(
+    aux_acc = lax.pcast(
         jnp.zeros((), jnp.float32), (axis_name, *varying_axes), to="varying"
     )
 
@@ -271,9 +268,7 @@ def pipeline_apply(
     Returns ``[M, B, ...]`` — the final stage's outputs (plus the aux sum
     when ``with_aux``). Differentiable end-to-end.
     """
-    from distkeras_tpu.utils.platform import get_shard_map
-
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     if io_spec is None:
         io_spec = _io_spec(mesh)

@@ -64,9 +64,6 @@ parallelism absent from the reference entirely).
 
 from __future__ import annotations
 
-from distkeras_tpu.utils.platform import axis_size as _axis_size
-from distkeras_tpu.utils.platform import pcast as _pcast
-
 from functools import partial
 
 import jax
@@ -108,7 +105,7 @@ def _1f1b_local(
     deadlock (the reason everything else is pcast varying below).
     """
     d = lax.axis_index(axis_name)
-    num_devices = _axis_size(axis_name)
+    num_devices = lax.axis_size(axis_name)
     M, B = microbatches.shape[0], microbatches.shape[1]
     feat = microbatches.shape[2:]
     dtype = microbatches.dtype
@@ -121,12 +118,9 @@ def _1f1b_local(
     bwd_perm = [(i, (i - 1) % Pd) for i in range(Pd)]
 
     def varying(x):
-        # Pre-VMA jax has no jax.typeof/vma tracking; _pcast is identity
-        # there, so "need everything" is both safe and correct.
-        typeof = getattr(jax, "typeof", None)
-        have = getattr(typeof(x), "vma", ()) if typeof is not None else ()
+        have = jax.typeof(x).vma
         need = tuple(a for a in all_axes if a not in have)
-        return _pcast(x, need, to="varying") if need else x
+        return lax.pcast(x, need, to="varying") if need else x
 
     # CRITICAL: the head params must be varying before any vjp touches
     # them. Taking a cotangent w.r.t. an axis-INVARIANT input makes JAX
@@ -208,7 +202,7 @@ def _1f1b_local(
 
         This removes P full-size hops per direction per step (all of the
         fill phase's cotangent traffic and the drain phase's activation
-        traffic — VERDICT r4 weak #5) and shrinks the fill/drain scan
+        traffic) and shrinks the fill/drain scan
         bodies to single-role conds.
         """
         assert enable_f or enable_b
@@ -398,7 +392,7 @@ def _1f1b_local(
     # caller's embedding vjp lands gradients on the same scale as the
     # stage/head grads (they stay dp-sharded like the inputs).
     for ax in varying_axes:
-        cot_out = cot_out / _axis_size(ax)
+        cot_out = cot_out / lax.axis_size(ax)
     stage_grads = jax.tree.map(lambda g: g[None], stage_grads)
     out = (loss,)
     if with_aux:
@@ -461,9 +455,7 @@ def pipeline_1f1b_value_and_grad(
     buffer); total residency adds one M-sized input-cotangent buffer —
     ``(min(P, M) + M)`` states, see the module docstring.
     """
-    from distkeras_tpu.utils.platform import get_shard_map
-
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     if io_spec is None:
         io_spec = P()

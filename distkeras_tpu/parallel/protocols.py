@@ -491,7 +491,7 @@ class AEASGDProtocol(AsyncProtocol):
             # lost, so the delta-mirror machinery (bf16 casts + mirror
             # advance on BOTH sides, dedupe replay state) is pure host CPU
             # with nothing to buy — measured 1.52x-vs-sync steady state
-            # against ADAG's 1.1-1.27x on loopback (BASELINE.md round 5).
+            # against ADAG's 1.1-1.27x on loopback (a CPU timing).
             # Ship the full-precision local tree with no worker_id; the PS
             # computes and applies the force and skips all per-worker
             # bookkeeping (`if wid is not None` in server_commit_pull).

@@ -104,7 +104,7 @@ def compiled_step_flops(step_fn, *args) -> float | None:
 
     This is the authoritative count for MFU: a hand-maintained
     ``Model.flops_per_example`` constant silently mis-reports the headline
-    metric when the model changes (VERDICT r1 weakness 6); the compiled
+    metric when the model changes; the compiled
     analysis counts what actually runs, including the backward pass and
     rematerialisation. With a persistent compile cache the extra
     ``lower().compile()`` is a cache hit, not a second real compile.

@@ -781,8 +781,8 @@ class AsynchronousDistributedTrainer(Trainer):
         self.compress_deltas = bool(compress_deltas)
         # Overlap the PS exchange with local compute: the window exchange
         # runs on a background thread while jitted steps continue, and the
-        # reply is rebased onto the advanced params (VERDICT r1 weakness 3 —
-        # the synchronous exchange made the async step 5.3x the sync step).
+        # reply is rebased onto the advanced params (the
+        # synchronous exchange made the async step 5.3x the sync step).
         self.overlap_window = bool(overlap_window)
         # "auto": keep a worker's partition resident in HBM (and gather
         # batches on device from index arrays) when it fits comfortably.
@@ -810,7 +810,7 @@ class AsynchronousDistributedTrainer(Trainer):
     def _device_cache_budget(self, device, state_bytes: int) -> int:
         """HBM bytes one worker may spend keeping its partition resident.
 
-        Derived from the device (VERDICT r3 task 4), not a constant:
+        Derived from the device, not a constant:
         ``memory_stats()['bytes_limit']`` minus three times the training
         state (the resident params + optimizer slots themselves, their
         gradients, and the donation ping-pong copy), minus a 25% headroom
@@ -1182,7 +1182,7 @@ class AsynchronousDistributedTrainer(Trainer):
                             # Partition lives in HBM whole; the scanned
                             # window gathers batches on device from [W, B]
                             # index arrays — no per-window host feature
-                            # traffic (NOTES_ROUND1 perf hypothesis).
+                            # traffic.
                             xcol = jax.device_put(
                                 np.ascontiguousarray(part[self.features_col]),
                                 batch_placement,

@@ -111,15 +111,9 @@ def pytree_mean(trees: list[Any]) -> Any:
 
 
 def _treedef_to_json(tree: Any) -> str:
-    # jax.tree.flatten_with_path is jax >= 0.4.34-ish; fall back to the
-    # long-stable jax.tree_util spelling (same signature) on older jax —
-    # same stance as utils/platform.get_shard_map.
-    flatten_with_path = getattr(
-        jax.tree, "flatten_with_path", None
-    ) or jax.tree_util.tree_flatten_with_path
     paths = [
         "/".join(_key_str(k) for k in path)
-        for path, _ in flatten_with_path(tree)[0]
+        for path, _ in jax.tree.flatten_with_path(tree)[0]
     ]
     return json.dumps(paths)
 

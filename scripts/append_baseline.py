@@ -1,22 +1,22 @@
 #!/usr/bin/env python
 """Append a measured bench result to BASELINE.md (idempotent).
 
-Called by the chip watcher (.tpu_watch.sh) the moment a bench artifact
-lands, so a chip-recovery window auto-converts into a recorded number with
-zero human/agent touches (VERDICT r3 task 1). Usage:
+Kept for its record loader, which tests/test_bench_tools.py covers; the
+`benchmark` PR that replaces bench.py decides whether any of it stays.
+Usage:
 
     python scripts/append_baseline.py <tag> <artifact.json>
     python scripts/append_baseline.py --check <artifact.json>
 
-The artifact is the bench child's stdout capture; its last JSON line is
-the canonical `{"metric": ..., "value": ..., "detail": {...}}` record
-(parsed with bench.py's own extractor, so the two cannot drift).
-``--check`` exits 0 iff the artifact holds a real measurement (parseable
-and not an ``infrastructure_failure`` fallback) — the watcher uses it to
-decide whether a model is genuinely warm. A row is appended at most once
-per identical (tag, metric, value, unit, mfu, device, detail) tuple;
-only the timestamp is excluded from the comparison, so re-runs with
-changed numbers (including kernel-report rows) always record.
+The artifact is a captured bench stdout; its last JSON line is the
+canonical `{"metric": ..., "value": ..., "detail": {...}}` record (parsed
+with bench.py's own extractor, so the two cannot drift). ``--check`` exits
+0 iff the artifact holds a real measurement (parseable and not an
+``infrastructure_failure`` line). A row is appended at most once per
+identical (tag, metric, value, unit, mfu, device, detail) tuple; only the
+timestamp is excluded from the comparison, so re-runs with changed numbers
+(including kernel-report rows) always record. BASELINE.md is created when
+it is not there.
 """
 
 from __future__ import annotations
@@ -32,11 +32,10 @@ sys.path.insert(0, HERE)
 from bench import _extract_json_line  # noqa: E402  (stdlib-only module)
 
 BASELINE = os.path.join(HERE, "BASELINE.md")
-SECTION = "## Measured results (auto-appended by the chip watcher)"
+SECTION = "## Measured results (appended by scripts/append_baseline.py)"
 HEADER = (
     "\n" + SECTION + "\n\n"
-    "Each row lands automatically when the watcher completes a bench run\n"
-    "(`scripts/append_baseline.py`); `infrastructure_failure` rows are\n"
+    "One row per recorded bench run; `infrastructure_failure` rows are\n"
     "excluded at the source.\n\n"
     "| When (UTC) | Tag | Metric | Value | Unit | MFU | Device | Detail |\n"
     "|---|---|---|---|---|---|---|---|\n"

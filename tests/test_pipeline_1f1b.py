@@ -241,29 +241,6 @@ def test_1f1b_dp_parity_with_gpipe():
         assert abs(a["loss"] - b["loss"]) < 2e-3, (a, b)
 
 
-def _legacy_shard_map() -> bool:
-    """True on jax versions before shard_map's promotion to jax.shard_map
-    (the utils.platform.get_shard_map fallback lane)."""
-    try:
-        from jax import shard_map  # noqa: F401
-
-        return False
-    except ImportError:
-        return True
-
-
-@pytest.mark.skipif(
-    _legacy_shard_map(),
-    reason="legacy (pre-jax.shard_map) replication checker cannot prove "
-           "ep-replication through the 1F1B engine's divergent tick "
-           "branches (the ep-psums sit inside lax.cond arms), so it "
-           "rejects the out_specs with a false-positive _SpecError; and "
-           "on that jax the checker also DRIVES the rep-aware transpose "
-           "rewrites, so check_rep=False runs to silently wrong expert "
-           "gradients (verified: loss/aux match sequential, grads do "
-           "not). No safe spelling exists before the VMA/pcast API — "
-           "the modern checker tracks varying axes through cond and "
-           "accepts this program as written.")
 def test_1f1b_ep_moe_engine_matches_sequential():
     """MoE/ep composition at the engine level (VERDICT r4 task 1): a toy
     manual-EP stage (local expert slab + psum over ep, differentiable aux)
