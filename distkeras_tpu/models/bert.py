@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from distkeras_tpu.models.core import Model
@@ -434,10 +435,14 @@ class EncoderLayer(nn.Module):
                 name="moe_mlp",
             )(y, train=train)
         else:
-            y = _dense(cfg.mlp_dim, ("embed", "mlp"), "mlp_in", cfg.dtype)(y)
-            y = nn.gelu(y)
-            y = _reduce_dense(cfg, cfg.hidden_size, ("mlp", "embed"),
-                              "mlp_out")(y)
+            # (The attention block is a module named "attention", which
+            # flax scopes by itself; the dense MLP is three calls.)
+            with jax.named_scope("mlp"):
+                y = _dense(cfg.mlp_dim, ("embed", "mlp"), "mlp_in",
+                           cfg.dtype)(y)
+                y = nn.gelu(y)
+                y = _reduce_dense(cfg, cfg.hidden_size, ("mlp", "embed"),
+                                  "mlp_out")(y)
         y = nn.Dropout(cfg.dropout_rate, deterministic=not train)(y)
         # Keep the residual stream in the compute dtype: the MoE block takes
         # the float32 LayerNorm output and would otherwise promote the whole
@@ -582,6 +587,7 @@ class Bert(nn.Module):
             return x
         return self._head(embed, x)
 
+    @jax.named_scope("head")
     def _head(self, embed, x):
         """Final LayerNorm + tied MLM head (the last pipeline stage's
         tail — and the whole model's, when unstaged)."""

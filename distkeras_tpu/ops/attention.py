@@ -74,6 +74,7 @@ def sp_batch_spec(mesh, seq_axis: str, batch_size: int):
     return P(batch_axis, seq_axis, None, None)
 
 
+@jax.named_scope("attention")
 def dot_product_attention(q, k, v, mask=None, causal: bool = False):
     """Standard attention. ``q/k/v: [B, S, H, D]`` -> ``[B, S, H, D]``.
 
@@ -135,6 +136,7 @@ def paged_kv_update(pool, new, tables, positions, page_tokens: int):
     return pool.at[rows, offs].set(new.astype(pool.dtype), mode="drop")
 
 
+@jax.named_scope("attention")
 def paged_attention(q, pool_k, pool_v, tables, positions):
     """Attention over paged (block-pooled) K/V: the serving engine's
     decode-slot read path when KV lives in a shared block pool instead of

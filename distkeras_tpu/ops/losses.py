@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from collections.abc import Callable
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -63,12 +64,15 @@ LOSSES: dict[str, LossFn] = {
 
 
 def get_loss(loss: str | LossFn) -> LossFn:
-    if callable(loss):
-        return loss
-    try:
-        return LOSSES[loss]
-    except KeyError:
-        raise ValueError(f"unknown loss {loss!r}; known: {sorted(LOSSES)}") from None
+    """The loss function, by name or as given, under the named scope
+    ``loss`` (metadata on its operations: what a device profile groups by;
+    the compiled program is the same)."""
+    if not callable(loss):
+        try:
+            loss = LOSSES[loss]
+        except KeyError:
+            raise ValueError(f"unknown loss {loss!r}; known: {sorted(LOSSES)}") from None
+    return jax.named_scope("loss")(loss)
 
 
 def get_optimizer(

@@ -151,7 +151,13 @@ Pipelined, all of that runs while the device executes the next tick:
 Per-tick ``serving_host_gap_seconds`` / ``serving_device_idle_ratio``
 (:class:`~distkeras_tpu.serving.metrics.HostGapTracker`) measure what
 the pipeline hides, and :meth:`ServingEngine.tick_timeline` keeps a
-bounded dispatch→harvest lane for tracez/debugz.
+bounded dispatch→harvest lane for tracez/debugz. What it does NOT hide
+is counted: every barrier that found a tick in flight adds to
+``serving_pipeline_barriers_total{reason}`` and is a ``barrier`` span
+(on the device's clock whenever a profiler session is live, see
+:mod:`distkeras_tpu.telemetry.spans`). A paged engine with many rows
+drains on almost every tick — some row crosses a block boundary — so
+its overlap is mostly lost to ``paged_growth`` (PERF.md, PR 25).
 """
 
 from __future__ import annotations
@@ -187,6 +193,7 @@ from distkeras_tpu.telemetry import (
     TimelineRecord,
     TraceStore,
     WideEventStore,
+    named_program,
     span,
 )
 from distkeras_tpu.serving.constraints import TokenDFA
@@ -1589,21 +1596,25 @@ class ServingEngine:
             if self._pp > 1:
                 fresh_jits = [
                     jax.jit(
-                        functools.partial(
-                            lambda shapes: jax.tree.map(
-                                lambda s: jnp.zeros(s.shape, s.dtype),
-                                shapes),
-                            part),
+                        named_program(
+                            functools.partial(
+                                lambda shapes: jax.tree.map(
+                                    lambda s: jnp.zeros(s.shape, s.dtype),
+                                    shapes),
+                                part),
+                            f"serving_fresh_row_s{s}"),
                         out_shardings=sh)
-                    for part, sh in zip(self._row_shapes,
-                                        self._row_shardings)]
+                    for s, (part, sh) in enumerate(zip(
+                        self._row_shapes, self._row_shardings))]
                 self._fresh_row_cache = (
                     lambda jits=fresh_jits: [f() for f in jits])
             else:
                 self._fresh_row_cache = jax.jit(
-                    lambda: jax.tree.map(
-                        lambda s: jnp.zeros(s.shape, s.dtype),
-                        self._row_shapes),
+                    named_program(
+                        lambda: jax.tree.map(
+                            lambda s: jnp.zeros(s.shape, s.dtype),
+                            self._row_shapes),
+                        "serving_fresh_row"),
                     **({} if mesh is None
                        else {"out_shardings": self._row_shardings}))
 
@@ -1670,9 +1681,11 @@ class ServingEngine:
                 jax.random.PRNGKey(0),
             )["cache"]
             self._fresh_draft_row = jax.jit(
-                lambda: jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype),
-                    self._draft_row_shapes),
+                named_program(
+                    lambda: jax.tree.map(
+                        lambda s: jnp.zeros(s.shape, s.dtype),
+                        self._draft_row_shapes),
+                    "serving_fresh_draft_row"),
                 **({} if mesh is None
                    else {"out_shardings": self._draft_rep}))
             # Host-side fed-token counts (int32 [slots], DENSE mode):
@@ -1708,7 +1721,11 @@ class ServingEngine:
         # across calls, so "exactly one executable per callable" survives
         # the mesh — and out_shardings guarantees the rebind-from-output
         # state (cache, tokens) never drifts off its layout.
-        def _sharded_jit(fn, in_sh, out_sh, donate):
+        # Every program is jitted under the name the auditor wraps it
+        # with below, so a profiler trace reads jit_serving_decode(<id>)
+        # where the audit report reads serving_decode.
+        def _sharded_jit(name, fn, in_sh, out_sh, donate):
+            fn = named_program(fn, name)
             if self.mesh is None:
                 return jax.jit(fn, donate_argnums=donate)
             return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
@@ -1721,10 +1738,12 @@ class ServingEngine:
             self._build_pp_programs(top_k, auditor)
         elif self._paged:
             self._prefill = _sharded_jit(
+                "serving_prefill",
                 functools.partial(_paged_prefill_fn, self._module, top_k),
                 (psh, csh, rep, rep, rep, rep, rep, rep), (csh, rep),
                 donate=(1,))
             self._admit_jit = _sharded_jit(
+                "serving_admit",
                 _paged_admit_fn,
                 (rep, rep, rep, rep, rep), (rep, rep), donate=(0, 1))
             if self._constrained_mode:
@@ -1733,12 +1752,14 @@ class ServingEngine:
                 # (all-zero rows for unconstrained slots), so mixed
                 # batches share it and compile-count==1 holds.
                 self._decode_step = _sharded_jit(
+                    "serving_decode",
                     functools.partial(_paged_decode_masked_fn,
                                       self._module, top_k, self._sentinel),
                     (psh, csh, rep, rep, rep, rep, rep, rep),
                     (csh, rep, rep), donate=(1,))
             else:
                 self._decode_step = _sharded_jit(
+                    "serving_decode",
                     functools.partial(_paged_decode_fn, self._module,
                                       top_k, self._sentinel),
                     (psh, csh, rep, rep, rep, rep, rep), (csh, rep, rep),
@@ -1750,16 +1771,20 @@ class ServingEngine:
             # epilogue. All are control-path (report-only audit): they
             # run once per admission, never per tick.
             self._prefill_logits = _sharded_jit(
+                "serving_prefill_logits",
                 functools.partial(_paged_prefill_logits_fn, self._module),
                 (psh, csh, rep, rep, rep, rep), (csh, rep), donate=(1,))
             self._fork_sample = _sharded_jit(
+                "serving_fork_sample",
                 functools.partial(_fork_sample_fn, top_k),
                 (rep, rep, rep), rep, donate=())
             self._score_chunk = _sharded_jit(
+                "serving_score_chunk",
                 functools.partial(_score_chunk_fn, self._module),
                 (psh, csh, rep, rep, rep, rep, rep), (csh, rep),
                 donate=(1,))
             self._embed_chunk = _sharded_jit(
+                "serving_embed_chunk",
                 functools.partial(_embed_chunk_fn, self._module),
                 (psh, csh, rep, rep, rep, rep), (csh, rep), donate=(1,))
             # Constrained-decoding mask state: host truth [slots, V]
@@ -1780,8 +1805,10 @@ class ServingEngine:
             # seam). Both run between ticks via the engine loop's
             # pending-op queue, so they can never race a donated cache.
             self._kv_gather = _sharded_jit(
+                "serving_kv_gather",
                 _kv_gather_fn, (csh, rep), rep, donate=())
             self._kv_scatter = _sharded_jit(
+                "serving_kv_scatter",
                 _kv_scatter_fn, (csh, rep, rep), csh, donate=(0,))
             # Pending export/import operations, serviced by the run
             # loop between iterations: (kind, arg, event, result).
@@ -1789,14 +1816,17 @@ class ServingEngine:
         else:
             rsh = self._row_shardings
             self._prefill = _sharded_jit(
+                "serving_prefill",
                 functools.partial(_prefill_fn, self._module, top_k),
                 (psh, rsh, rep, rep, rep, rep, rep), (rsh, rep),
                 donate=(1,))
             self._admit_jit = _sharded_jit(
+                "serving_admit",
                 _admit_fn,
                 (csh, rep, rep, rep, rsh, rep, rep), (csh, rep, rep),
                 donate=(0, 1, 2))
             self._decode_step = _sharded_jit(
+                "serving_decode",
                 functools.partial(_decode_fn, self._module, top_k),
                 (psh, csh, rep, rep, rep), (csh, rep), donate=(1,))
         if self._spec and self._pp == 1:
@@ -1805,11 +1835,13 @@ class ServingEngine:
             # like the fallback decode step it substitutes for. The
             # draft trio runs fully replicated on a sharded engine.
             self._draft_step = _sharded_jit(
+                "serving_draft",
                 functools.partial(_spec_draft_fn, self._draft_module,
                                   self.spec_k),
                 (rep, rep, rep, rep, rep), (rep, rep), donate=(1,))
             if self._paged:
                 self._verify_step = _sharded_jit(
+                    "serving_verify",
                     functools.partial(_paged_spec_verify_fn, self._module,
                                       top_k),
                     (psh, csh, rep, rep, rep, rep, rep, rep, rep, rep,
@@ -1817,14 +1849,17 @@ class ServingEngine:
                     (csh, rep, rep, rep), donate=(1, 2))
             else:
                 self._verify_step = _sharded_jit(
+                    "serving_verify",
                     functools.partial(_spec_verify_fn, self._module,
                                       top_k),
                     (psh, csh, rep, rep, rep, rep, rep, rep, rep),
                     (csh, rep, rep, rep), donate=(1, 2))
             self._draft_prefill = _sharded_jit(
+                "serving_draft_prefill",
                 functools.partial(_draft_prefill_fn, self._draft_module),
                 (rep, rep, rep, rep, rep), rep, donate=(1,))
             self._draft_admit = _sharded_jit(
+                "serving_draft_admit",
                 _draft_admit_fn, (rep, rep, rep), rep, donate=(0,))
 
         # Recompile auditing: the compile-count==1 decode invariant as a
@@ -1989,10 +2024,13 @@ class ServingEngine:
         hop = self._to_stage
 
         def sjit(fn, in_sh, out_sh, donate):
-            return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
-                           donate_argnums=donate)
+            # Jitted by wrap(), which has the program's name.
+            return lambda name: jax.jit(
+                named_program(fn, name), in_shardings=in_sh,
+                out_shardings=out_sh, donate_argnums=donate)
 
-        def wrap(fn, name):
+        def wrap(build, name):
+            fn = build(name)
             return fn if auditor is None else auditor.wrap(fn, name)
 
         if self._paged:
@@ -3379,7 +3417,7 @@ class ServingEngine:
                              or (st.request.deadline is not None
                                  and now > st.request.deadline))]
                 if dead:
-                    await self._pipeline_barrier(loop)
+                    await self._pipeline_barrier(loop, "cancel_expire")
                 for i in dead:
                     st = self._slot_state[i]
                     if st is None:
@@ -3419,7 +3457,7 @@ class ServingEngine:
                     # tick (the speculative tick dispatched before its
                     # rows' finishes were known): a swap waits for zero
                     # in-flight ticks, full stop.
-                    await self._pipeline_barrier(loop)
+                    await self._pipeline_barrier(loop, "param_swap")
                     params, ev, res, prov = self._pending_swap
                     self._pending_swap = None
                     if self.flight_recorder is not None:
@@ -3455,7 +3493,7 @@ class ServingEngine:
                     # Barrier: the export gather / import scatter must
                     # never interleave with a tick that is mid-flight
                     # over the same pool rows.
-                    await self._pipeline_barrier(loop)
+                    await self._pipeline_barrier(loop, "kv_transfer")
                     ops, self._pending_kv = self._pending_kv, []
                     for kind, arg, ev, res in ops:
                         with span("kv_transfer", kind=kind):
@@ -3494,7 +3532,7 @@ class ServingEngine:
                     # on the same check — so it must NOT drain the
                     # pipeline every iteration: that would pay the full
                     # host gap per tick for the whole parked period.
-                    await self._pipeline_barrier(loop)
+                    await self._pipeline_barrier(loop, "admission")
                 if not self._stopping:
                     while self.free_slots and len(self.scheduler):
                         if (self._paged and self._parked_at_version
@@ -3641,7 +3679,7 @@ class ServingEngine:
                         # phases therefore serialize with the decode
                         # tick exactly as before — the pipeline's win is
                         # the steady decode state between admissions.
-                        await self._pipeline_barrier(loop)
+                        await self._pipeline_barrier(loop, "prefill_chunk")
                         start = self._prefill_rr
                         i = min(pending,
                                 key=lambda s: (s - start) % self.slots)
@@ -3657,7 +3695,7 @@ class ServingEngine:
                 # tick whose every row finished leaves active == 0 with
                 # a garbage tick still pending) and wait.
                 if self.active_slots == 0:
-                    await self._pipeline_barrier(loop)
+                    await self._pipeline_barrier(loop, "idle")
                     if self._stopping:
                         break
                     if (self._paged and self._parked_req is not None
@@ -3679,15 +3717,16 @@ class ServingEngine:
                         # them immediately instead of them re-checking
                         # pool.version once per idle poll.
                         if self._tier_pending(self._parked_req):
-                            await self.scheduler.wait_for_kv_arrival(
-                                idle_poll_s)
+                            wait = self.scheduler.wait_for_kv_arrival
                         else:
-                            await self.scheduler.wait_for_wake(idle_poll_s)
+                            wait = self.scheduler.wait_for_wake
                     else:
-                        await self.scheduler.wait_for_request(idle_poll_s)
+                        wait = self.scheduler.wait_for_request
+                    with span("loop_idle"):
+                        await wait(idle_poll_s)
                     continue
                 if self._stopping and not self._draining:
-                    await self._pipeline_barrier(loop)
+                    await self._pipeline_barrier(loop, "shutdown")
                     for i, st in enumerate(self._slot_state):
                         if st is not None:
                             self._finish_error(st.request, EngineStopped(
@@ -3703,18 +3742,21 @@ class ServingEngine:
                 # the pool is dry. Host bookkeeping only; the decode
                 # step itself never changes shape. Growth mutates table
                 # rows (and may preempt = tear down): barrier first, but
-                # ONLY when some slot actually needs a block — the
-                # common tick crosses no block boundary and keeps the
-                # pipeline full.
-                if self._paged:
-                    if any(st is not None and st.prefill is None
-                           and self._needs_tail_block(i)
-                           for i, st in enumerate(self._slot_state)):
-                        await self._pipeline_barrier(loop)
-                    for i in range(self.slots):
-                        st = self._slot_state[i]
-                        if st is not None and st.prefill is None:
-                            self._ensure_tail_block(i)
+                # ONLY when some slot actually needs a block — a tick
+                # on which no row crosses a block boundary keeps the
+                # pipeline full (with many rows that is the rare tick:
+                # 64 rows on 16-token blocks leave 1.6 % of them, and
+                # serving_pipeline_barriers_total counts the rest).
+                if self._paged and any(
+                        st is not None and st.prefill is None
+                        and self._needs_tail_block(i)
+                        for i, st in enumerate(self._slot_state)):
+                    await self._pipeline_barrier(loop, "paged_growth")
+                    with span("paged_growth"):
+                        for i in range(self.slots):
+                            st = self._slot_state[i]
+                            if st is not None and st.prefill is None:
+                                self._ensure_tail_block(i)
                 # 6. One decode iteration for the whole batch — skipped
                 # while EVERY active slot is still mid-prefill (the whole
                 # tick's output would be discarded; the chunk in 4b was
@@ -3725,14 +3767,17 @@ class ServingEngine:
                 # the same batch commit their usual one token from the
                 # verify's position-0 logits. All-sampled batches (and
                 # the swap rewarm) take the one-token fallback step.
-                await self._tick_step(loop)
+                rows = await self._tick_step(loop)
                 if self.inject_decode_delay_s > 0:
                     # Injected fault (SLO bench): stretch the host side
                     # of every iteration so observed latencies genuinely
                     # breach — never a synthetic metric write.
                     await asyncio.sleep(self.inject_decode_delay_s)
                 self.metrics.sample(
-                    len(self.scheduler), self.active_slots, self.slots)
+                    len(self.scheduler), self.active_slots, self.slots,
+                    rows_decoded=rows)
+                if self._paged:
+                    self.kv_pool.note_decode_tick()
                 # Yield so the server can read sockets between iterations.
                 await asyncio.sleep(0)
         except BaseException as e:
@@ -3783,8 +3828,9 @@ class ServingEngine:
             self._running = False
 
     # -- decode pipeline ----------------------------------------------------
-    async def _tick_step(self, loop) -> None:
-        """One decode (or speculative) tick, pipelined. Plain → plain is
+    async def _tick_step(self, loop) -> int:
+        """One decode (or speculative) tick, pipelined; returns how many
+        rows the tick it dispatched decodes (0: none). Plain → plain is
         the fully overlapped path: dispatch tick N+1 FIRST, then harvest
         and stream tick N while N+1 executes — the host bookkeeping for
         N (token pushes, teardown, metrics) plus the whole next loop
@@ -3799,8 +3845,8 @@ class ServingEngine:
                 # Every dispatched row disappeared (cancel barrier tore
                 # them down before the harvest): flush so the stale
                 # handles don't pin device buffers.
-                await self._pipeline_barrier(loop)
-            return
+                await self._pipeline_barrier(loop, "idle")
+            return 0
 
         def want_spec() -> bool:
             # A zero-accept row (every draft rejected last spec tick)
@@ -3827,10 +3873,10 @@ class ServingEngine:
             # commits gate every later dispatch). Harvest, then
             # re-evaluate: the stream may have finished rows or flipped
             # the owe-fallback state.
-            await self._pipeline_barrier(loop)
+            await self._pipeline_barrier(loop, "spec")
             decodable = self._decodable()
             if not decodable:
-                return
+                return 0
             spec_tick = want_spec()
         if spec_tick:
             if self._paged:
@@ -3866,8 +3912,10 @@ class ServingEngine:
             # invariant.
             self._arm_after_warmup = False
             self.auditor.arm(*self._decode_audit_names)
+        rows = len(self._inflight[-1].rows)
         if self.pipeline_depth == 0:
-            await self._pipeline_barrier(loop)
+            await self._pipeline_barrier(loop, "depth0")
+        return rows
 
     async def _dispatch(self, loop, fn) -> _InflightTick:
         """Run one tick dispatch. The first ever goes to the executor
@@ -3882,16 +3930,22 @@ class ServingEngine:
         self._dispatch_warm = True
         return tick
 
-    async def _pipeline_barrier(self, loop) -> None:
+    async def _pipeline_barrier(self, loop, reason: str) -> None:
         """Drain the pipeline: harvest, stream, and tear down the
         in-flight tick (if any). Called before every event that mutates
         batch shape or content — admission, chunked-prefill progress,
         paged growth/preemption, param swap, KV transfer, cancel/expire
         teardown, idle, shutdown — and as the depth-0 serializer. Under
         depth>1 this drains ALL stages' in-flight micro-batch ticks,
-        oldest first."""
-        while self._inflight:
-            await self._complete_tick(loop, self._inflight.popleft())
+        oldest first. ``reason`` is one of ``BARRIER_REASONS``; a barrier
+        that found a tick to drain (the overlap was lost) is counted under
+        it and is a ``barrier`` span, one that found none costs nothing."""
+        if not self._inflight:
+            return
+        self.metrics.record_barrier(reason)
+        with span("barrier", reason=reason):
+            while self._inflight:
+                await self._complete_tick(loop, self._inflight.popleft())
 
     async def _complete_tick(self, loop, tick: _InflightTick) -> None:
         """Harvest one dispatched tick and do its host half: stream the
@@ -3906,21 +3960,19 @@ class ServingEngine:
         # and the executor round trip would cost more than the read.
         # Only a harvest that would genuinely BLOCK takes the thread
         # hop, keeping the event loop responsive through real waits.
+        harvest = (self._harvest_spec if tick.kind == "spec"
+                   else self._harvest_decode)
+        ready = _tick_ready(tick)
+        with span("harvest", kind=tick.kind, ready=ready):
+            got = (harvest(tick) if ready
+                   else await self._in_executor(loop, harvest, tick))
         if tick.kind == "spec":
-            if _tick_ready(tick):
-                out, commit, caps = self._harvest_spec(tick)
-            else:
-                out, commit, caps = await self._in_executor(
-                    loop, self._harvest_spec, tick)
+            out, commit, caps = got
             self._spec_owe_fallback = any(
                 int(commit[i]) == 0 for i in tick.rows
                 if self._slot_state[i] is not None)
         else:
-            if _tick_ready(tick):
-                nxt = self._harvest_decode(tick)
-            else:
-                nxt = await self._in_executor(
-                    loop, self._harvest_decode, tick)
+            nxt = got
             self._spec_owe_fallback = False
         t = time.monotonic()
         with span("stream", active=self.active_slots):

@@ -37,8 +37,16 @@ from distkeras_tpu.telemetry.registry import (
 )
 from distkeras_tpu.tracing import MetricStream
 
-__all__ = ["BubbleTracker", "HostGapTracker", "ServingMetrics",
-           "percentile"]
+__all__ = ["BARRIER_REASONS", "BubbleTracker", "HostGapTracker",
+           "ServingMetrics", "percentile"]
+
+# Why the engine drained its decode pipeline before a tick: the label of
+# ``serving_pipeline_barriers_total`` and the ``reason`` of a ``barrier``
+# span, one per kind of call site in the engine's loop.
+BARRIER_REASONS = (
+    "admission", "prefill_chunk", "paged_growth", "cancel_expire",
+    "param_swap", "kv_transfer", "spec", "idle", "depth0", "shutdown",
+)
 
 # Decode ticks and inter-token gaps sit well under the default buckets'
 # upper range; keep a finer low end for them.
@@ -265,6 +273,19 @@ class ServingMetrics:
             "serving_tokens_out_total", help="tokens streamed to clients")
         self._c_iterations = reg.counter(
             "serving_decode_iterations_total", help="decode loop iterations")
+        # Monotone totals, so that a window's delta over the iterations'
+        # delta is a mean: rows decoding per tick (over slots: occupancy),
+        # and ticks that first had to drain the pipeline, by reason.
+        self._c_slot_ticks = reg.counter(
+            "serving_slot_ticks_total",
+            help="rows decoded, summed over decode loop iterations")
+        self._c_barriers = {
+            reason: reg.counter(
+                "serving_pipeline_barriers_total",
+                help="pipeline barriers that found a tick in flight and "
+                     "drained it (the overlap with the next tick was lost)",
+                reason=reason)
+            for reason in BARRIER_REASONS}
         self._h = {
             "ttft": reg.histogram(
                 "serving_ttft_seconds", help="time to first token",
@@ -787,11 +808,18 @@ class ServingMetrics:
         records diff this around a request's lifetime)."""
         return self._iterations
 
+    def record_barrier(self, reason: str) -> None:
+        """One pipeline barrier that drained a tick (``BARRIER_REASONS``)."""
+        self._c_barriers[reason].inc()
+
     # -- per-iteration sampling --------------------------------------------
-    def sample(self, queue_depth: int, slots_active: int, slots_total: int) -> None:
-        """Call once per decode iteration; emits one stream record."""
+    def sample(self, queue_depth: int, slots_active: int, slots_total: int,
+               rows_decoded: int = 0) -> None:
+        """Call once per decode iteration; emits one stream record.
+        ``rows_decoded``: rows of the tick this iteration dispatched."""
         self._iterations += 1
         self._c_iterations.inc()
+        self._c_slot_ticks.inc(rows_decoded)
         occ = slots_active / max(1, slots_total)
         self._occupancy.append(occ)
         self._queue_depth.append(queue_depth)

@@ -70,6 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from distkeras_tpu.telemetry import named_program
+
 __all__ = ["KVBlockPool", "PrefixCache", "PrefixMatch"]
 
 
@@ -516,6 +518,11 @@ class PrefixCache(_BlockTrie):
                     jnp.zeros((self.capacity, self.block_tokens)
                               + a.shape[2:], a.dtype))
 
+        # Named for the profiler trace (a partial alone is jit__unknown).
+        store_fn = named_program(
+            functools.partial(_store_fn, self.block_tokens), "prefix_store")
+        splice_fn = named_program(
+            functools.partial(_splice_fn, self.block_tokens), "prefix_splice")
         if self._stages:
             from distkeras_tpu.parallel.sharding import kv_pytree_shardings
 
@@ -531,16 +538,15 @@ class PrefixCache(_BlockTrie):
             self._pool = [jax.device_put(p, sh)
                           for p, sh in zip(self._pool, pool_sh)]
             self._store = [
-                jax.jit(functools.partial(_store_fn, self.block_tokens),
-                        donate_argnums=(0,), out_shardings=sh)
+                jax.jit(store_fn, donate_argnums=(0,), out_shardings=sh)
                 for sh in pool_sh]
             self._splice = [
-                jax.jit(functools.partial(_splice_fn, self.block_tokens),
-                        donate_argnums=(0,), out_shardings=sh)
+                jax.jit(splice_fn, donate_argnums=(0,), out_shardings=sh)
                 for sh in row_sh]
             self._materialize = [
-                jax.jit(functools.partial(_materialize_fn, self.block_tokens,
-                                          shapes),
+                jax.jit(named_program(
+                    functools.partial(_materialize_fn, self.block_tokens,
+                                      shapes), "prefix_materialize"),
                         out_shardings=sh)
                 for shapes, sh in zip(self._row_shapes, row_sh)]
         else:
@@ -557,16 +563,17 @@ class PrefixCache(_BlockTrie):
                 row_sh = kv_pytree_shardings(mesh, self._row_shapes)
                 self._pool = jax.device_put(self._pool, pool_sh)
             self._store = jax.jit(
-                functools.partial(_store_fn, self.block_tokens),
-                donate_argnums=(0,),
+                store_fn, donate_argnums=(0,),
                 **({} if mesh is None else {"out_shardings": pool_sh}))
             self._splice = jax.jit(
-                functools.partial(_splice_fn, self.block_tokens),
+                splice_fn,
                 donate_argnums=(0,),  # the cache being built; pool persists
                 **({} if mesh is None else {"out_shardings": row_sh}))
             self._materialize = jax.jit(
-                functools.partial(_materialize_fn, self.block_tokens,
-                                  self._row_shapes),
+                named_program(
+                    functools.partial(_materialize_fn, self.block_tokens,
+                                      self._row_shapes),
+                    "prefix_materialize"),
                 **({} if mesh is None else {"out_shardings": row_sh}))
         if registry is not None:
             self._metrics = _register_trie_metrics(registry)
@@ -785,6 +792,13 @@ class KVBlockPool(_BlockTrie):
                 "free": registry.gauge(
                     "kv_pool_blocks_free", help="KV blocks on the free "
                                                 "list"),
+                # A total and not a gauge: its delta over a window, over
+                # the decode iterations' delta and the capacity, is the
+                # pool's mean fill while the window decoded.
+                "block_ticks": registry.counter(
+                    "kv_pool_block_ticks_total",
+                    help="KV blocks in use, summed over decode loop "
+                         "iterations"),
             }
             self._g_pool["total"].set(self.capacity)
             self._note_occupancy()
@@ -988,6 +1002,12 @@ class KVBlockPool(_BlockTrie):
                 self._metrics["inserts"].inc(len(uploads))
                 self._note_occupancy()
         return uploads, resident
+
+    def note_decode_tick(self) -> None:
+        """Once per decode loop iteration (the engine's): add the blocks
+        in use to ``kv_pool_block_ticks_total``."""
+        if self._g_pool is not None:
+            self._g_pool["block_ticks"].inc(self.blocks_used)
 
     def _note_occupancy(self) -> None:
         if self._metrics is not None:

@@ -57,6 +57,7 @@ from distkeras_tpu.telemetry.recompile import (
     RecompileAuditor,
     RecompileError,
     abstract_signature,
+    named_program,
 )
 from distkeras_tpu.telemetry.registry import (
     DEFAULT_BUCKETS,
@@ -118,6 +119,7 @@ __all__ = [
     "RecompileError",
     "CompileEvent",
     "abstract_signature",
+    "named_program",
     "MetricsRegistry",
     "Counter",
     "Gauge",

@@ -30,6 +30,7 @@ armed auditor is cheap enough to leave on in production serving.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Any, Callable
 
@@ -38,7 +39,20 @@ __all__ = [
     "RecompileError",
     "CompileEvent",
     "abstract_signature",
+    "named_program",
 ]
+
+
+def named_program(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, for ``jax.jit``: the compiled program is then
+    ``jit_<name>`` in a profiler trace and in the HLO. JAX names a program
+    after its function's ``__name__``, and a ``functools.partial`` has
+    none (``jit__unknown``): give a program the name it is wrapped with in
+    :meth:`RecompileAuditor.wrap`, so the audit report and the trace agree.
+    ``fn`` itself is left as it is (a module-level function is shared)."""
+    fn = functools.partial(fn)
+    fn.__name__ = name
+    return fn
 
 
 class RecompileError(RuntimeError):

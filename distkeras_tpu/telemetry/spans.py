@@ -1,18 +1,28 @@
-"""Hierarchical wall-clock spans with Chrome-trace export.
+"""Hierarchical wall-clock spans with Chrome-trace export, also written
+into any live ``jax.profiler`` session.
 
 ``jax.profiler`` traces the XLA timeline; these spans trace the *host*
 timeline — where a training step or serving iteration spends its wall
 clock between device dispatches (data load, h2d transfer, admission,
 prefill, checkpoint writes). The two views are complementary: the
-profiler shows what the chip did, spans show why the chip waited.
+profiler shows what the chip did, spans show why the chip waited. While
+a profiler session is live (``--profile-out``, or anyone's
+``jax.profiler.start_trace``) every span is also a
+``jax.profiler.TraceAnnotation`` named ``dk:<name>`` (:data:`PREFIX`)
+with its attributes as metadata: it lands on the ``/host:CPU`` plane of
+the ``.xplane.pb``, on the clock the device plane uses, so an idle gap
+of the chip can be read against what the program was doing in it. The
+program is not told that a session started; ``span()`` asks the profiler
+(``TraceAnnotation.is_enabled()``).
 
 Design constraints, in priority order:
 
 1. **Near-zero overhead when disabled.** ``span("name")`` with no active
-   tracer is one module-global read plus returning a shared no-op context
-   manager — no allocation, no clock read. Hot paths (the serving decode
-   loop, per-step trainer loops) keep their ``with span(...)`` lines
-   unconditionally.
+   tracer and no profiler session is one module-global read, one call
+   into the profiler's "is a session live" flag, and returning a shared
+   no-op context manager — no allocation, no clock read. Hot paths (the
+   serving decode loop, per-step trainer loops) keep their
+   ``with span(...)`` lines unconditionally.
 2. **Correct nesting across threads AND asyncio tasks.** Parent tracking
    uses a :class:`contextvars.ContextVar`, which asyncio snapshots per
    task and threading isolates per thread — a span opened inside a task
@@ -37,6 +47,7 @@ import time
 from typing import Any
 
 __all__ = [
+    "PREFIX",
     "Tracer",
     "span",
     "enable_tracing",
@@ -58,6 +69,33 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+# What every span's name starts with in a profiler trace: a reader selects
+# the program's spans by it, without a list of names.
+PREFIX = "dk:"
+
+# jax.profiler.TraceAnnotation, imported by the first span() call.
+_ANNOTATION = None
+
+
+class _BothSpans:
+    """A span recorded by the tracer and the profiler session at once."""
+
+    __slots__ = ("_span", "_annotation")
+
+    def __init__(self, span_, annotation):
+        self._span = span_
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        return False
 
 # The active span in the CURRENT logical context (task- and thread-local).
 _CURRENT: contextvars.ContextVar["_Span | None"] = contextvars.ContextVar(
@@ -236,10 +274,20 @@ def active_tracer() -> Tracer | None:
 
 
 def span(name: str, **attrs: Any):
-    """Context manager marking one timed region, parented to the
-    enclosing span of the current task/thread. A no-op singleton when
-    tracing is disabled — safe to leave on every hot path."""
+    """Context manager marking one timed region: recorded by the active
+    tracer (parented to the enclosing span of the current task/thread),
+    written to the live profiler session as ``dk:<name>``, or both. A
+    no-op singleton when there is neither — safe to leave on every hot
+    path. The profiler's event starts where this is called, so call it in
+    the ``with`` statement itself."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
     t = _ACTIVE
-    if t is None:
-        return _NULL_SPAN
-    return t.span(name, **attrs)
+    if not _ANNOTATION.is_enabled():
+        return _NULL_SPAN if t is None else t.span(name, **attrs)
+    annotation = _ANNOTATION(PREFIX + name, **attrs)
+    return annotation if t is None else _BothSpans(
+        t.span(name, **attrs), annotation)

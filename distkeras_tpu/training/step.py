@@ -183,8 +183,10 @@ def make_train_step(
             if "accuracy" in metrics:
                 out_metrics["accuracy"] = acc_sum / accum
 
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             params=new_params,
             model_state=new_model_state if new_model_state else state.model_state,

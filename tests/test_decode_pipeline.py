@@ -314,3 +314,101 @@ def test_tick_timeline_and_debugz_surface(tiny_lm):
     lane_txt = format_tracez({"recent": [], "records": 0,
                               "ticks": lane[-5:]})
     assert "tick lane" in lane_txt
+
+
+def _programs(engine):
+    """The engine's jitted programs, by attribute."""
+    return {k: f for k, f in vars(engine).items()
+            if callable(f) and hasattr(f, "lower")}
+
+
+def test_every_program_of_a_paged_engine_is_named(tiny_lm):
+    """A profiler trace tells programs apart by ``jit_<__name__>``: none
+    of a paged engine's may be ``_unknown`` (a bare functools.partial) or
+    a lambda, each reads as the auditor names it, and exactly one is a
+    ``decode`` (the draft's step is ``serving_draft``)."""
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=2, kv_pool_blocks=24,
+                           kv_block_tokens=8, draft_model=model,
+                           draft_variables=variables)
+    names = {k: f.__name__ for k, f in _programs(engine).items()}
+    assert len(names) >= 14
+    assert all(n.startswith("serving_") for n in names.values()), names
+    assert [n for n in names.values() if "decode" in n] == ["serving_decode"]
+    assert names["_draft_step"] == "serving_draft"
+    # the name is what the compiled module is called, not a label beside it
+    assert engine._fresh_draft_row.lower().as_text().startswith(
+        "module @jit_serving_fresh_draft_row")
+    # with an auditor, the audit report and the trace use one name
+    audited = _engine(tiny_lm, 1, kv_pool_blocks=24, kv_block_tokens=8)
+    _run_engine(audited, _prompts(2, seed=3), 4)
+    assert {"serving_decode", "serving_prefill", "serving_admit"} <= set(
+        audited.auditor.report())
+
+
+def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
+    """``serving_slot_ticks_total``, ``kv_pool_block_ticks_total`` and
+    ``serving_pipeline_barriers_total{reason}`` against what this test
+    counts at the same places; every ``barrier`` span carries one of the
+    fixed reasons and there is one per counted barrier."""
+    from distkeras_tpu import telemetry as T
+    from distkeras_tpu.serving.metrics import BARRIER_REASONS
+
+    engine = _engine(tiny_lm, 1, kv_pool_blocks=24, kv_block_tokens=8)
+    rows, blocks, barriers = [], [], []
+    dispatch, sample = engine._decode_dispatch, engine.metrics.sample
+    barrier = engine._pipeline_barrier
+
+    def counting_dispatch():
+        tick = dispatch()
+        rows.append(len(tick.rows))
+        return tick
+
+    def counting_sample(*args, **kw):
+        blocks.append(engine.kv_pool.blocks_used)
+        return sample(*args, **kw)
+
+    def counting_barrier(loop, reason):
+        if engine._inflight:
+            barriers.append(reason)
+        return barrier(loop, reason)
+
+    engine._decode_dispatch = counting_dispatch
+    engine.metrics.sample = counting_sample
+    engine._pipeline_barrier = counting_barrier
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        await asyncio.sleep(0.03)  # nothing queued yet: the loop idles
+        reqs = [engine.submit(p, 12) for p in _prompts(6, seed=23)]
+        for r in reqs:
+            await r.result()
+        engine.shutdown(drain=True)
+        await task
+
+    tracer = T.enable_tracing()
+    try:
+        asyncio.run(main())
+    finally:
+        T.disable_tracing()
+    snap = engine.metrics.registry.snapshot()
+    assert len(rows) > 12 and len(blocks) >= len(rows)
+    assert snap["serving_slot_ticks_total"]["value"] == sum(rows)
+    assert snap["kv_pool_block_ticks_total"]["value"] == sum(blocks)
+    assert snap["serving_decode_iterations_total"]["value"] == len(blocks)
+    assert set(barriers) <= set(BARRIER_REASONS)
+    assert {"admission", "paged_growth"} <= set(barriers)
+    for reason in BARRIER_REASONS:
+        key = "serving_pipeline_barriers_total{reason=%s}" % reason
+        assert snap[key]["value"] == barriers.count(reason), reason
+    begins = [(name, attrs) for ph, name, _t, _lane, _parent, attrs
+              in tracer.events() if ph == "B"]
+    spans = [attrs["reason"] for name, attrs in begins if name == "barrier"]
+    assert sorted(spans) == sorted(barriers)
+    harvests = [attrs for name, attrs in begins if name == "harvest"]
+    assert len(harvests) == len(rows)
+    assert all(a["kind"] == "decode" and a["ready"] in (True, False)
+               for a in harvests)
+    assert any(name == "loop_idle" for name, _ in begins)
+    with pytest.raises(KeyError):
+        engine.metrics.record_barrier("because")

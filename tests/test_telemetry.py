@@ -171,6 +171,78 @@ def test_tracer_event_cap_keeps_matched_be():
     assert meta and meta[0]["args"]["count"] == 10
 
 
+def _profiled_spans(tmp_path, body) -> dict[str, list[dict]]:
+    """Run ``body()`` inside a jax.profiler session; the ``dk:`` events of
+    the trace's ``/host:CPU`` plane, name -> [stats of each event]."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    found: dict[str, list[dict]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(T.spans.PREFIX):
+                    assert plane.name == "/host:CPU"
+                    found.setdefault(ev.name, []).append(
+                        {"duration_ns": ev.duration_ns, **dict(ev.stats)})
+    return found
+
+
+def test_span_is_null_without_tracer_and_without_profiler_session(tmp_path):
+    from jax.profiler import TraceAnnotation
+
+    assert not TraceAnnotation.is_enabled() and T.active_tracer() is None
+    assert T.span("x") is T.spans._NULL_SPAN
+    found = _profiled_spans(
+        tmp_path, lambda: T.span("seen").__enter__().__exit__(None, None, None))
+    assert list(found) == ["dk:seen"]
+    # the session is over: the program was never told, it asks each time
+    assert T.span("x", attr=1) is T.spans._NULL_SPAN
+
+
+def test_span_reaches_a_live_profiler_session_with_its_attrs(tmp_path):
+    import time
+
+    def body():
+        with T.span("decode_tick", active=64):
+            time.sleep(0.005)
+        with T.span("barrier", reason="paged_growth"):
+            pass
+
+    found = _profiled_spans(tmp_path, body)
+    assert set(found) == {"dk:decode_tick", "dk:barrier"}
+    (tick,) = found["dk:decode_tick"]
+    assert tick["active"] == 64 and tick["duration_ns"] >= 5e6
+    assert found["dk:barrier"][0]["reason"] == "paged_growth"
+
+
+def test_tracer_and_profiler_session_together_record_both(tmp_path):
+    tracer = T.enable_tracing()
+
+    def body():
+        with T.span("outer", step=3):
+            with T.span("inner"):
+                pass
+
+    found = _profiled_spans(tmp_path, body)
+    T.disable_tracing()
+    assert found["dk:outer"][0]["step"] == 3 and "dk:inner" in found
+    # the Chrome export keeps its own names (no prefix), parents and format
+    trace = tracer.chrome_trace()
+    _balanced_stacks(trace)
+    begins = {e["name"]: e for e in trace["traceEvents"] if e["ph"] == "B"}
+    assert set(begins) == {"outer", "inner"}
+    assert begins["outer"]["args"] == {"step": 3}
+    assert begins["inner"]["args"] == {"parent": "outer"}
+
+
 def test_sanitize_metric_name():
     from distkeras_tpu.telemetry import sanitize_metric_name
 
