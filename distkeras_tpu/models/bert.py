@@ -89,8 +89,12 @@ class BertConfig:
     decode_cache_len: int = 0
     # > 0 selects PAGED decode (decode_slots only): K/V lives in a
     # shared block pool of this many fixed-size blocks per layer
-    # ([paged_blocks, page_tokens, H, D] cache variables) instead of a
-    # dense [B, L, H, D] cache, addressed through per-row block tables.
+    # ([paged_blocks, page_tokens, H*D] cache variables: heads folded
+    # into the minor dimension, so a block's (page_tokens, H*D) minor
+    # pair fills whole TPU tiles at a head size of 64 as at 128 and the
+    # compiled scatter and gather run on the leaf as stored; see
+    # ops/attention.paged_kv_update) instead of a dense
+    # [B, L, H, D] cache, addressed through per-row block tables.
     # The module becomes position-stateless: the caller passes
     # ``positions`` [B] (each row's write offset) and ``block_tables``
     # [B, page_table_blocks] to every apply — traced arrays, so one
@@ -315,7 +319,7 @@ class SelfAttention(nn.Module):
         every position not yet written by a real token.
 
         Paged mode (``cfg.paged_blocks > 0``): the cache variables are
-        the shared block pools ``[C, page_tokens, H, D]`` and the module
+        the shared block pools ``[C, page_tokens, H*D]`` and the module
         is position-stateless — ``positions``/``block_tables`` come from
         the caller as traced arrays, the write is a dropped-OOB scatter,
         and the read is a gather over the row's block table
@@ -336,9 +340,9 @@ class SelfAttention(nn.Module):
 
             C, bt = cfg.paged_blocks, cfg.page_tokens
             pk = self.variable("cache", "pool_key", jnp.zeros,
-                               (C, bt, H, D), cfg.dtype)
+                               (C, bt, H * D), cfg.dtype)
             pv = self.variable("cache", "pool_value", jnp.zeros,
-                               (C, bt, H, D), cfg.dtype)
+                               (C, bt, H * D), cfg.dtype)
             if self.is_initializing():
                 return dot_product_attention(q, k, v, causal=True)
             if positions is None or block_tables is None:
@@ -349,16 +353,20 @@ class SelfAttention(nn.Module):
             # attention output below) to the head-sharded layout at the
             # scatter/gather sites, so the replicated table/position
             # indices can never argue the partitioner into moving KV
-            # bytes. No-ops when tp_mesh is None.
+            # bytes. A pool's heads live in its last dimension (a
+            # contiguous split of H*D over tp is the same partition of
+            # heads). No-ops when tp_mesh is None.
             pk.value = constrain_heads(
                 paged_kv_update(pk.value, k, block_tables, positions, bt),
-                cfg.tp_mesh)
+                cfg.tp_mesh, dim=-1)
             pv.value = constrain_heads(
                 paged_kv_update(pv.value, v, block_tables, positions, bt),
-                cfg.tp_mesh)
+                cfg.tp_mesh, dim=-1)
+            tp = cfg.tp_mesh.shape.get("tp", 1) if cfg.tp_mesh else 1
             return constrain_heads(
                 paged_attention(q, pk.value, pv.value, block_tables,
-                                positions),
+                                positions,
+                                head_groups=tp if H % tp == 0 else 1),
                 cfg.tp_mesh)
         L = cfg.decode_cache_len or cfg.max_seq_len
         ck = self.variable("cache", "cached_key", jnp.zeros, (B, L, H, D), cfg.dtype)

@@ -36,17 +36,21 @@ def constrain_heads(x, mesh, axis: str = "tp", dim: int = -2):
     """Pin ``x``'s heads dimension to the mesh's tensor-parallel axis
     with ``with_sharding_constraint`` (no-op outside a sharded context).
 
-    The serving engine's paged decode threads ``[C, bt, H, D]`` block
-    pools and ``[B, S, H, D]`` activations through gather/scatter ops
-    whose index operands (block tables, positions) are replicated; left
-    to propagation alone, the SPMD partitioner may resolve that mixed
-    evidence by resharding — or worse, all-gathering — the multi-MB
-    pool around every scatter. Constraining the heads dim at the
-    update/read sites makes the head-parallel layout an explicit fact
-    of the program: K/V bytes never move between devices, only the
-    (tiny, replicated) indices do. Leaves whose head count does not
-    divide the axis pass through unconstrained (replicated layouts stay
-    legal)."""
+    ``dim`` is where the heads live: ``-2`` for ``[B, S, H, D]``
+    activations and dense caches, ``-1`` for a paged block pool
+    ``[C, bt, H*D]``, whose heads are folded into the minor dimension (a
+    contiguous split of ``H*D`` over the axis is the same partition of
+    heads). The serving engine's paged decode threads both through
+    gather/scatter ops whose index operands (block tables, positions)
+    are replicated; left to propagation alone, the SPMD partitioner may
+    resolve that mixed evidence by resharding — or worse, all-gathering
+    — the multi-MB pool around every scatter. Constraining the heads
+    dim at the update/read sites makes the head-parallel layout an
+    explicit fact of the program: K/V bytes never move between devices,
+    only the (tiny, replicated) indices do. A dimension the axis does
+    not divide passes through unconstrained (replicated layouts stay
+    legal; the engine refuses a head count its ``tp`` does not divide
+    before any pool exists)."""
     if mesh is None or axis not in mesh.axis_names:
         return x
     n = mesh.shape[axis]
@@ -96,9 +100,11 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False):
 def paged_kv_update(pool, new, tables, positions, page_tokens: int):
     """Scatter per-row K (or V) vectors into a paged block pool.
 
-    ``pool``: ``[C, page_tokens, H, D]`` — the shared block pool (row ``c``
-    is one ``page_tokens``-token block). ``new``: ``[B, S, H, D]`` — each
-    batch row's ``S`` new K/V vectors. ``tables``: int32 ``[B, T]`` —
+    ``pool``: ``[C, page_tokens, H*D]`` — the shared block pool (row ``c``
+    is one ``page_tokens``-token block; a token's heads lie side by side
+    in the minor dimension, the row-major bytes of ``[H, D]``). ``new``:
+    ``[B, S, H, D]`` — each batch row's ``S`` new K/V vectors, written
+    as rows of ``H*D``. ``tables``: int32 ``[B, T]`` —
     row ``b``'s block table: entry ``t`` is the pool row holding its
     virtual positions ``[t*page_tokens, (t+1)*page_tokens)``; any id
     ``>= C`` marks an unallocated table entry. ``positions``: int32
@@ -115,6 +121,18 @@ def paged_kv_update(pool, new, tables, positions, page_tokens: int):
     equivalent of the dense cache's "garbage stays in your own row"
     discipline.
 
+    Why the pool is not ``[C, page_tokens, H, D]``: on a TPU an array's
+    two minor dimensions are laid out in (8, 128) tiles — (16, 128) for
+    bfloat16 — and ``(H, D) = (12, 64)`` would pad to 2.67 x its bytes,
+    so the compiler instead stores such a leaf with ``C`` minor-most and
+    converts the WHOLE leaf to a scatterable layout and back around
+    every update (three leaf-sized copies per leaf per program; the
+    decode tick then follows the pool's capacity, not its rows).
+    ``(page_tokens, H*D)`` fills whole tiles wherever ``H*D`` is a
+    multiple of 128, so the donated leaf is scattered in place as
+    stored. ``tests/test_tpu_compile.py`` holds that in the compiled
+    programs.
+
     Multi-token windows (``S > 1``) serve chunked prefill AND the
     speculative verify step: a ``K``-token draft window scatters all
     its K/V in one call, rejected-draft positions are "rolled back" by
@@ -126,34 +144,87 @@ def paged_kv_update(pool, new, tables, positions, page_tokens: int):
     """
     C = pool.shape[0]
     T = tables.shape[1]
-    pos = positions[:, None] + jnp.arange(new.shape[1])[None, :]  # [B, S]
+    B, S = new.shape[:2]
+    pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
     blk = pos // page_tokens
     rows = jnp.take_along_axis(tables, jnp.minimum(blk, T - 1), axis=1)
     # Past the table's reach: force an out-of-range pool row so the
     # scatter drops the write instead of clamping into a real block.
     rows = jnp.where(blk < T, rows, C)
     offs = pos % page_tokens
-    return pool.at[rows, offs].set(new.astype(pool.dtype), mode="drop")
+    new = new.reshape(B, S, -1).astype(pool.dtype)  # rows of H*D
+    return pool.at[rows, offs].set(new, mode="drop")
+
+
+# A query window of at most this many rows (S x heads of a group) goes
+# through the folded-heads read: one pass of a 128-row matrix unit
+# either way, so the block-diagonal zeros cost nothing.
+_FOLDED_QUERY_ROWS = 128
+
+
+def _folded_heads_attention(q, k, v, mask, head_groups: int):
+    """Masked softmax attention of ``q`` ``[B, S, H, D]`` over K/V that
+    keep their heads folded, ``[B, L, H*D]``, without ever splitting
+    the K/V arrays' minor dimension.
+
+    Each group of ``G = H / head_groups`` neighbouring heads is one
+    matrix product: the group's queries are laid out block-diagonally,
+    ``[S*G, G*D]`` with head ``g``'s ``D`` values in lanes
+    ``[g*D, (g+1)*D)`` and exact zeros elsewhere, against the group's
+    K as stored, ``[L, G*D]``. The zeros add nothing to any float32
+    accumulation, so scores and outputs are the per-head products'
+    own; the values come back ``[S*G, G*D]`` and each head keeps its
+    diagonal block. ``G`` times the multiply-adds of the per-head form,
+    on a unit that a short window leaves idle; in exchange a ``D`` below
+    the 128-lane tile never becomes a minor dimension (for
+    ``[B, L, 12, 64]`` bfloat16 that is a padded copy 2.67 x the
+    gathered bytes, per K and V, per layer)."""
+    B, S, H, D = q.shape
+    L = k.shape[1]
+    n, G = head_groups, H // head_groups
+    k = k.reshape(B, L, n, G * D)
+    v = v.reshape(B, L, n, G * D)
+    own = jnp.eye(G, dtype=bool)[:, :, None]  # [G, G, 1]
+    qbd = jnp.where(own, q.reshape(B, S, n, G, 1, D), 0)  # [B,S,n,G,G,D]
+    qbd = qbd.reshape(B, S, n, G, G * D)
+    scores = jnp.einsum("bqngj,bknj->bngqk", qbd, k).astype(jnp.float32)
+    scores = scores * D ** -0.5
+    scores = jnp.where(mask[:, :, None], scores, -1e30)  # mask [B,1,S,L]
+    weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bngqk,bknj->bqngj", weights, v)
+    out = jnp.einsum("bqnggd->bqngd", out.reshape(B, S, n, G, G, D))
+    return out.reshape(B, S, H, D)
 
 
 @jax.named_scope("attention")
-def paged_attention(q, pool_k, pool_v, tables, positions):
+def paged_attention(q, pool_k, pool_v, tables, positions,
+                    head_groups: int = 1):
     """Attention over paged (block-pooled) K/V: the serving engine's
     decode-slot read path when KV lives in a shared block pool instead of
     a dense per-slot ``[B, L, H, D]`` cache.
 
     ``q``: ``[B, S, H, D]`` queries whose first token sits at virtual
     position ``positions[b]`` (int32 ``[B]``). ``pool_k``/``pool_v``:
-    ``[C, bt, H, D]`` block pools. ``tables``: int32 ``[B, T]`` per-row
+    ``[C, bt, H*D]`` block pools. ``tables``: int32 ``[B, T]`` per-row
     block tables (ids ``>= C`` = unallocated; the gather clamp reads an
     arbitrary real block there, and the position mask hides it).
+    ``head_groups``: into how many groups of neighbouring heads the
+    heads may be kept apart — the tensor-parallel degree, so that no
+    product mixes lanes of two shards; 1 on one device.
 
-    Each row's virtual K/V ``[T*bt, H, D]`` is gathered in table order —
-    position order, exactly the dense cache's layout — and masked with
+    Each row's virtual K/V is gathered in table order as ``[T*bt, H*D]``
+    — position order, exactly the dense cache's layout once the heads
+    are split — straight from the pool as stored, and masked with
     the same ``k_pos <= q_pos`` rule the dense decode path uses, so for
     any masked-out tail the softmax contributions are exactly zero and
-    the output is bitwise identical to dense attention over the same
-    resident K/V. One compiled program for every table layout.
+    the output is that of dense attention over the same resident K/V.
+    One compiled program for every table layout.
+
+    The heads are split on the GATHERED array and never on the pool. A
+    short window (decode, speculative verify: ``S`` x a group's heads
+    within ``_FOLDED_QUERY_ROWS``) does not split them at all
+    (:func:`_folded_heads_attention`); a long one (a prefill chunk, one
+    row) reshapes its own small gather to ``[B, T*bt, H, D]``.
 
     With an ``S > 1`` query window (speculative verify), query ``j``
     attends to positions ``<= positions[b] + j`` — including the
@@ -162,15 +233,18 @@ def paged_attention(q, pool_k, pool_v, tables, positions):
     to what one-token-at-a-time decode would have produced given the
     same prefix, the property speculative acceptance depends on.
     """
-    B, S = q.shape[0], q.shape[1]
+    B, S, H, D = q.shape
     bt = pool_k.shape[1]
     T = tables.shape[1]
-    k = pool_k[tables].reshape((B, T * bt) + pool_k.shape[2:])
-    v = pool_v[tables].reshape((B, T * bt) + pool_v.shape[2:])
+    k = pool_k[tables].reshape(B, T * bt, H * D)
+    v = pool_v[tables].reshape(B, T * bt, H * D)
     q_pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
     k_pos = jnp.arange(T * bt)
     mask = k_pos[None, None, None, :] <= q_pos[:, None, :, None]  # [B,1,S,L]
-    return dot_product_attention(q, k, v, mask=mask)
+    if S * (H // head_groups) <= _FOLDED_QUERY_ROWS:
+        return _folded_heads_attention(q, k, v, mask, head_groups)
+    return dot_product_attention(q, k.reshape(B, T * bt, H, D),
+                                 v.reshape(B, T * bt, H, D), mask=mask)
 
 
 def _block_attn_update(q, k_blk, v_blk, acc, m, denom, scale, mask=None):

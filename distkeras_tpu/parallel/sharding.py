@@ -74,24 +74,28 @@ def kv_pytree_shardings(mesh: Mesh, tree, axis: str = "tp"):
     sharded over its **heads** dimension, everything else replicated.
 
     The rule is shape-driven because cache variables carry no logical-
-    axis annotations (they are created with plain ``self.variable``):
-    K/V leaves are the ``ndim >= 3`` arrays — dense per-slot caches
-    ``[B, L, H, D]``, single prefill rows ``[1, L, H, D]``, and paged
-    block pools ``[C, block_tokens, H, D]`` all keep heads at axis
-    ``-2`` — and shard only when the head count divides the mesh axis.
-    1-D index leaves (cache/pos counters) and anything else stay
-    replicated host-ish metadata, mirroring the serving engine's stance
-    that block tables and slot state are replicated while only the KV
-    bytes shard. ``tree`` may hold concrete arrays or ``eval_shape``
-    structs."""
+    axis annotations (they are created with plain ``self.variable``).
+    4-D leaves — dense per-slot caches ``[B, L, H, D]``, single prefill
+    rows ``[1, L, H, D]`` and the dense prefix cache's blocks
+    ``[C, block_tokens, H, D]`` — keep heads at axis ``-2``. 3-D leaves
+    are paged block pools ``[C, block_tokens, H*D]``, heads folded into
+    the last axis, where a contiguous split over the mesh axis is the
+    same partition of heads. A leaf shards only when that dimension
+    divides the mesh axis (the engine refuses a head count its ``tp``
+    does not divide before it builds a pool). 1-D index leaves
+    (cache/pos counters) and anything else stay replicated host-ish
+    metadata, mirroring the serving engine's stance that block tables
+    and slot state are replicated while only the KV bytes shard.
+    ``tree`` may hold concrete arrays or ``eval_shape`` structs."""
     n = mesh.shape.get(axis, 1)
 
     def rule(leaf):
         shape = getattr(leaf, "shape", ())
+        heads_dim = -1 if len(shape) == 3 else -2
         if (axis in mesh.axis_names and n > 1 and len(shape) >= 3
-                and shape[-2] % n == 0):
+                and shape[heads_dim] % n == 0):
             spec = [None] * len(shape)
-            spec[-2] = axis
+            spec[heads_dim] = axis
             return NamedSharding(mesh, P(*spec))
         return NamedSharding(mesh, P())
 

@@ -422,6 +422,15 @@ def _kv_gather_fn(cache, ids):
     return jax.tree.map(lambda p: p[ids] if p.ndim > 1 else p[:0], cache)
 
 
+def _as_pool_rows(rows, leaf):
+    """Host-side block rows ``[n, bt, ...]`` of a KVX1 payload in the
+    pool leaf's own trailing shape. A payload spells a token's elements
+    as its writer stored them — ``[H, D]`` from a peer or a disk tier
+    older than the flat leaf, ``[H*D]`` since — over the same bytes in
+    the same order."""
+    return rows.reshape((rows.shape[0],) + tuple(leaf.shape[1:]))
+
+
 def _kv_scatter_fn(cache, data, ids):
     """Scatter imported block rows ``data`` into the pool at rows
     ``ids`` — the device half of a KV block IMPORT. ``ids`` pads its
@@ -1421,7 +1430,7 @@ class ServingEngine:
         self._key = jax.random.PRNGKey(seed)
 
         # Device-resident batch state. In paged mode ``_cache`` holds the
-        # SHARED block pools (per-layer [capacity, bt, H, D] leaves, no
+        # SHARED block pools (per-layer [capacity, bt, H*D] leaves, no
         # per-slot index leaves — positions/tables are passed per call);
         # in dense mode, the classic [slots, L, H, D] per-slot caches.
         # Sharded: the KV leaves are committed to their heads-sharded
@@ -1702,8 +1711,17 @@ class ServingEngine:
         # the decode step must stay at exactly one executable for the
         # server's lifetime (see decode_compile_count()). The live batch
         # cache is donated — the engine rebinds it from each call's
-        # outputs, and donation keeps the multi-MB KV caches updating in
-        # place instead of copying per decoded token. The decode step's
+        # outputs, and donation keeps the KV caches updating in place
+        # instead of copying per decoded token. For the paged pools
+        # that takes two things, and tests/test_tpu_compile.py holds
+        # both in the programs compiled for a v5e: every leaf is in the
+        # executable's input_output_alias, AND the leaf is stored
+        # [capacity, bt, H*D] — a shape the compiler lays out the way
+        # the scatter and the gather want it, so no program converts a
+        # whole leaf's layout on the way in, for the gather, and on the
+        # way out (three leaf-sized copies a leaf with [.., H, D] at
+        # D = 64: the donation held and the tick still followed
+        # --kv-pool-mb). The decode step's
         # TOKEN operand is deliberately NOT donated (unlike the cache):
         # that is the pipeline's double buffer — tick N's output tokens
         # are the harvest handle the host reads AFTER tick N+1 has been
@@ -3033,13 +3051,18 @@ class ServingEngine:
                 f"KV leaf count mismatch: payload has {len(theirs)}, "
                 f"this pool {len(mine)}")
         for i, (meta, leaf) in enumerate(zip(theirs, mine)):
-            want = (tuple(int(s) for s in meta["shape"][1:]),
-                    str(meta["dtype"]))
-            have = (tuple(leaf.shape[1:]), np.dtype(leaf.dtype).name)
+            # A block's tokens and a token's elements: a payload may
+            # spell the latter [H, D] (a peer older than the flat pool
+            # leaf) or [H*D]; the bytes and their order are the same.
+            shape = [int(s) for s in meta["shape"]]
+            want = (shape[1], int(np.prod(shape[2:])), str(meta["dtype"]))
+            have = (leaf.shape[1], int(np.prod(leaf.shape[2:])),
+                    np.dtype(leaf.dtype).name)
             if want != have:
                 raise KVTransferError(
                     f"KV leaf {i} geometry mismatch: payload "
-                    f"{want[1]}{want[0]}, this pool {have[1]}{have[0]}")
+                    f"{want[2]}{shape[1:]}, this pool "
+                    f"{have[2]}{list(leaf.shape[1:])}")
         prov = header.get("provenance") or {}
         mine_prov = self.weight_version
         if (int(prov.get("version") or 0), prov.get("digest")) != (
@@ -3067,7 +3090,7 @@ class ServingEngine:
                 if leaf.ndim <= 1:
                     data_leaves.append(jnp.zeros((b, 0), leaf.dtype))
                     continue
-                arr = next(src)[idxs]
+                arr = _as_pool_rows(next(src)[idxs], leaf)
                 if len(idxs) < b:  # pad to the pow2 bucket (dropped)
                     pad = np.zeros((b - len(idxs),) + arr.shape[1:],
                                    arr.dtype)
@@ -3171,6 +3194,24 @@ class ServingEngine:
         return (int(prov.get("version") or 0), prov.get("digest")) == (
             int(mine.get("version") or 0), mine.get("digest"))
 
+    def _tier_rows(self, payload):
+        """A host-tier payload's per-leaf block rows in the pool's own
+        leaf shapes, or None where it cannot be adopted (truncated or
+        corrupt, another block geometry, other weights): the caller
+        stops its chain there and never raises."""
+        from distkeras_tpu.serving.kv_transfer import deserialize_blocks
+
+        mine = [l for l in jax.tree.leaves(self._cache) if l.ndim > 1]
+        try:
+            header, leaves = deserialize_blocks(payload)
+            if (int(header.get("block_tokens") or 0) != self.kv_block_tokens
+                    or len(leaves) != len(mine)
+                    or not self._tier_provenance_ok(header)):
+                return None
+            return [_as_pool_rows(l, m) for l, m in zip(leaves, mine)]
+        except Exception:
+            return None
+
     def _readmit_from_tier(self, tokens, trace_id: str | None = None) -> int:
         """Extend the device trie along ``tokens`` from the host tier:
         for each complete block past the device-resident prefix, fetch
@@ -3191,25 +3232,15 @@ class ServingEngine:
         resident = pool.probe(toks) // bt
         if resident >= cap or not tier.contains(toks[:(resident + 1) * bt]):
             return 0
-        from distkeras_tpu.serving.kv_transfer import deserialize_blocks
-
         t0 = time.monotonic()
-        mine = [l for l in jax.tree.leaves(self._cache) if l.ndim > 1]
         staged: list[tuple[int, list]] = []  # (pool_row, per-leaf [1,bt,..])
         nbytes = 0
         k = resident
         while k < cap:
             chain = toks[:(k + 1) * bt]
             payload = tier.get(chain)
-            if payload is None:
-                break
-            try:
-                header, leaves = deserialize_blocks(payload)
-            except Exception:
-                break  # truncated/corrupt entry: stop, never raise
-            if (int(header.get("block_tokens") or 0) != bt
-                    or len(leaves) != len(mine)
-                    or not self._tier_provenance_ok(header)):
+            leaves = None if payload is None else self._tier_rows(payload)
+            if leaves is None:
                 break
             # adopt_foreign re-walks the chain from the root: resident
             # prefix blocks are touched, block k gets a fresh row (or
@@ -3258,25 +3289,13 @@ class ServingEngine:
         tier = self.kv_tier
         if tier is None:
             return n
-        from distkeras_tpu.serving.kv_transfer import deserialize_blocks
-
         bt = self.kv_block_tokens
         n_total = len(tokens) // bt
         extras, k = [], n
         while k < n_total:
             payload = tier.get(tokens[:(k + 1) * bt])
-            if payload is None:
-                break
-            try:
-                header, lv = deserialize_blocks(payload)
-            except Exception:
-                break
-            if (int(header.get("block_tokens") or 0) != bt
-                    or not self._tier_provenance_ok(header)):
-                break
-            want = len(leaves) if leaves else (
-                len(extras[0]) if extras else len(lv))
-            if len(lv) != want or not lv:
+            lv = None if payload is None else self._tier_rows(payload)
+            if lv is None:
                 break
             extras.append(lv)
             k += 1

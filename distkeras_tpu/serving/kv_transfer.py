@@ -24,8 +24,10 @@ prompt), the sender's weight provenance stamp (version + digest; KV is
 a pure function of (weights, tokens), so the receiver REJECTS a stamp
 that differs from its own — typed, before any device work), and each
 KV leaf's per-block shape + dtype (the compatibility check between
-pools). Leaf bytes are raw C-order ``[n_blocks, block_tokens, H, D]``
-arrays in ``jax.tree.leaves`` order — the same prompt serialized twice
+pools). Leaf bytes are raw C-order ``[n_blocks, block_tokens, H*D]``
+arrays (a token's heads side by side: the bytes of ``[H, D]``, which is
+how an older sender's header spells the same rows; a receiver takes
+either) in ``jax.tree.leaves`` order — the same prompt serialized twice
 from the same pool is byte-identical, and a same-geometry receiver
 re-uploads them bit-for-bit. A tensor-parallel receiver re-shards the
 heads dimension through the engine's existing ``kv_pytree_shardings``

@@ -732,7 +732,7 @@ class KVBlockPool(_BlockTrie):
     prefix cache.
 
     Unlike :class:`PrefixCache` this class owns NO device arrays: the
-    pools (``[capacity, block_tokens, H, D]`` per layer K/V) are the
+    pools (``[capacity, block_tokens, H*D]`` per layer K/V) are the
     paged module's cache variables, threaded through the engine's
     compiled programs. The pool hands out *row ids*:
 

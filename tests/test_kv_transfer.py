@@ -176,8 +176,13 @@ def test_adopt_foreign_pool_dry_and_partial():
 
 
 # -- engine-level transfer ---------------------------------------------------
+@pytest.mark.parametrize("spelling", ["flat", "per_head"])
 def test_export_import_bitwise_roundtrip_and_identical_continuation(
-        lm, rng):
+        lm, rng, spelling):
+    """``per_head``: the payload as a peer older than the flat pool leaf
+    sends it — header shapes ``[n, bt, H, D]`` over the same bytes in the
+    same order — is adopted just the same (the wire format did not move
+    with the stored shape)."""
     async def main():
         e1 = _engine(lm)
         e2 = _engine(lm)
@@ -191,6 +196,15 @@ def test_export_import_bitwise_roundtrip_and_identical_continuation(
         assert "error" not in res and res["matched_tokens"] >= 12
         payload = res["payload"]
         header, leaves = deserialize_blocks(payload)
+        if spelling == "per_head":
+            heads = lm[0].config.num_heads
+            split = [l.reshape(l.shape[:2] + (heads, -1)) for l in leaves]
+            payload = serialize_blocks(
+                header["tokens"], split,
+                block_tokens=header["block_tokens"],
+                provenance=header["provenance"])
+            assert [l.tobytes() for l in split] == [
+                l.tobytes() for l in leaves]
         imp = await _kv_op(e2.request_kv_import, payload)
         assert imp["adopted_blocks"] == header["n_blocks"]
         assert imp["matched_tokens"] == res["matched_tokens"]

@@ -193,6 +193,75 @@ def test_block_pool_spill_burst_flushes_even_on_shortfall():
     pool.release(m)
 
 
+# -- the pool leaf [C, bt, H*D]: written and read as stored -------------------
+
+@pytest.mark.parametrize("S,head_groups", [
+    (1, 1),    # a decode tick: heads stay folded
+    (1, 2),    # the same under tp=2: two groups of heads, never mixed
+    (5, 1),    # a speculative verify window, still folded
+    (64, 1),   # a prefill chunk: heads split on the chunk's own gather
+])
+def test_paged_ops_match_dense_attention_over_the_same_kv(S, head_groups):
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.attention import (
+        dot_product_attention,
+        paged_attention,
+        paged_kv_update,
+    )
+
+    B, H, D, bt, T, C = 3, 4, 8, 4, 20, 70
+    L = T * bt
+    r = np.random.default_rng(S * 10 + head_groups)
+    dense_k, dense_v = (r.standard_normal((B, L, H, D)).astype(np.float32)
+                        for _ in range(2))
+    q, new_k, new_v = (jnp.asarray(r.standard_normal((B, S, H, D)),
+                                   jnp.float32) for _ in range(3))
+    tables = r.permutation(C)[:B * T].reshape(B, T).astype(np.int32)
+    positions = np.asarray([0, 7, L - S], np.int32)
+    # The pool holds each row's resident K/V at its table's blocks, a
+    # token's heads side by side; blocks no table names hold junk.
+    pool_k, pool_v = (r.standard_normal((C, bt, H * D)).astype(np.float32)
+                      for _ in range(2))
+    for pool, dense in ((pool_k, dense_k), (pool_v, dense_v)):
+        pool[tables] = dense.reshape(B, T, bt, H * D)
+    # A row whose table is all sentinel writes nothing.
+    tables[1] = C
+    before = pool_k.copy()
+    pk = paged_kv_update(jnp.asarray(pool_k), new_k, jnp.asarray(tables),
+                         jnp.asarray(positions), bt)
+    pv = paged_kv_update(jnp.asarray(pool_v), new_v, jnp.asarray(tables),
+                         jnp.asarray(positions), bt)
+    assert pk.shape == (C, bt, H * D)
+    changed = np.flatnonzero((np.asarray(pk) != before).any(axis=(1, 2)))
+    assert set(changed) <= set(tables[[0, 2]].ravel())
+    got = paged_attention(q, pk, pv, jnp.asarray(tables),
+                          jnp.asarray(positions), head_groups=head_groups)
+    # The dense twin: the same writes at the same positions, same mask.
+    for b in (0, 2):
+        p = int(positions[b])
+        dense_k[b, p:p + S] = np.asarray(new_k[b])
+        dense_v[b, p:p + S] = np.asarray(new_v[b])
+    q_pos = positions[:, None] + np.arange(S)[None, :]
+    mask = np.arange(L)[None, None, None, :] <= q_pos[:, None, :, None]
+    want = dot_product_attention(q, jnp.asarray(dense_k),
+                                 jnp.asarray(dense_v), mask=jnp.asarray(mask))
+    np.testing.assert_allclose(np.asarray(got)[[0, 2]],
+                               np.asarray(want)[[0, 2]], rtol=2e-6, atol=2e-6)
+
+
+def test_engine_pool_leaves_fold_heads_into_the_minor_dimension(lm):
+    import jax
+
+    model, variables = lm
+    engine = ServingEngine(model, variables, slots=2, kv_pool_blocks=12,
+                           kv_block_tokens=4)
+    cfg = model.config
+    leaves = [l for l in jax.tree.leaves(engine._cache) if l.ndim > 1]
+    assert len(leaves) == 2 * cfg.num_layers
+    assert {l.shape for l in leaves} == {(12, 4, cfg.hidden_size)}
+
+
 # -- paged engine: parity + the compile invariant ----------------------------
 
 def test_paged_parity_vs_generate_and_dense_with_armed_auditor(lm, rng):

@@ -9,7 +9,9 @@ asserts the Mosaic kernel is really in the executable
 compile that passes says nothing about results or speed.
 """
 
+import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 # Nothing here touches a chip, so several test processes (xdist workers)
@@ -115,3 +117,111 @@ def test_ring_flash_attention_grad_compiles_for_four_v5e_chips(topo):
 
     _assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)),
                             *_qkv((2, 4096, 12, 64), seq_sharded))
+
+
+# -- the serving engine's paged programs, at gpt2-small.gen_closed's flags ----
+#
+# `run serve --model gpt_small --paged --kv-block-tokens 16 --slots 64
+# --kv-pool-mb 8192 --max-context 1024`: 24 pool leaves of 14,563 blocks.
+# What is held here is structural (it is in the compiled program or it is
+# not): the donated pool is written and read in place as stored. With a leaf
+# declared [C, bt, H, D] the donation held just the same and every program
+# still converted each whole leaf's layout three times (PR 26).
+_SLOTS, _BLOCK_TOKENS, _CONTEXT, _POOL_BLOCKS = 64, 16, 1024, 14563
+
+
+def _paged_program(topo, which, tp, vocab, layers):
+    """The engine's own paged step (`serving/engine.py`), jitted the way
+    `_sharded_jit` does, with the shapes it is called with: (jitted,
+    arguments, the pool leaves)."""
+    from distkeras_tpu import models
+    from distkeras_tpu.inference.generate import _decode_module
+    from distkeras_tpu.parallel.sharding import (
+        infer_variable_shardings,
+        kv_pytree_shardings,
+        unbox,
+    )
+    from distkeras_tpu.serving import engine
+
+    table = -(-_CONTEXT // _BLOCK_TOKENS)
+    mesh = None
+    if tp > 1:
+        mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, tp), ("dp", "tp"))
+    module, _ = _decode_module(
+        models.gpt_small(seq_len=_CONTEXT, vocab_size=vocab), slots=True,
+        paged_blocks=_POOL_BLOCKS, page_tokens=_BLOCK_TOKENS,
+        page_table_blocks=table, tp_mesh=mesh, num_layers=layers)
+    abstract = jax.eval_shape(
+        lambda r: module.init(r, jnp.zeros((_SLOTS, 1), jnp.int32),
+                              train=False), jax.random.PRNGKey(0))
+    params, cache = unbox(abstract["params"]), abstract["cache"]
+    if mesh is None:
+        rep = SingleDeviceSharding(topo.devices[0])
+        psh = jax.tree.map(lambda _: rep, params)
+        csh = jax.tree.map(lambda _: rep, cache)
+    else:
+        rep = NamedSharding(mesh, P())
+        psh = infer_variable_shardings(mesh, abstract)["params"]
+        csh = kv_pytree_shardings(mesh, cache)
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    key = arg(jnp.uint32, 2)
+    if which == "decode":
+        fn = functools.partial(engine._paged_decode_fn, module, 0,
+                               _POOL_BLOCKS)
+        args = (arg(jnp.int32, _SLOTS), arg(jnp.float32, _SLOTS),
+                arg(jnp.int32, _SLOTS), arg(jnp.int32, _SLOTS, table), key)
+        out_sh = (csh, rep, rep)
+    else:  # one prefill bucket: 128 tokens, the cell's longest prompt
+        fn = functools.partial(engine._paged_prefill_fn, module, 0)
+        args = (arg(jnp.int32, 1, 128), arg(jnp.int32), arg(jnp.int32),
+                arg(jnp.int32, table), arg(jnp.float32), key)
+        out_sh = (csh, rep)
+    jitted = jax.jit(fn, donate_argnums=(1,),
+                     **({} if mesh is None else {"out_shardings": out_sh}))
+    leaves = [a for a in jax.tree.leaves(cache) if a.ndim > 1]
+    return jitted, (placed(params, psh), placed(cache, csh)) + args, leaves
+
+
+@pytest.mark.parametrize("which,tp,vocab,layers", [
+    ("decode", 1, 50257, 12),
+    ("prefill", 1, 50257, 12),
+    # tp needs the vocabulary padded (50,257 divides by nothing); two
+    # layers show what twelve do. 768 / 2 = 384 lanes a shard: whole tiles.
+    ("decode", 2, 50304, 2),
+    # 768 / 4 = 192 lanes a shard pad to 256, so the compiler stores the
+    # shard block-minor again and converts it around the scatter: known,
+    # priced in PERF.md, no cell runs it (ROADMAP B9).
+    pytest.param("decode", 4, 50304, 2, marks=pytest.mark.xfail(
+        strict=True, reason="192 lanes a shard do not fill 128-lane tiles")),
+])
+def test_paged_programs_touch_the_pool_in_place_on_v5e(topo, which, tp, vocab,
+                                                       layers):
+    jitted, args, leaves = _paged_program(topo, which, tp, vocab, layers)
+    compiled = jitted.lower(*args).compile()
+    memory = compiled.memory_analysis()
+    leaf_elems = int(np.prod(leaves[0].shape)) // tp  # on one chip
+    leaf_bytes = leaf_elems * leaves[0].dtype.itemsize
+    # (a) the donation holds: every pool leaf's bytes are aliased to an output
+    assert memory.alias_size_in_bytes >= len(leaves) * leaf_bytes
+    # (b) nothing but the scatter (inside its fusion) makes a leaf-sized array
+    made = {}
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) == leaf_elems:
+            made.setdefault(m.group(2), []).append(line.strip()[:160])
+    for passive in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+        made.pop(passive, None)
+    assert sorted(made) == ["fusion", "scatter"], {
+        op: lines[0] for op, lines in made.items()}
+    assert len(made["fusion"]) == len(made["scatter"]) == len(leaves)
+    # (c) and the program's temporaries are smaller than one leaf
+    assert memory.temp_size_in_bytes < leaf_bytes
