@@ -79,6 +79,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             line["breakdown"] = summary["breakdown"]
         shutil.rmtree(trace_dir, ignore_errors=True)
     line["device"] = device
+    if got.get("split"):  # the run by where its numbers were taken
+        line["split"] = got["split"]
     line["compared"] = compared  # last: each number beside its limit
     return line
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import asyncio
 import bisect
+import contextlib
 import json
 import os
 import signal
@@ -22,10 +23,34 @@ import time
 
 import numpy as np
 
-from . import cell as cell_mod, traffic as traffic_mod
+from . import cell as cell_mod, host_probe, traffic as traffic_mod
 from .program import memory_peak_bytes
 
 DRAIN_S = 60.0  # an answer may come this long after the window closed
+
+
+def split_cpus(cpus) -> tuple[set, set] | None:
+    """(the load generator's, the server's) of the CPUs this process may
+    run on: disjoint, the server the larger share (three quarters). None
+    where there are too few to part."""
+    cpus = sorted(cpus)
+    if len(cpus) < 4:
+        return None
+    k = len(cpus) // 4
+    return set(cpus[:k]), set(cpus[k:])
+
+
+@contextlib.contextmanager
+def own_cpus(cpus):
+    """This process kept to ``cpus`` (all it has, where None), and back on
+    the CPUs it was found on afterwards, also when the body raises."""
+    found = os.sched_getaffinity(0)
+    if cpus:
+        os.sched_setaffinity(0, cpus)
+    try:
+        yield
+    finally:
+        os.sched_setaffinity(0, found)
 
 
 class Record:
@@ -44,14 +69,18 @@ class Record:
 class Server:
     """The child and its two pipes."""
 
-    def __init__(self, cell, seed: int, require_platform: str | None):
+    def __init__(self, cell, seed: int, require_platform: str | None,
+                 cpus=None):
         here = os.path.dirname(os.path.abspath(__file__))
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(here, "serve_child.py"),
-             cell.config_path, str(seed), require_platform or "-",
-             cell.root],
-            cwd=cell.root, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            text=True)
+        # started from ``cpus``: the child and every thread it starts
+        # inherit the set, with no moment at which it runs elsewhere
+        with own_cpus(cpus):
+            self.proc = subprocess.Popen(
+                [sys.executable, os.path.join(here, "serve_child.py"),
+                 cell.config_path, str(seed), require_platform or "-",
+                 cell.root],
+                cwd=cell.root, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+                text=True)
         self.banner = None
 
     def read_json(self, key: str, timeout_s: float) -> dict:
@@ -193,35 +222,40 @@ async def _drive(cell, server: Server, schedule, seed: int, seconds: float,
         await asyncio.sleep(tr["ramp_s"])
 
     before = await _counters(host, port)
-    marks["t0"] = t0 = time.perf_counter()
-    marks["wall0"] = time.time()
+    with host_probe.GcWatch() as marks["collections"]:
+        marks["t0"] = t0 = time.perf_counter()
+        marks["wall0"] = time.time()
 
-    async def heartbeat(period_s: float = 0.02):
-        """How late this process's own loop woke, at worst, in the window:
-        a starved load generator must not read as a stalled server."""
-        marks["loadgen_stall_s"] = 0.0
-        while not closing.is_set():
-            asked = time.perf_counter()
-            await asyncio.sleep(period_s)
-            marks["loadgen_stall_s"] = max(
-                marks["loadgen_stall_s"], time.perf_counter() - asked - period_s)
+        async def heartbeat(period_s: float = 0.02):
+            """How late this process's own loop woke in the window, at
+            worst and each time it was over 10 ms: a starved load generator
+            must not read as a stalled server."""
+            marks["loadgen_stall_s"], marks["loadgen_stalls"] = 0.0, []
+            while not closing.is_set():
+                asked = time.perf_counter()
+                await asyncio.sleep(period_s)
+                late = time.perf_counter() - asked - period_s
+                marks["loadgen_stall_s"] = max(marks["loadgen_stall_s"], late)
+                if late > 0.01:  # (seconds into the window, seconds late)
+                    marks["loadgen_stalls"].append((asked - t0, late))
 
-    beat = asyncio.create_task(heartbeat())
+        beat = asyncio.create_task(heartbeat())
 
-    if trace_dir:
-        await asyncio.sleep(tr["trace_offset_s"])
-        await loop.run_in_executor(
-            None, server.control, f"trace_start {trace_dir}")
-        marks["trace_t0"] = time.perf_counter()
-        await asyncio.sleep(tr["trace_seconds"])
-        marks["traced_s"] = (await loop.run_in_executor(
-            None, server.control, "trace_stop"))["traced_s"]
+        if trace_dir:
+            await asyncio.sleep(tr["trace_offset_s"])
+            await loop.run_in_executor(
+                None, server.control, f"trace_start {trace_dir}")
+            marks["trace_t0"] = time.perf_counter()
+            await asyncio.sleep(tr["trace_seconds"])
+            marks["traced_s"] = (await loop.run_in_executor(
+                None, server.control, "trace_stop"))["traced_s"]
 
-    await asyncio.sleep(max(0.0, t0 + seconds - time.perf_counter()))
-    marks["t_end"] = time.perf_counter()
+        await asyncio.sleep(max(0.0, t0 + seconds - time.perf_counter()))
+        marks["t_end"] = time.perf_counter()
     closing.set()
     await beat
     at_close = await _counters(host, port)
+    marks["counters_read_s"] = time.perf_counter() - marks["t_end"]
     t_end = marks["t_end"]
     if tr["kind"] == "open":
         # Wait for every answer that is due: late is late, not wrong.
@@ -305,15 +339,20 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None,
     sizes = config["model"]["config"]
     schedule = traffic_mod.request_schedule(
         tr, sizes["vocab_size"], seed, seconds + tr.get("ramp_s", 0.0))
-    server = Server(cell, seed, require_platform)
-    marks: dict = {}
+    # The generator and the server on disjoint CPUs, parted from whatever
+    # this machine gives the process (PERF.md section 6, PR 28: runs whose
+    # server is ~5 % slow from start to end are rarer so, not gone).
+    parted = split_cpus(os.sched_getaffinity(0))
+    server = Server(cell, seed, require_platform, parted and parted[1])
+    marks: dict = {"cpus": parted and [sorted(part) for part in parted]}
     try:
         server.banner = server.read_json("serving", 1100.0)
         device = {"platform": server.banner["platform"],
                   "kind": server.banner["device_kind"],
                   "count": server.banner["device_count"]}
-        records = asyncio.run(_drive(cell, server, schedule, seed, seconds,
-                                     trace_dir, marks))
+        with own_cpus(parted and parted[0]):
+            records = asyncio.run(_drive(cell, server, schedule, seed,
+                                         seconds, trace_dir, marks))
         memory = server.control("memstats")["memory_stats"]
         stopped = server.stop()
     finally:
@@ -342,6 +381,7 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None,
         "attempted": len(mine), "failed": len(failed), "device": device,
         "numbers": numbers, "controls": numbers.pop("controls", {}),
         "window_s": window_s,
+        "split": split_by_side(records, marks, e2e, spans, window_s, ticks),
         "setup_s": t0 - t_process_start, "e2e": e2e,
         "memory_peak_bytes": max(memory_peak_bytes(m) for m in memory),
         "traced_s": marks.get("traced_s"),
@@ -360,6 +400,50 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None,
         # host clock (shared by both processes) when the trace began
         "spans": {**spans, "trace_t0": marks.get("trace_t0"),
                   "loadgen_stall_s": marks["loadgen_stall_s"]},
+    }
+
+
+def split_by_side(records, marks, e2e, spans, window_s, ticks):
+    """One run's numbers by where they were taken (chipbench/spread.py
+    reads them): the clients' count against the server's own over the same
+    window, each half of the window reduced like the whole, the gaps
+    between tokens by percentile (a run whose ticks are all slow against
+    one with a few long stalls), and each time the generator's loop woke
+    over 10 ms late with the tokens stamped in the 30 ms after: a backlog
+    that the server built meanwhile (several ticks' worth: the generator
+    alone was held) or no more than the usual (the server was held too)."""
+    t0, t_end = marks["t0"], marks["t_end"]
+    mid = 0.5 * (t0 + t_end)
+    halves = [window_metrics(records, a, b)[2] for a, b in
+              ((t0, mid), (mid, t_end))]
+    server_window_s = t_end - t0 + marks["counters_read_s"]
+    before = marks["before"]["metricsz"]
+    slot_ticks = sum(v - before.get(k, 0)
+                     for k, v in marks["at_close"]["metricsz"].items()
+                     if "slot_ticks_total" in k)
+    arrivals = sorted(t for t, _ in spans["token_events"])
+    gaps = spans["token_gap_s"]
+
+    def burst(at_s, late_s, span_s=0.03):
+        end = t0 + at_s + 0.02 + late_s  # when the late timer fired
+        return bisect.bisect_left(arrivals, end + span_s) - \
+            bisect.bisect_left(arrivals, end)
+
+    return {
+        "clients": {"window_s": window_s, **e2e},
+        "halves": halves,
+        "server": {"window_s": server_window_s, "ticks": ticks,
+                   "slot_ticks": slot_ticks,
+                   "ticks_per_s": ticks / server_window_s,
+                   "slot_ticks_per_s": slot_ticks / server_window_s},
+        "gaps_ms": {f"p{q}": 1e3 * traffic_mod.percentile(gaps, q)
+                    for q in (10, 50, 75, 90, 99)} if gaps else {},
+        "loadgen": {"stall_max_ms": 1e3 * marks["loadgen_stall_s"],
+                    "stalls": [[round(at, 3), round(1e3 * late, 1),
+                                burst(at, late)]
+                               for at, late in marks["loadgen_stalls"][:16]],
+                    "cpus": marks["cpus"],
+                    **marks["collections"].summary()},
     }
 
 

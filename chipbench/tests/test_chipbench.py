@@ -330,6 +330,14 @@ def test_serve_rehearsal_with_new_files_only(tmp_path):
     assert line["metrics"]["prefix_hit_share"]["value"] == 0
     assert line["metrics"]["queue_wait_p95_ms"]["value"] >= 0
     assert 0 <= line["metrics"]["loadgen_stall_max_ms"]["value"] < 2000
+    # the run by where its numbers were taken: the server's own count of the
+    # rows it ticked is the clients' count of tokens, to a tick or two
+    split = line["split"]
+    assert split["server"]["slot_ticks"] == pytest.approx(
+        split["clients"]["serve_tokens_per_s"] * split["clients"]["window_s"],
+        rel=0.05)
+    assert len(split["halves"]) == 2 and split["halves"][0]["serve_tokens_per_s"] > 0
+    assert list(line)[-1] == "compared"          # still last
     assert 0 <= line["compared"]["served_logit_gap"]["value"] < 0.05
 
 
