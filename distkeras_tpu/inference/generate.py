@@ -18,7 +18,6 @@ Sampling: greedy, temperature, and top-k (all inside the scan;
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -31,30 +30,34 @@ __all__ = ["generate", "beam_search", "Generator", "accept_prefix_length",
 
 def _decode_module(model, slots: bool = False, **overrides):
     """Decode-mode twin of ``model``'s module (same params, KV-cache
-    attention). ``slots=True`` selects the per-slot vector-index variant
-    that the continuous-batching engine (serving/engine.py) steps;
-    ``overrides`` are extra BertConfig replacements (the engine's
-    ``decode_cache_len`` cap and ``paged_blocks``/``page_tokens``/
-    ``page_table_blocks`` paged-KV geometry — cache-variable shape knobs
-    only, params stay layout-identical to the trained model)."""
-    from distkeras_tpu.models.bert import Bert, BertConfig
+    attention). ``slots=True`` selects the per-slot variant that the
+    continuous-batching engine (serving/engine.py) steps; ``overrides``
+    are replacements in the model's config (the engine's
+    ``decode_cache_len`` cap, the ``paged_blocks``/``page_tokens``/
+    ``page_table_blocks`` paged-KV geometry and ``tp_mesh`` —
+    cache-variable shape knobs only, params stay layout-identical to the
+    trained model).
 
+    What a model has to bring (the decode surface; docs/serving.md): a
+    ``config`` that is ``causal`` and has ``decode_module(slots,
+    **overrides) -> (module, config)``; the twin config states
+    ``vocab_size``, ``num_layers``, ``max_seq_len``, ``kv_token_bytes``
+    and ``tp_split_sizes``, and the module takes ``positions`` /
+    ``block_tables``. No model is known here by its class or name."""
     cfg = getattr(model, "config", None)
-    if not isinstance(cfg, BertConfig):
+    if not callable(getattr(cfg, "decode_module", None)):
         raise ValueError(
-            "generate() needs a causal model from the distkeras_tpu.models."
-            f"bert zoo (gpt_tiny/gpt_small/...); got {getattr(model, 'name', model)!r}"
+            "generate() needs a causal LM whose config has a decode module "
+            "(the distkeras_tpu.models.bert zoo: gpt_tiny/gpt_small/...; "
+            "distkeras_tpu.models.cohere2_moe: command_a_plus_*); got "
+            f"{getattr(model, 'name', model)!r}"
         )
     if not cfg.causal:
         raise ValueError(
-            f"model {model.name!r} is not causal (BertConfig.causal=False); "
-            "generation requires a decoder LM"
+            f"model {model.name!r} is not causal ({type(cfg).__name__}."
+            "causal is False); generation requires a decoder LM"
         )
-    dec_cfg = dataclasses.replace(
-        cfg, decode=True, decode_slots=slots, dropout_rate=0.0,
-        ring_mesh=None, use_flash_attention=False, **overrides,
-    )
-    return Bert(dec_cfg), dec_cfg
+    return cfg.decode_module(slots=slots, **overrides)
 
 
 def _trained_len(model, dec_cfg) -> int:

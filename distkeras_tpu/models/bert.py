@@ -116,6 +116,28 @@ class BertConfig:
     # default) changes nothing.
     tp_mesh: object = None
 
+    # -- the decode surface (inference/generate._decode_module) ----------
+
+    @property
+    def kv_token_bytes(self) -> int:
+        """K and V of one token through every layer, as a cache holds them."""
+        return (self.num_layers * 2 * self.hidden_size
+                * jnp.dtype(self.dtype).itemsize)
+
+    @property
+    def tp_split_sizes(self) -> dict:
+        """What a tensor-parallel degree has to divide."""
+        return {"num_heads": self.num_heads, "mlp_dim": self.mlp_dim,
+                "vocab_size": self.vocab_size}
+
+    def decode_module(self, slots: bool = False, **overrides):
+        """The decode-mode twin ``(module, config)``: KV-cache attention,
+        no dropout, no sequence parallelism, no flash kernel."""
+        cfg = dataclasses.replace(
+            self, decode=True, decode_slots=slots, dropout_rate=0.0,
+            ring_mesh=None, use_flash_attention=False, **overrides)
+        return Bert(cfg), cfg
+
 
 def _pos_window(pos_embed, starts, S: int, max_seq_len: int):
     """Per-row positional-embedding window ``[B, S, H]``: row ``b`` gets

@@ -29,6 +29,7 @@ __all__ = [
     "ring_attention",
     "ring_self_attention",
     "sp_batch_spec",
+    "window_table_entries",
 ]
 
 
@@ -196,9 +197,34 @@ def _folded_heads_attention(q, k, v, mask, head_groups: int):
     return out.reshape(B, S, H, D)
 
 
+def _grouped_query_attention(q, k, v, mask):
+    """Masked softmax attention of ``q`` ``[B, S, H, D]`` over K/V with
+    fewer heads, ``[B, L, Hkv, D]``: query head ``h`` reads K/V head
+    ``h // (H // Hkv)``. The K/V arrays are used as they are, never
+    repeated: a K/V head's ``H // Hkv`` query heads are rows of one
+    product against it. ``mask``: ``[B, 1, S, L]``."""
+    B, S, H, D = q.shape
+    n = k.shape[2]
+    q = q.reshape(B, S, n, H // n, D)
+    scores = jnp.einsum("bsngd,blnd->bngsl", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(mask[:, :, None], scores * D ** -0.5, -1e30)
+    weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bngsl,blnd->bsngd", weights, v).reshape(B, S, H, D)
+
+
+def window_table_entries(window: int, S: int, page_tokens: int) -> int:
+    """Block-table entries that cover every position a window of ``S``
+    queries can see through a sliding window of ``window`` positions:
+    ``window + S - 1`` positions in a row touch at most this many blocks
+    (``window / page_tokens + 1`` for one query)."""
+    return (window + S - 2) // page_tokens + 2
+
+
 @jax.named_scope("attention")
 def paged_attention(q, pool_k, pool_v, tables, positions,
-                    head_groups: int = 1):
+                    head_groups: int = 1, kv_heads: int | None = None,
+                    window: int | None = None):
     """Attention over paged (block-pooled) K/V: the serving engine's
     decode-slot read path when KV lives in a shared block pool instead of
     a dense per-slot ``[B, L, H, D]`` cache.
@@ -226,6 +252,16 @@ def paged_attention(q, pool_k, pool_v, tables, positions,
     (:func:`_folded_heads_attention`); a long one (a prefill chunk, one
     row) reshapes its own small gather to ``[B, T*bt, H, D]``.
 
+    ``kv_heads`` (fewer K/V heads than query heads: the pool's rows
+    are ``kv_heads * D`` lanes wide) and ``window`` (query ``i`` sees key
+    ``j`` iff ``0 <= i - j < window``) select the grouped-query read.
+    With a window, a row gathers only the table entries that cover the
+    positions its queries can see (:func:`window_table_entries` of them,
+    starting at the block that holds ``positions[b] - window + 1``), not
+    its whole virtual context: the bytes a window layer reads follow the
+    window, whatever the row holds. Both ``None`` is the read above,
+    unchanged.
+
     With an ``S > 1`` query window (speculative verify), query ``j``
     attends to positions ``<= positions[b] + j`` — including the
     window's own earlier K/V written by :func:`paged_kv_update` in the
@@ -236,6 +272,23 @@ def paged_attention(q, pool_k, pool_v, tables, positions,
     B, S, H, D = q.shape
     bt = pool_k.shape[1]
     T = tables.shape[1]
+    if kv_heads is not None or window is not None:
+        n = kv_heads or H
+        q_pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
+        if window is None:
+            entries, first, seen = T, jnp.zeros_like(positions), tables
+        else:
+            entries = min(T, window_table_entries(window, S, bt))
+            first = jnp.clip((positions - window + 1) // bt, 0, T - entries)
+            seen = jnp.take_along_axis(
+                tables, first[:, None] + jnp.arange(entries)[None, :], axis=1)
+        k = pool_k[seen].reshape(B, entries * bt, n, D)
+        v = pool_v[seen].reshape(B, entries * bt, n, D)
+        k_pos = (first * bt)[:, None] + jnp.arange(entries * bt)[None, :]
+        mask = k_pos[:, None, None, :] <= q_pos[:, None, :, None]
+        if window is not None:
+            mask &= k_pos[:, None, None, :] > q_pos[:, None, :, None] - window
+        return _grouped_query_attention(q, k, v, mask)
     k = pool_k[tables].reshape(B, T * bt, H * D)
     v = pool_v[tables].reshape(B, T * bt, H * D)
     q_pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]

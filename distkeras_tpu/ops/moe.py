@@ -17,6 +17,13 @@ assignments contribute nothing — a token dropped by both experts passes
 through the residual unchanged, as in GShard/Switch. The reference
 framework has nothing comparable (SURVEY §2: EP absent); this closes the
 ``ep`` axis of the mesh design.
+
+Beside it, for serving, a DROPLESS expert layer that is told which
+experts it holds (:func:`sigmoid_topk_route`, :func:`dropless_experts`):
+the router scores every expert of the model, this device computes the
+part of the result that its own experts give, and a pair whose expert
+lives elsewhere costs nothing and adds nothing — one chip's share of an
+expert-parallel stage, run without its exchange.
 """
 
 from __future__ import annotations
@@ -24,8 +31,100 @@ from __future__ import annotations
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-__all__ = ["MoEMLP"]
+__all__ = ["MoEMLP", "dropless_experts", "sigmoid_topk_route"]
+
+# Rows of one grouped product: one pass of a 128-row matrix unit. An
+# expert with fewer rows pays for a whole step, one with more takes
+# several.
+_ROWS_PER_STEP = 128
+
+
+@jax.named_scope("router")
+def sigmoid_topk_route(logits, k: int):
+    """Sigmoid routing: ``logits`` ``[N, E]`` -> ``(ids [N, k] int32,
+    weights [N, k] float32)``, the ``k`` largest of ``sigmoid(logits)``
+    per token with their scores normalised to sum to 1 over the ``k``
+    chosen (whichever device holds them). Scores and choice in float32."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    top, ids = lax.top_k(scores, k)
+    return ids.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+@jax.named_scope("moe")
+def dropless_experts(x, w_gate, w_up, w_down, ids, weights,
+                     held: tuple[int, int], live=None):
+    """This device's part of a routed gated-SiLU expert layer, without
+    a capacity: ``sum_k weights[n, k] * f_{ids[n, k]}(x[n])`` over the
+    pairs whose expert is held here, ``f_e(x) = (silu(x Wg_e) * (x
+    Wu_e)) Wd_e``.
+
+    ``x``: ``[N, H]``. ``w_gate``/``w_up``: ``[count, H, F]``,
+    ``w_down``: ``[count, F, H]`` — the experts ``first .. first +
+    count - 1`` of the model, ``held = (first, count)``. ``ids``/
+    ``weights``: ``[N, K]`` from the router, over ALL the model's
+    experts. ``live``: bool ``[N]``, tokens that count (a free decode
+    slot's row is dropped like a pair held elsewhere).
+
+    The ``N*K`` pairs are sorted by expert, held ones first; then one
+    loop runs over steps of ``_ROWS_PER_STEP`` sorted rows of ONE expert
+    each (``ceil(rows_e / step)`` steps for expert ``e``, none for an
+    expert nobody chose, whose weights are then never read), each three
+    products against that expert's matrices, indexed in place. The trip
+    count is data, every shape is static: one compiled program whatever
+    the routing, and no pair is ever dropped — if every token picks the
+    same held expert the loop just runs longer.
+
+    Returns ``(y [N, H] float32, stats)`` with ``stats`` int32 scalars
+    ``pairs`` (live tokens x K), ``pairs_held``, ``experts_touched``
+    (held experts with at least one row) and ``expert_rows_max`` (the
+    fullest held expert's rows)."""
+    N, K = ids.shape
+    first, count = held
+    H, C, R = x.shape[-1], _ROWS_PER_STEP, N * K
+    e = ids.reshape(R) - first
+    mine = (e >= 0) & (e < count)
+    if live is not None:
+        mine = mine & jnp.repeat(live, K)
+    key = jnp.where(mine, e, count)  # pairs held elsewhere sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
+                    axis=0).astype(jnp.int32)  # rows of each held expert
+    offsets = jnp.cumsum(sizes) - sizes
+    steps = (sizes + C - 1) // C
+    step_end = jnp.cumsum(steps)
+    xs = jnp.pad(x[order // K], ((0, C), (0, 0)))  # a step may overhang
+
+    def one_step(i, ys):
+        ex = jnp.sum(step_end <= i).astype(jnp.int32)  # whose step i is
+        w = i - (step_end[ex] - steps[ex])
+        start = offsets[ex] + w * C
+        rows = lax.dynamic_slice(xs, (start, 0), (C, H))
+        gate = jnp.dot(rows, lax.dynamic_index_in_dim(w_gate, ex, 0, False),
+                       preferred_element_type=jnp.float32)
+        up = jnp.dot(rows, lax.dynamic_index_in_dim(w_up, ex, 0, False),
+                     preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        out = jnp.dot(hidden, lax.dynamic_index_in_dim(w_down, ex, 0, False),
+                      preferred_element_type=jnp.float32)
+        # rows past this expert's own belong to the next expert's step
+        old = lax.dynamic_slice(ys, (start, 0), (C, H))
+        own = (jnp.arange(C) < sizes[ex] - w * C)[:, None]
+        return lax.dynamic_update_slice(ys, jnp.where(own, out, old),
+                                        (start, 0))
+
+    ys = lax.fori_loop(0, step_end[-1], one_step,
+                       jnp.zeros((R + C, H), jnp.float32))
+    # back to token order; a pair held elsewhere reads a row of zeros
+    by_pair = ys[jnp.argsort(order)].reshape(N, K, H)
+    y = jnp.einsum("nkh,nk->nh", by_pair, weights.astype(jnp.float32))
+    pairs = R if live is None else K * jnp.sum(live)
+    stats = {"pairs": jnp.asarray(pairs, jnp.int32),
+             "pairs_held": jnp.sum(sizes),
+             "experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
+             "expert_rows_max": jnp.max(sizes)}
+    return y, stats
 
 
 class MoEMLP(nn.Module):

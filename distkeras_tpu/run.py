@@ -40,6 +40,10 @@ MODEL_ZOO = {
     "bert_base_mlm": ("distkeras_tpu.models.bert", "bert_base_mlm"),
     "gpt_tiny": ("distkeras_tpu.models.bert", "gpt_tiny"),
     "gpt_small": ("distkeras_tpu.models.bert", "gpt_small"),
+    "command_a_plus_tiny": ("distkeras_tpu.models.cohere2_moe",
+                            "command_a_plus_tiny"),
+    "command_a_plus_tp8ep8": ("distkeras_tpu.models.cohere2_moe",
+                              "command_a_plus_tp8ep8"),
 }
 
 
@@ -147,7 +151,9 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
     replica processes behind a supervised router on ``--port``."""
     ap = argparse.ArgumentParser(prog=f"distkeras_tpu.run {prog}")
     ap.add_argument("--model", default="gpt_tiny",
-                    help="causal LM from the zoo (gpt_tiny/gpt_small)")
+                    help="causal LM from the zoo: any MODEL_ZOO entry whose "
+                         "config has a decode module (gpt_tiny, gpt_small, "
+                         "command_a_plus_tiny, command_a_plus_tp8ep8)")
     ap.add_argument("--model-args", default="{}",
                     help="JSON kwargs for the model fn")
     ap.add_argument("--weights", default=None,
@@ -165,6 +171,11 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
                          "tokens, one per decode tick — bounds the decode "
                          "stall (p99 ITL) a long prompt can cause; "
                          "default: monolithic prefill")
+    ap.add_argument("--min-prefill-bucket", type=int, default=8,
+                    help="smallest prefill pad width (a power of two): a "
+                         "prompt, or a long prompt's ragged last chunk, pads "
+                         "to the next power of two at or above it, so it "
+                         "bounds the prefill programs compiled from below")
     ap.add_argument("--pipeline-depth", type=int, default=1,
                     help="decode pipeline depth: 1 (default) dispatches "
                          "tick N+1 before consuming tick N's tokens, so "
@@ -490,6 +501,7 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
         auditor=auditor,
         arm_auditor_after_warmup=args.audit_recompiles == "arm",
         prefill_chunk=args.prefill_chunk,
+        min_prefill_bucket=args.min_prefill_bucket,
         prefix_cache_mb=0.0 if kv_pool_mb else args.prefix_cache_mb,
         prefix_block_tokens=args.prefix_block,
         kv_pool_mb=kv_pool_mb,
@@ -627,6 +639,8 @@ def _serving_config_flags(args) -> list[str]:
         extra += ["--top-k", str(args.top_k)]
     if args.prefill_chunk is not None:
         extra += ["--prefill-chunk", str(args.prefill_chunk)]
+    if getattr(args, "min_prefill_bucket", None) is not None:
+        extra += ["--min-prefill-bucket", str(args.min_prefill_bucket)]
     if getattr(args, "pipeline_depth", None) is not None:
         extra += ["--pipeline-depth", str(args.pipeline_depth)]
     if args.paged or args.kv_pool_mb:
@@ -860,7 +874,9 @@ def deploy_main(argv=None) -> int:
                          "fleet bootstraps on (and publishes) seed-init "
                          "weights as v1")
     ap.add_argument("--model", default="gpt_tiny",
-                    help="causal LM from the zoo (gpt_tiny/gpt_small)")
+                    help="causal LM from the zoo: any MODEL_ZOO entry whose "
+                         "config has a decode module (gpt_tiny, gpt_small, "
+                         "command_a_plus_tiny, command_a_plus_tp8ep8)")
     ap.add_argument("--model-args", default="{}",
                     help="JSON kwargs for the model fn")
     ap.add_argument("--host", default="127.0.0.1")

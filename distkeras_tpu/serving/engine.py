@@ -315,14 +315,28 @@ def _paged_decode_fn(module, top_k, sentinel, params, pools, tokens, temps,
     ticks re-feed the returned vector instead of rebuilding and
     re-uploading a host array every tick. The host re-uploads from its
     ``_lens`` truth only when the dirty flag says the decodable set or
-    a watermark changed — the same gating the block tables use."""
+    a watermark changed — the same gating the block tables use.
+
+    A module that counts what its tick did (``module.tick_counters``
+    names the int32 vector it sows into the ``counters`` collection:
+    routed pairs, experts touched, window positions read) gets a fourth
+    output: the counts appended to the token vector as ONE array, the
+    tick's harvest handle, so they ride on the device-to-host copy the
+    tick already makes while the tokens alone stay on the device as the
+    next tick's input. For every other module the program is the one
+    above, output for output."""
+    counted = bool(getattr(module, "tick_counters", ()))
     logits, mut = module.apply(
         {"params": params, "cache": pools}, tokens[:, None], train=False,
-        mutable=["cache"], positions=positions, block_tables=tables,
+        mutable=["cache", "counters"] if counted else ["cache"],
+        positions=positions, block_tables=tables,
     )
     nxt = sample_rows(logits[:, -1], temps, key, top_k)
     live = (tables[:, 0] != sentinel).astype(positions.dtype)
-    return mut["cache"], nxt, positions + live
+    out = (mut["cache"], nxt, positions + live)
+    if counted:
+        out += (jnp.concatenate([nxt, mut["counters"]["tick"][0]]),)
+    return out
 
 
 def _paged_decode_masked_fn(module, top_k, sentinel, params, pools, tokens,
@@ -1220,18 +1234,17 @@ class ServingEngine:
                 "pool, which is stage-partitioned under pp")
         # Geometry probe: the plain decode-slots config, for the trained
         # context limit and (paged) the per-token KV byte cost.
-        base_module, base_cfg = _decode_module(model, slots=True)
+        _, base_cfg = _decode_module(model, slots=True)
         if mesh is not None and self._tp > 1:
-            bad = [f"{name}={val}" for name, val in (
-                ("num_heads", base_cfg.num_heads),
-                ("mlp_dim", base_cfg.mlp_dim),
-                ("vocab_size", base_cfg.vocab_size),
-            ) if val % self._tp]
+            bad = [f"{name}={val}"
+                   for name, val in base_cfg.tp_split_sizes.items()
+                   if val % self._tp]
             if bad:
                 raise ValueError(
                     f"model {getattr(model, 'name', model)!r} does not "
                     f"shard over tp={self._tp}: {', '.join(bad)} not "
-                    f"divisible — pick a tp that divides all three")
+                    f"divisible — pick a tp that divides all of "
+                    f"{', '.join(base_cfg.tp_split_sizes)}")
         base_limit = _context_limit(model, base_cfg)
         if max_context is not None:
             if not 1 <= max_context <= base_limit:
@@ -1242,14 +1255,6 @@ class ServingEngine:
         else:
             self.limit = base_limit
         if self._paged:
-            # Per-token KV byte cost from the UNCAPPED row geometry (the
-            # paged module's own cache leaves are pool-shaped, not
-            # row-shaped, so the budget math needs the dense twin).
-            row_shapes = jax.eval_shape(
-                lambda r: base_module.init(
-                    r, jnp.zeros((1, 1), jnp.int32), train=False),
-                jax.random.PRNGKey(0),
-            )["cache"]
             if prefix_cache is not None:
                 raise ValueError(
                     "paged mode subsumes the prefix cache (the KV pool "
@@ -1259,11 +1264,9 @@ class ServingEngine:
                     f"kv_block_tokens must be >= 1, got {kv_block_tokens}")
             bt = int(kv_block_tokens)
             table_blocks = -(-self.limit // bt)
-            kv_leaves = [a for a in jax.tree.leaves(row_shapes)
-                         if a.ndim > 1]
-            bytes_per_block = sum(
-                bt * int(np.prod(a.shape[2:])) * a.dtype.itemsize
-                for a in kv_leaves)
+            # Per-token KV byte cost: the model's own statement (K and V
+            # of one token through every layer, as the pool holds them).
+            bytes_per_block = bt * base_cfg.kv_token_bytes
             if kv_pool_blocks is not None:
                 capacity = int(kv_pool_blocks)
             else:
@@ -1752,6 +1755,13 @@ class ServingEngine:
         rep = self._replicated
         psh = self._param_shardings
         csh = self._cache_shardings
+        # What the module's plain paged decode tick counts, by name (none
+        # for most models): harvested with the tokens, published as
+        # ``<name>_total`` (ServingMetrics.record_tick_counters).
+        self._tick_counters = tuple(
+            getattr(self._module, "tick_counters", ())
+            if self._paged and self._pp == 1 and not self._constrained_mode
+            else ())
         if self._pp > 1:
             self._build_pp_programs(top_k, auditor)
         elif self._paged:
@@ -1780,7 +1790,8 @@ class ServingEngine:
                     "serving_decode",
                     functools.partial(_paged_decode_fn, self._module,
                                       top_k, self._sentinel),
-                    (psh, csh, rep, rep, rep, rep, rep), (csh, rep, rep),
+                    (psh, csh, rep, rep, rep, rep, rep),
+                    (csh, rep, rep) + (rep,) * bool(self._tick_counters),
                     donate=(1,))
             # Request-kind programs (PR 19). _prefill_logits is the
             # final-chunk prefill that hands the logits row back (fork
@@ -4581,6 +4592,7 @@ class ServingEngine:
             return self._pp_decode_dispatch()
         self._key, sub = jax.random.split(self._key)
         rows = tuple(self._decodable())
+        harvest = ()
         if self._paged:
             tables_dev = self._upload_tables(rows)
             if self._positions_dirty or self._positions_dev is None:
@@ -4608,7 +4620,9 @@ class ServingEngine:
                         self._temps, self._positions_dev, tables_dev,
                         mask_dev, sub))
             else:
-                self._cache, self._tokens, self._positions_dev = (
+                # (a counting module's step hands back a fourth array,
+                # tokens + counts: the handle the harvest reads)
+                self._cache, self._tokens, self._positions_dev, *harvest = (
                     self._decode_step(
                         self._params, self._cache, self._tokens,
                         self._temps, self._positions_dev, tables_dev,
@@ -4625,7 +4639,8 @@ class ServingEngine:
                     self._spec_pos[i] += 1
         t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="decode", rows=rows, t_dispatch=t,
-                             tokens=self._tokens, advanced=set(rows))
+                             tokens=harvest[0] if harvest else self._tokens,
+                             advanced=set(rows))
 
     def _to_stage(self, x, s):
         """Place a cross-stage value on stage ``s``'s replicated
@@ -4707,6 +4722,10 @@ class ServingEngine:
         hg.harvest_started()
         nxt = np.asarray(tick.tokens)
         t = hg.harvest_ended()
+        if len(nxt) > self.slots:  # tokens, then the tick's counts
+            self.metrics.record_tick_counters(self._tick_counters,
+                                              nxt[self.slots:])
+            nxt = nxt[:self.slots]
         if self._pp > 1:
             self.metrics.bubble.record(tick.t_dispatch, t, self._pp)
         self._tick_log.append({

@@ -808,6 +808,20 @@ class ServingMetrics:
         records diff this around a request's lifetime)."""
         return self._iterations
 
+    def record_tick_counters(self, names, values) -> None:
+        """What a counting model's decode tick did (its module's
+        ``tick_counters``: routed pairs, experts touched, window
+        positions read), harvested with the tick's tokens: each name is
+        the counter ``<name>_total`` (the registry gets or makes it);
+        ``serving_counted_ticks_total`` counts the ticks they are sums
+        over."""
+        for name, value in zip(("serving_counted_ticks", *names),
+                               (1, *values)):
+            self.registry.counter(
+                f"{name}_total",
+                help=f"{name.replace('_', ' ')}, summed over layers and "
+                     "plain decode ticks (live rows)").inc(int(value))
+
     def record_barrier(self, reason: str) -> None:
         """One pipeline barrier that drained a tick (``BARRIER_REASONS``)."""
         self._c_barriers[reason].inc()

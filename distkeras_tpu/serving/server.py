@@ -96,8 +96,12 @@ class ServingServer:
 
     async def start(self) -> None:
         self._engine_task = asyncio.create_task(self.engine.run())
+        # A request line may be as long as a frame may be: a 14 k-token
+        # prompt is ~100 KB of JSON, past StreamReader's 64 KB default
+        # (which dropped the connection without an answer).
         self._server = await asyncio.start_server(
-            self._handle, self.host, self._requested_port)
+            self._handle, self.host, self._requested_port,
+            limit=wire.MAX_FRAME)
 
     async def serve_forever(self) -> None:
         if self._server is None:

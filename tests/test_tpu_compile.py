@@ -225,3 +225,82 @@ def test_paged_programs_touch_the_pool_in_place_on_v5e(topo, which, tp, vocab,
     assert len(made["fusion"]) == len(made["scatter"]) == len(leaves)
     # (c) and the program's temporaries are smaller than one leaf
     assert memory.temp_size_in_bytes < leaf_bytes
+
+
+# -- the same programs for the second block, at command-a-plus-tp8ep8's flags --
+#
+# `run serve --model command_a_plus_tp8ep8 --paged --kv-block-tokens 16
+# --slots 64 --kv-pool-mb 2048 --max-context 16384 --prefill-chunk 512`:
+# 8 pool leaves of 65,536 blocks, each [C, 16, 128] (one K/V head is one
+# lane tile), a block table of 1,024 entries a row.
+_MOE_CONTEXT, _MOE_POOL_BLOCKS, _MOE_WINDOW = 16384, 65536, 4096
+
+
+def _moe_paged_program(topo, which):
+    from distkeras_tpu.inference.generate import _decode_module
+    from distkeras_tpu.models.cohere2_moe import command_a_plus_tp8ep8
+    from distkeras_tpu.serving import engine
+
+    table = _MOE_CONTEXT // _BLOCK_TOKENS
+    module, cfg = _decode_module(
+        command_a_plus_tp8ep8(), slots=True, paged_blocks=_MOE_POOL_BLOCKS,
+        page_tokens=_BLOCK_TOKENS, page_table_blocks=table)
+    assert cfg.sliding_window == _MOE_WINDOW
+    abstract = jax.eval_shape(
+        lambda r: module.init(r, jnp.zeros((_SLOTS, 1), jnp.int32),
+                              train=False), jax.random.PRNGKey(0))
+    rep = SingleDeviceSharding(topo.devices[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    key = arg(jnp.uint32, 2)
+    if which == "decode":
+        fn = functools.partial(engine._paged_decode_fn, module, 0,
+                               _MOE_POOL_BLOCKS)
+        args = (arg(jnp.int32, _SLOTS), arg(jnp.float32, _SLOTS),
+                arg(jnp.int32, _SLOTS), arg(jnp.int32, _SLOTS, table), key)
+    else:  # one prefill chunk of 512 tokens
+        fn = functools.partial(engine._paged_prefill_fn, module, 0)
+        args = (arg(jnp.int32, 1, 512), arg(jnp.int32), arg(jnp.int32),
+                arg(jnp.int32, table), arg(jnp.float32), key)
+    leaves = [a for a in jax.tree.leaves(abstract["cache"]) if a.ndim > 1]
+    return (jax.jit(fn, donate_argnums=(1,)),
+            (placed(abstract["params"]), placed(abstract["cache"])) + args,
+            leaves)
+
+
+@pytest.mark.parametrize("which,rows,queries", [("decode", _SLOTS, 1),
+                                                ("prefill", 1, 512)])
+def test_window_layers_gather_their_window_and_no_more_on_v5e(topo, which,
+                                                              rows, queries):
+    """In the compiled step the three window layers gather the block-table
+    entries that cover their window (4096 / 16 + 1 = 257 a decode row; 289
+    for a chunk of 512 queries), the full layer all 1,024; the donated pool
+    is written in place; parameters are bfloat16 (8.47 GB) and the program
+    fits the chip."""
+    from distkeras_tpu.ops.attention import window_table_entries
+
+    jitted, args, leaves = _moe_paged_program(topo, which)
+    assert len(leaves) == 8 and {l.shape for l in leaves} == {
+        (_MOE_POOL_BLOCKS, _BLOCK_TOKENS, 128)}
+    params = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                 for a in jax.tree.leaves(args[0]))
+    assert round(params / 1e9, 2) == 8.47
+    compiled = jitted.lower(*args).compile()
+    memory = compiled.memory_analysis()
+    leaf_bytes = int(np.prod(leaves[0].shape)) * leaves[0].dtype.itemsize
+    assert memory.alias_size_in_bytes >= len(leaves) * leaf_bytes
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 13e9)  # of the chip's 16 GB
+    entries = window_table_entries(_MOE_WINDOW, queries, _BLOCK_TOKENS)
+    assert entries == {1: 257, 512: 289}[queries]
+    lead = f"{rows}," if rows > 1 else ""
+    gathered = re.findall(r"= bf16\[([\d,]+),16,128\]\S* gather\(",
+                          compiled.as_text())
+    assert sorted(gathered) == sorted(
+        [f"{lead}{entries}"] * 6 + [f"{lead}{_MOE_CONTEXT // _BLOCK_TOKENS}"] * 2)
