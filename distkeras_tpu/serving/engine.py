@@ -130,9 +130,15 @@ Pipelined, all of that runs while the device executes the next tick:
   is still going to read (16 bytes per tick of extra alloc, nothing);
 - a **pipeline barrier** (harvest + stream + teardown of the in-flight
   tick) runs only at the events that change batch shape or content
-  mid-flight: admission, chunked-prefill progress, paged growth /
-  preemption, param swap (a swap still waits for zero in-flight
-  ticks), KV transfer, cancel/expire teardown, and engine idle/exit;
+  mid-flight: admission, chunked-prefill progress, paged growth from a
+  DRY pool (it preempts, or evicts with a spill to the host tier),
+  param swap (a swap still waits for zero in-flight ticks), KV
+  transfer, cancel/expire teardown, and engine idle/exit;
+- paged growth the pool can serve from its free list (or by evicting
+  an unreferenced trie leaf with no host tier) is NOT such an event:
+  the block is chained into the host table with the tick in flight,
+  which holds its own device copy of the tables, and the next dispatch
+  re-uploads (step 5c of :meth:`ServingEngine.run`);
 - a slot that FINISHES at tick N is detected at N's harvest — after
   N+1 was dispatched, so the in-flight tick ran one speculative row
   for it. Its N+1 output is dropped exactly like a mid-prefill
@@ -156,8 +162,11 @@ is counted: every barrier that found a tick in flight adds to
 ``serving_pipeline_barriers_total{reason}`` and is a ``barrier`` span
 (on the device's clock whenever a profiler session is live, see
 :mod:`distkeras_tpu.telemetry.spans`). A paged engine with many rows
-drains on almost every tick — some row crosses a block boundary — so
-its overlap is mostly lost to ``paged_growth`` (PERF.md, PR 25).
+has some row crossing a block boundary on almost every tick; until
+PR 30 each such tick drained, and the overlap was mostly lost to
+``paged_growth``. ``serving_paged_growth_blocks_total{drained}`` now
+says how many blocks were chained with the tick left in flight
+(``0``) against behind the barrier (``1``: a dry or spilling pool).
 """
 
 from __future__ import annotations
@@ -1550,8 +1559,9 @@ class ServingEngine:
             # serving/kv_tier.py. The spill hook fires inside
             # _BlockTrie._alloc, which only runs on the engine loop (or
             # the executor while the loop awaits it) and always after a
-            # pipeline barrier, so the gather never races a donated
-            # in-flight tick.
+            # pipeline barrier (in-flight growth asks the pool's
+            # next_alloc_spills first), so the gather never races a
+            # donated in-flight tick.
             if kv_host_tier_mb > 0:
                 from distkeras_tpu.serving.kv_tier import HostKVTier
 
@@ -3133,8 +3143,10 @@ class ServingEngine:
         into the host tier as exact KVX1 bytes, keyed by its full
         root→block token chain. Runs inside ``_BlockTrie._alloc`` —
         always on the engine loop, or on the executor while the loop
-        awaits it, and always after a pipeline barrier, so the gather
-        cannot race a donated in-flight tick. The payload is the same
+        awaits it, and always after a pipeline barrier (growth with a
+        tick in flight refuses an allocation that would spill:
+        :meth:`_chain_tail_block`), so the gather cannot race a donated
+        in-flight tick. The payload is the same
         serialization a peer transfer ships, so a spilled block is
         re-admittable locally AND exportable to the fleet."""
         tier = self.kv_tier
@@ -3767,26 +3779,27 @@ class ServingEngine:
                     break
                 # 5c. Paged growth: before the tick, every decoding slot
                 # whose next write position crosses into an unallocated
-                # block chains one more from the pool — preempting the
-                # lowest-priority youngest slot (possibly itself) when
-                # the pool is dry. Host bookkeeping only; the decode
-                # step itself never changes shape. Growth mutates table
-                # rows (and may preempt = tear down): barrier first, but
-                # ONLY when some slot actually needs a block — a tick
-                # on which no row crosses a block boundary keeps the
-                # pipeline full (with many rows that is the rare tick:
-                # 64 rows on 16-token blocks leave 1.6 % of them, and
-                # serving_pipeline_barriers_total counts the rest).
-                if self._paged and any(
-                        st is not None and st.prefill is None
-                        and self._needs_tail_block(i)
-                        for i, st in enumerate(self._slot_state)):
-                    await self._pipeline_barrier(loop, "paged_growth")
-                    with span("paged_growth"):
-                        for i in range(self.slots):
-                            st = self._slot_state[i]
-                            if st is not None and st.prefill is None:
-                                self._ensure_tail_block(i)
+                # block chains one more from the pool. Host bookkeeping
+                # only; the decode step itself never changes shape. With
+                # many rows some row is at a boundary on nearly every
+                # tick (64 rows on 16-token blocks: four a tick), so
+                # growth must not cost the overlap:
+                #   (a) a block the pool hands out with no victim and no
+                #       device work is chained with the tick IN FLIGHT.
+                #       That tick holds its own device copy of the
+                #       tables and positions, the next dispatch
+                #       re-uploads both from host state the dispatch
+                #       already advanced, and no live row's table names
+                #       a block alloc() can return;
+                #   (b) only for the rows a dry or spilling pool refused
+                #       (_chain_tail_block says which case is which) is
+                #       the barrier kept: preemption, a wedged row's
+                #       error and a spill all need harvested state.
+                if self._paged:
+                    dry = self._grow_in_flight()
+                    if dry:
+                        await self._pipeline_barrier(loop, "paged_growth")
+                        self._grow_drained(dry)
                 # 6. One decode iteration for the whole batch — skipped
                 # while EVERY active slot is still mid-prefill (the whole
                 # tick's output would be discarded; the chunk in 4b was
@@ -3964,8 +3977,10 @@ class ServingEngine:
         """Drain the pipeline: harvest, stream, and tear down the
         in-flight tick (if any). Called before every event that mutates
         batch shape or content — admission, chunked-prefill progress,
-        paged growth/preemption, param swap, KV transfer, cancel/expire
-        teardown, idle, shutdown — and as the depth-0 serializer. Under
+        paged growth that must preempt or spill (NOT growth the pool
+        serves from its free list: :meth:`_chain_tail_block`), param
+        swap, KV transfer, cancel/expire teardown, idle, shutdown — and
+        as the depth-0 serializer. Under
         depth>1 this drains ALL stages' in-flight micro-batch ticks,
         oldest first. ``reason`` is one of ``BARRIER_REASONS``; a barrier
         that found a tick to drain (the overlap was lost) is counted under
@@ -4024,9 +4039,22 @@ class ServingEngine:
                         # advanced this slot's watermark; the request is
                         # finished, so roll every such advance back
                         # BEFORE adoption — the trie must never claim an
-                        # in-flight speculative write (its block is
-                        # freed instead, and the write lands before any
-                        # barrier-gated reuse can touch it).
+                        # in-flight speculative write; its block is
+                        # freed instead. Growth may hand that block to
+                        # another row for the NEXT tick with no barrier
+                        # between (step 5c), and the stale write is
+                        # still harmless: programs run on the device in
+                        # dispatch order, so it lands first; the new
+                        # owner starts at the block's offset 0 and
+                        # attention masks every position past a row's
+                        # length, so the stale offset is overwritten
+                        # before it is ever read; and the trie adopts
+                        # complete blocks only, every offset of which
+                        # its owner wrote. (Likewise a row that already
+                        # finished INSIDE the in-flight tick may be
+                        # given a tail block it never uses: teardown
+                        # frees it past the adopt watermark, like a
+                        # speculating row's unused lookahead block.)
                         for later in self._inflight:
                             if i in later.advanced:
                                 self._lens[i] -= 1
@@ -5030,16 +5058,86 @@ class ServingEngine:
         return (blk < self._table_blocks
                 and self._tables[i, blk] == self._sentinel)
 
+    def _chain(self, i: int, ids) -> None:
+        """Point slot ``i``'s next write position at the fresh block
+        ``ids`` (one row of the pool) and mark the device views stale."""
+        blk = int(self._lens[i]) // self.kv_block_tokens
+        self._tables[i, blk] = ids[0]
+        self._slot_state[i].blocks.extend(ids)
+        self._mark_tables_dirty()
+
+    def _chain_tail_block(self, i: int) -> bool:
+        """The half of growth that is safe with a tick in flight: chain
+        one block when the pool gives it with NO victim and NO device
+        work, else touch nothing and return False (the caller drains the
+        pipeline and runs :meth:`_ensure_tail_block`). Which case is
+        which, by what :meth:`KVBlockPool.alloc` would do:
+
+        - a row off the free list, or an unreferenced trie leaf evicted
+          with no host tier attached: host bookkeeping only — chained;
+        - a leaf evicted with a tier attached (``next_alloc_spills``):
+          the spill hook (:meth:`_spill_blocks`) gathers the victim's
+          rows from ``self._cache``, the in-flight tick's output, and
+          waits for them on the host — refused, left to the barrier;
+        - nothing free and nothing evictable (``alloc`` gives None):
+          growth has to preempt a slot or error the wedged row, both of
+          which tear down state only a harvest settles — refused."""
+        pool = self.kv_pool
+        if pool.next_alloc_spills:
+            return False
+        ids = pool.alloc(1)
+        if ids is None:
+            return False
+        self._chain(i, ids)
+        return True
+
+    def _grow_in_flight(self) -> list[int]:
+        """Step 5c's first pass, with whatever tick is in flight left
+        alone: chain a tail block to every decoding row that needs one
+        and that :meth:`_chain_tail_block` can serve; return the rows it
+        could not (empty on all but a dry or spilling pool). ``alloc``
+        never frees, so once one row is refused every later one is: the
+        refused rows are a suffix, and the pool sees this pass and the
+        drained one allocate in slot order, as a depth-0 loop does."""
+        # (the decodable rows: a fork child still waiting for its
+        # parent's prefill has nothing to write, and the block growth
+        # used to give it was lost when the fan-out rebuilt its table)
+        growing = [i for i in self._decodable()
+                   if self._needs_tail_block(i)]
+        if not growing:
+            return growing
+        with span("paged_growth", blocks=len(growing), drained=0):
+            dry = [i for i in growing if not self._chain_tail_block(i)]
+        self.metrics.record_paged_growth(len(growing) - len(dry),
+                                         drained=False)
+        return dry
+
+    def _grow_drained(self, dry: list[int]) -> None:
+        """Step 5c's second pass, behind the ``paged_growth`` barrier:
+        today's preempting growth for the rows the first pass left. The
+        barrier may have FINISHED some of them (and freed blocks), and an
+        earlier row's preemption may have taken a later one: re-check,
+        as step 2 of the loop does for ``dead``."""
+        chained = 0
+        with span("paged_growth", blocks=len(dry), drained=1):
+            for i in dry:
+                if (self._slot_state[i] is not None
+                        and self._needs_tail_block(i)
+                        and self._ensure_tail_block(i)):
+                    chained += 1
+        self.metrics.record_paged_growth(chained, drained=True)
+
     def _ensure_tail_block(self, i: int) -> bool:
-        """Pre-tick growth: make sure slot ``i``'s next write position
-        has a block, preempting the lowest-priority youngest slot —
-        itself included — when the pool is dry. Returns False when slot
-        ``i`` itself was the fairest victim (it is gone; the tick runs
-        without it)."""
+        """Pre-tick growth behind the barrier: make sure slot ``i``'s
+        next write position has a block, preempting the lowest-priority
+        youngest slot — itself included — when the pool is dry. Returns
+        False when slot ``i`` itself was the fairest victim (it is gone;
+        the tick runs without it). Never called with a tick in flight:
+        preemption adopts the victim's blocks by its harvested length,
+        and an eviction may spill (see :meth:`_chain_tail_block`)."""
         st = self._slot_state[i]
         if not self._needs_tail_block(i):
             return True
-        blk = int(self._lens[i]) // self.kv_block_tokens
         ids = self.kv_pool.alloc(1)
         while ids is None:
             victims = [(j, s) for j, s in enumerate(self._slot_state)
@@ -5065,9 +5163,7 @@ class ServingEngine:
             if j == i:
                 return False
             ids = self.kv_pool.alloc(1)
-        self._tables[i, blk] = ids[0]
-        st.blocks.extend(ids)
-        self._mark_tables_dirty()
+        self._chain(i, ids)
         return True
 
     def _preempt_slot(self, i: int) -> None:

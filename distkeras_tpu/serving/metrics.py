@@ -286,6 +286,17 @@ class ServingMetrics:
                      "drained it (the overlap with the next tick was lost)",
                 reason=reason)
             for reason in BARRIER_REASONS}
+        # Tail blocks chained by the loop's paged growth, by whether the
+        # pipeline was first drained for them (a dry or spilling pool).
+        self._c_growth = {
+            drained: reg.counter(
+                "serving_paged_growth_blocks_total",
+                help="tail blocks chained to decoding rows: drained=0 "
+                     "with the tick in flight left alone, drained=1 "
+                     "behind the paged_growth barrier (pool dry, or an "
+                     "eviction that spills to the host tier)",
+                drained=str(int(drained)))
+            for drained in (False, True)}
         self._h = {
             "ttft": reg.histogram(
                 "serving_ttft_seconds", help="time to first token",
@@ -825,6 +836,10 @@ class ServingMetrics:
     def record_barrier(self, reason: str) -> None:
         """One pipeline barrier that drained a tick (``BARRIER_REASONS``)."""
         self._c_barriers[reason].inc()
+
+    def record_paged_growth(self, blocks: int, drained: bool) -> None:
+        """``blocks`` tail blocks chained by one pass of paged growth."""
+        self._c_growth[drained].inc(blocks)
 
     # -- per-iteration sampling --------------------------------------------
     def sample(self, queue_depth: int, slots_active: int, slots_total: int,

@@ -102,8 +102,9 @@ def test_pipelined_engine_matches_generate_slots1(tiny_lm, mode):
 
 def test_pipeline_parity_paged_preempt_resume(tiny_lm):
     """An oversubscribed pool (preempt + requeue + resume) stays
-    token-identical under the pipelined loop: growth/preemption are
-    barriers, so the round trip always sees fully-harvested state."""
+    token-identical under the pipelined loop: growth that has to preempt
+    is a barrier (growth the pool can serve is not, since PR 30), so the
+    round trip always sees fully-harvested state."""
     model, variables = tiny_lm
     prompts = _prompts(6, seed=7, lo=8, hi=16)
     new_tokens = 12
@@ -397,7 +398,12 @@ def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
     assert snap["kv_pool_block_ticks_total"]["value"] == sum(blocks)
     assert snap["serving_decode_iterations_total"]["value"] == len(blocks)
     assert set(barriers) <= set(BARRIER_REASONS)
-    assert {"admission", "paged_growth"} <= set(barriers)
+    # admission always drains; growth only from a dry pool, and 24 blocks
+    # are roomy (the dry case: test_paged_growth_from_a_dry_pool_...)
+    assert "admission" in barriers and "paged_growth" not in barriers
+    grown = snap["serving_paged_growth_blocks_total{drained=0}"]["value"]
+    assert grown > 0
+    assert snap["serving_paged_growth_blocks_total{drained=1}"]["value"] == 0
     for reason in BARRIER_REASONS:
         key = "serving_pipeline_barriers_total{reason=%s}" % reason
         assert snap[key]["value"] == barriers.count(reason), reason
@@ -405,6 +411,9 @@ def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
               in tracer.events() if ph == "B"]
     spans = [attrs["reason"] for name, attrs in begins if name == "barrier"]
     assert sorted(spans) == sorted(barriers)
+    growth = [attrs for name, attrs in begins if name == "paged_growth"]
+    assert growth and all(a["drained"] == 0 for a in growth)
+    assert sum(a["blocks"] for a in growth) == grown
     harvests = [attrs for name, attrs in begins if name == "harvest"]
     assert len(harvests) == len(rows)
     assert all(a["kind"] == "decode" and a["ready"] in (True, False)
@@ -412,3 +421,285 @@ def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
     assert any(name == "loop_idle" for name, _ in begins)
     with pytest.raises(KeyError):
         engine.metrics.record_barrier("because")
+
+
+# -- paged growth with the tick in flight (PR 30) ----------------------------
+
+def _watch(engine):
+    """Counting wrappers at the places the engine counts: the barriers
+    that found a tick in flight, by reason, and every tail block chained,
+    as ``(slot, block, rows of the newest tick in flight or None)``."""
+    barriers, chained = [], []
+    barrier, chain = engine._pipeline_barrier, engine._chain
+
+    def counting_barrier(loop, reason):
+        if engine._inflight:
+            barriers.append(reason)
+        return barrier(loop, reason)
+
+    def counting_chain(i, ids):
+        chained.append((i, int(ids[0]), engine._inflight[-1].rows
+                        if engine._inflight else None))
+        return chain(i, ids)
+
+    engine._pipeline_barrier = counting_barrier
+    engine._chain = counting_chain
+    return barriers, chained
+
+
+def _growth_counts(engine):
+    snap = engine.metrics.registry.snapshot()
+    return tuple(
+        snap["serving_paged_growth_blocks_total{drained=%d}" % d]["value"]
+        for d in (0, 1))
+
+
+def _generate(tiny_lm, prompt, new_tokens):
+    model, variables = tiny_lm
+    return generate(model, variables, np.asarray([prompt], np.int32),
+                    new_tokens, greedy=True)[0].tolist()
+
+
+def test_paged_growth_keeps_the_tick_in_flight_under_a_roomy_pool(tiny_lm):
+    """Four rows on 4-token blocks cross a block boundary on most ticks.
+    With a roomy pool no ``paged_growth`` barrier finds a tick to drain:
+    every tail block is chained with the tick in flight left alone, the
+    counter says how many, and the streams are those of depth 0 and of
+    generate()."""
+    model, variables = tiny_lm
+    prompts = _prompts(4, seed=31, lo=3, hi=9)
+    new_tokens = 18
+    got = {}
+    for depth in (0, 1):
+        engine = ServingEngine(
+            model, variables, slots=4, pipeline_depth=depth,
+            kv_pool_blocks=64, kv_block_tokens=4,
+            auditor=RecompileAuditor(), arm_auditor_after_warmup=True)
+        barriers, chained = _watch(engine)
+        got[depth] = _run_engine(engine, prompts, new_tokens)
+        assert engine.auditor.compiles("serving_decode") == 1
+        assert "paged_growth" not in barriers
+        # every row grows by ceil-ish(18 / 4) blocks past its prompt's
+        assert len(chained) >= 4 * (new_tokens // 4 - 1)
+        assert _growth_counts(engine) == (len(chained), 0)
+        in_flight = [c for c in chained if c[2] is not None]
+        if depth:
+            # the mechanism engaged: nearly all growth met a tick in
+            # flight (the first tick after the admissions' barrier has
+            # none), and the grown row rode in that tick
+            assert len(in_flight) >= len(chained) - 4
+            assert all(i in rows for i, _blk, rows in in_flight)
+        else:
+            assert not in_flight
+    assert got[0] == got[1]
+    for p, toks in zip(prompts, got[1]):
+        assert toks == _generate(tiny_lm, p, new_tokens)
+
+
+def test_paged_growth_from_a_dry_pool_drains_before_it_preempts(tiny_lm):
+    """The oversubscribed pool of the preempt-and-resume parity test: a
+    row that cannot be served without a victim still drains the pipeline
+    first. No preemption, and no call of the preempting growth at all,
+    ever sees a tick in flight; ``drained=1`` counts its blocks; parity
+    with depth 0 and generate() holds."""
+    # (seed 8: twice the victim is the OTHER row, so the wedged row is
+    # then given its block behind the barrier; seed 7's rows always
+    # preempt themselves)
+    prompts = _prompts(6, seed=8, lo=8, hi=16)
+    new_tokens = 12
+    got, preempted = {}, {}
+    for depth in (0, 1):
+        engine = _engine(tiny_lm, depth, kv_pool_blocks=7,
+                         kv_block_tokens=4)
+        barriers, chained = _watch(engine)
+        calls = []
+        for name in ("_preempt_slot", "_ensure_tail_block"):
+            def wrapped(i, _fn=getattr(engine, name), _name=name):
+                calls.append((_name, bool(engine._inflight)))
+                return _fn(i)
+            setattr(engine, name, wrapped)
+        got[depth] = _run_engine(engine, prompts, new_tokens)
+        assert engine.auditor.compiles("serving_decode") == 1
+        preempted[depth] = sum(n == "_preempt_slot" for n, _ in calls)
+        assert preempted[depth] > 0
+        assert not any(inflight for _name, inflight in calls)
+        undrained, drained = _growth_counts(engine)
+        assert drained > 0 and undrained + drained == len(chained)
+        if depth:
+            assert barriers.count("paged_growth") > 0
+            assert engine.metrics.registry.snapshot()[
+                "serving_pipeline_barriers_total{reason=paged_growth}"][
+                    "value"] == barriers.count("paged_growth")
+    assert got[0] == got[1]
+    for p, toks in zip(prompts, got[1]):
+        assert toks == _generate(tiny_lm, p, new_tokens)
+
+
+def test_block_freed_by_a_finish_is_handed_on_with_its_stale_write_in_flight(
+        tiny_lm):
+    """The reuse invariant. Row A (5 + 6 tokens) finishes at tick 5; tick
+    6, dispatched before that harvest, writes A's garbage K/V at offset 2
+    of A's partial tail block, which the harvest frees. On the next
+    iteration row B (6-token prompt) reaches position 12 and needs a
+    block, the pool of 6 has exactly that one free, and growth chains it
+    with tick 6 still in flight. B then writes offsets 0..3 of it in
+    later ticks and reads none before it wrote it: its stream is
+    generate()'s, the pool ends holding B's adopted chain only, and the
+    decode step compiled once."""
+    a, b = _prompts(1, seed=41, lo=5, hi=6)[0], _prompts(
+        1, seed=43, lo=6, hi=7)[0]
+    new_a, new_b = 6, 16
+    got = {}
+    for depth in (0, 1):
+        engine = _engine(tiny_lm, depth, kv_pool_blocks=6,
+                         kv_block_tokens=4)
+        pool = engine.kv_pool
+        barriers, chained = _watch(engine)
+        freed, free = [], pool.free
+
+        def recording_free(ids):
+            freed.append(([int(i) for i in ids], len(chained)))
+            return free(ids)
+
+        pool.free = recording_free
+
+        async def main():
+            task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+            reqs = [engine.submit(a, new_a), engine.submit(b, new_b)]
+            outs = [await r.result() for r in reqs]
+            engine.shutdown(drain=True)
+            await task
+            return outs
+
+        got[depth] = asyncio.run(main())
+        assert engine.auditor.compiles("serving_decode") == 1
+        assert "paged_growth" not in barriers
+        assert _growth_counts(engine) == (len(chained), 0)
+        # B's adopted chain and nothing else: A's two adopted blocks were
+        # evicted for B's last two, A's tail and B's tail were freed
+        assert pool.blocks_used == (len(b) + new_b - 1) // 4 == 5
+        if depth:
+            # A's teardown freed one block (its partial tail) ...
+            (a_tail,), at = next(f for f in freed if f[0])
+            # ... and the very next block chained is that one, to B (slot
+            # 1), while the tick in flight still carries A's row (slot 0)
+            slot, block, rows = chained[at]
+            assert (slot, block) == (1, a_tail)
+            assert rows is not None and 0 in rows and 1 in rows
+    assert got[0] == got[1]
+    assert got[1] == [_generate(tiny_lm, a, new_a),
+                      _generate(tiny_lm, b, new_b)]
+
+
+def test_growth_that_would_spill_to_the_host_tier_drains_first(tiny_lm):
+    """With a host tier attached an allocation from an empty free list
+    evicts a trie leaf WITH a spill: a D2H gather from the engine's
+    cache, which with a tick in flight is that tick's output. Such
+    growth keeps its barrier: every spill runs with nothing in flight,
+    its blocks count under ``drained=1``, and the stream is generate()'s."""
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=1, pipeline_depth=1,
+                           kv_pool_blocks=5, kv_block_tokens=4,
+                           kv_host_tier_mb=4.0)
+    barriers, chained = _watch(engine)
+    spills = []
+    for name in ("_spill_block", "_spill_blocks"):
+        def wrapped(*args, _fn=getattr(engine, name)):
+            spills.append(bool(engine._inflight))
+            return _fn(*args)
+        setattr(engine, name, wrapped)
+    engine.kv_pool.spill_hook = engine._spill_block
+    engine.kv_pool.spill_many_hook = engine._spill_blocks
+    first, second = _prompts(1, seed=51, lo=8, hi=9)[0], _prompts(
+        1, seed=53, lo=6, hi=7)[0]
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        # 8 + 5 tokens leave three adopted leaves; the second request
+        # takes the two free blocks at admission and grows twice
+        outs = [await engine.submit(first, 5).result(),
+                await engine.submit(second, 8).result()]
+        engine.shutdown(drain=True)
+        await task
+        return outs
+
+    outs = asyncio.run(main())
+    assert outs == [_generate(tiny_lm, first, 5),
+                    _generate(tiny_lm, second, 8)]
+    assert spills and not any(spills)
+    assert engine.kv_tier.stats()["host_entries"] >= 2
+    assert barriers.count("paged_growth") == 2
+    undrained, drained = _growth_counts(engine)
+    assert drained == 2 and undrained + drained == len(chained)
+
+
+def test_wedged_fork_row_errors_behind_the_barrier(tiny_lm):
+    """Only fork rows resident and the pool dry: growth has no victim to
+    preempt and errors the wedged row's request typed, tearing its fork
+    group down. That too waits for the barrier: the teardown sees no
+    tick in flight, and the pool gets every block back."""
+    from distkeras_tpu.serving.scheduler import PoolExhausted
+
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=2, pipeline_depth=1,
+                           kv_pool_blocks=9, kv_block_tokens=4)
+    # submit() refuses what the pool could never hold (7 blocks here), so
+    # the pool is made dry as a decode replica sees it: rows held outside
+    held = engine.kv_pool.alloc(3)
+    barriers, _chained = _watch(engine)
+    torn, teardown = [], engine._teardown_fork
+
+    def watching_teardown(req):
+        torn.append(bool(engine._inflight))
+        return teardown(req)
+
+    engine._teardown_fork = watching_teardown
+    prompt = _prompts(1, seed=61, lo=5, hi=6)[0]
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        # two rows of 5 + 11 positions want 1 shared + 2 x 3 blocks of 6
+        req = engine.submit(prompt, 12, kind="sample", n=2,
+                            temperature=1.0)
+        with pytest.raises(PoolExhausted):
+            await req.result()
+        engine.shutdown(drain=True)
+        await task
+
+    asyncio.run(main())
+    assert torn == [False]
+    assert "paged_growth" in barriers
+    assert engine.metrics.oom_rejections == 1
+    engine.kv_pool.free(held)
+    engine.kv_pool.flush()
+    assert engine.kv_pool.blocks_free == engine.kv_pool.capacity
+
+
+def test_fork_child_waiting_for_its_parent_is_given_no_block(tiny_lm):
+    """A ``sample`` request's child rows hold their slots (``fork_wait``)
+    while the parent prefills in chunks and another row keeps the decode
+    step running. Growth used to chain a block to each waiting child,
+    which the fan-out then dropped from its table without freeing. Every
+    block in use afterwards is a node of the trie."""
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=4, pipeline_depth=1,
+                           kv_pool_blocks=64, kv_block_tokens=4,
+                           prefill_chunk=4)
+    prompt = _prompts(1, seed=71, lo=17, hi=18)[0]
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        other = engine.submit([3, 4, 5], 30)
+        fork = engine.submit(prompt, 6, kind="sample", n=3, speculate=False)
+        await fork.result()
+        await other.result()
+        engine.shutdown(drain=True)
+        await task
+        return fork
+
+    fork = asyncio.run(main())
+    want = _generate(tiny_lm, prompt, 6)
+    assert fork.fork_completions == [want, want, want]
+    pool = engine.kv_pool
+    assert pool._fork_refs == {}
+    assert pool.blocks_used == len(pool._by_slot)
