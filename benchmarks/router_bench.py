@@ -326,9 +326,12 @@ def main() -> None:
     args = ap.parse_args()
     args._roles = None
     if args.roles:
-        from benchmarks.serving_bench import _parse_roles_spec
+        from distkeras_tpu.serving.cluster import parse_roles
 
-        args._roles = _parse_roles_spec(args.roles)
+        try:
+            args._roles = parse_roles(args.roles)
+        except ValueError as e:
+            raise SystemExit(f"--roles: {e}") from None
         args.replicas = len(args._roles)
 
     report: dict = {"config": {

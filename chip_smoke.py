@@ -4,7 +4,7 @@
 Drives the two main paths once, through the entry points a user calls, at
 the full width of models the zoo already has (random weights from --seed):
 
-1. *serve*  — ``python -m distkeras_tpu.run serve --model gpt_small --paged``
+1. *serve*  — ``python -m distkeras_tpu.run serve --model gpt_small``
    in a child process; this process is only a ``ServingClient``.
 2. *train*  — ``dk.SingleTrainer(bert_base_mlm(seq_len=512))``, then the same
    model on the Pallas kernels (flash attention + fused softmax-xent), whose
@@ -476,7 +476,7 @@ async def serve_phase(cfg: dict, rehearse: bool, seed: int,
     proc = await asyncio.create_subprocess_exec(
         sys.executable, "-m", "distkeras_tpu.run", "serve",
         "--model", cfg["lm"], "--model-args", json.dumps(cfg["lm_args"]),
-        "--paged", "--kv-pool-mb", str(cfg["kv_pool_mb"]),
+        "--kv-pool-mb", str(cfg["kv_pool_mb"]),
         "--kv-block-tokens", "16", "--slots", "8", "--port", "0",
         "--seed", str(seed), "--audit-recompiles", "report",
         cwd=HERE, stdout=asyncio.subprocess.PIPE)

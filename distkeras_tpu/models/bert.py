@@ -77,15 +77,15 @@ class BertConfig:
     # inside one compiled step. The same index leaves make prefill
     # restartable at any offset (pre-set them to ``n`` and an apply
     # continues the sequence at position ``n`` — see _decode_attention's
-    # non-zero-offset contract), which is what the engine's chunked
-    # prefill and prefix-cache splice build on. Requires ``decode=True``;
+    # non-zero-offset contract), which is what the speculative draft's
+    # chunked prefill and per-row rewind build on. Requires ``decode=True``;
     # params are still layout-identical to the training model.
     decode_slots: bool = False
     # > 0: dense decode caches hold this many positions per slot instead
-    # of max_seq_len — lets a serving engine cap the pre-reserved
-    # per-slot KV bytes below the positional capacity (the padded max a
-    # dense engine can afford under a byte budget). Params (pos_embed in
-    # particular) are untouched; only the cache variables shrink.
+    # of max_seq_len — the serving engine sizes its speculative draft's
+    # cache with it (the request context plus the verify window's
+    # overhang). Params (pos_embed in particular) are untouched; only
+    # the cache variables change size.
     decode_cache_len: int = 0
     # > 0 selects PAGED decode (decode_slots only): K/V lives in a
     # shared block pool of this many fixed-size blocks per layer
@@ -336,7 +336,7 @@ class SelfAttention(nn.Module):
         cache_with_index``) continues a sequence at position ``n``
         exactly as if positions ``[0, n)`` had been run through this same
         module, provided the cache rows ``[0, n)`` hold that prefix's
-        K/V (e.g. spliced from ``serving.prefix_cache.PrefixCache``).
+        K/V (the speculative draft's chunked prefill does this).
         Garbage rows at ``>= n`` stay invisible: ``k_pos <= q_pos`` masks
         every position not yet written by a real token.
 

@@ -312,8 +312,9 @@ class Cohere2Moe(nn.Module):
                 "mesh without a pp axis)")
         if cfg.decode and cfg.paged_blocks <= 0:
             raise ValueError(
-                "Cohere2Moe decodes through the paged KV pool only: serve it "
-                "with --paged / --kv-pool-mb (no dense per-slot cache)")
+                "Cohere2Moe decodes through the paged KV pool only (the "
+                "serving engine's): it has no dense cache, so no "
+                "generate() and no drafting")
         embed = self.param("token_embed", nn.initializers.normal(0.02),
                            (cfg.vocab_size, cfg.hidden_size), cfg.dtype)
         x = embed[token_ids.astype(jnp.int32)]

@@ -188,41 +188,36 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
                          "behavior). Greedy output is token-identical "
                          "either way — see docs/serving.md 'Decode "
                          "pipeline'")
-    ap.add_argument("--prefix-cache-mb", type=float, default=0.0,
-                    help="> 0 enables the device-resident prompt prefix "
-                         "cache under this byte budget: shared prefixes "
-                         "(system prompts, templates) splice cached KV "
-                         "blocks instead of recomputing prefill")
-    ap.add_argument("--prefix-block", type=int, default=16,
-                    help="prefix-cache block granularity in tokens")
     ap.add_argument("--paged", action="store_true",
-                    help="paged KV: decode slots allocate fixed-size "
-                         "blocks from ONE shared pool (which doubles as "
-                         "the prefix cache) via per-slot block tables — "
-                         "capacity scales with resident tokens, the pool "
-                         "may be oversubscribed (preempt-and-requeue), "
-                         "and long-context requests chain blocks up to "
-                         "the trained context")
+                    help="accepted and ignored: every engine serves from "
+                         "the one KV block pool")
     ap.add_argument("--constrained", action="store_true",
                     help="constrained decoding: compile the decode step "
                          "with a per-slot additive token-mask input so "
                          "requests may carry a 'constraint' automaton "
-                         "(docs/serving.md 'Request kinds'). Requires "
-                         "--paged/--kv-pool-mb; without this flag such "
-                         "requests are rejected as bad_request")
+                         "(docs/serving.md 'Request kinds'); without "
+                         "this flag such requests are rejected as "
+                         "bad_request")
     ap.add_argument("--kv-pool-mb", type=float, default=0.0,
-                    help="paged-KV pool byte budget (MB); > 0 implies "
-                         "--paged. See docs/serving.md 'KV pool sizing'")
+                    help="KV pool byte budget (MB): slots allocate "
+                         "fixed-size blocks from ONE shared pool, which "
+                         "is also the prefix cache; a budgeted pool may "
+                         "be oversubscribed (preempt-and-requeue). 0 "
+                         "(default) sizes it slots x ceil(context / "
+                         "--kv-block-tokens) blocks, a whole context "
+                         "for every slot. See docs/serving.md 'KV pool "
+                         "sizing'")
     ap.add_argument("--kv-block-tokens", type=int, default=16,
-                    help="paged-KV block granularity in tokens")
+                    help="KV block granularity in tokens (allocation "
+                         "and prefix sharing)")
     ap.add_argument("--kv-host-tier-mb", type=float, default=0.0,
                     help="tiered KV cache: host-RAM spill tier byte "
                          "budget (MB). > 0: unreferenced hot blocks "
                          "evicted from the device pool spill to host "
                          "RAM (exact serialized KV bytes) and re-admit "
                          "on the next prefix hit instead of "
-                         "re-prefilling. Requires --paged/--kv-pool-mb. "
-                         "See docs/serving.md 'Tiered KV cache'")
+                         "re-prefilling. See docs/serving.md 'Tiered KV "
+                         "cache'")
     ap.add_argument("--kv-disk-tier-dir", default=None, metavar="DIR",
                     help="tiered KV cache: optional disk tier under the "
                          "host tier — host-tier evictions demote to "
@@ -237,9 +232,9 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
                          "per-put thrash)")
     ap.add_argument("--max-context", type=int, default=None,
                     help="cap per-request context below the trained "
-                         "length; in dense mode also shrinks the "
-                         "pre-reserved per-slot KV cache to this many "
-                         "positions")
+                         "length (and with it each slot's block table, "
+                         "so the pool sized when no --kv-pool-mb is "
+                         "given)")
     ap.add_argument("--draft-model", default=None,
                     help="speculative decoding: a small causal LM from "
                          "the zoo drafts --spec-k tokens per tick and "
@@ -263,8 +258,8 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
                     help="draft tokens proposed per speculative tick")
     ap.add_argument("--mesh", action="store_true",
                     help="GSPMD tensor-parallel serving: shard the model "
-                         "and its KV (dense caches or the paged pool "
-                         "alike) over a device mesh — ONE replica spread "
+                         "and its KV pool over a device mesh — ONE "
+                         "replica spread "
                          "over every visible device (tp=<all>), greedy "
                          "output token-identical to the unsharded "
                          "engine. See docs/serving.md 'Sharded serving'")
@@ -308,8 +303,7 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
     ap.add_argument("--roles", default=None, metavar="prefill=N,decode=M",
                     help="disaggregated cluster: N prefill replicas + M "
                          "decode replicas (implies cluster mode, "
-                         "overrides --replicas; requires --paged/"
-                         "--kv-pool-mb). The router prefills each "
+                         "overrides --replicas). The router prefills each "
                          "prompt family ONCE on its prefill replica "
                          "and decode replicas adopt the KV blocks over "
                          "the wire (KVBLK frames) — chunked prefill "
@@ -453,17 +447,6 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
             capacity=recorder_cap,
             dump_path=args.flight_dump,
             source=f"serve:{args.model}:pid{os.getpid()}")
-    # --paged with no explicit budget gets a sane default pool; an
-    # explicit --kv-pool-mb implies --paged.
-    kv_pool_mb = args.kv_pool_mb or (64.0 if args.paged else 0.0)
-    if args.kv_host_tier_mb and not kv_pool_mb:
-        raise SystemExit("--kv-host-tier-mb requires --paged or "
-                         "--kv-pool-mb: the host tier spills paged-KV "
-                         "blocks")
-    if args.constrained and not kv_pool_mb:
-        raise SystemExit("--constrained requires --paged or "
-                         "--kv-pool-mb: the token-mask decode step "
-                         "runs on the paged pool")
     draft_model = draft_variables = None
     if args.draft_model:
         draft_kwargs = json.loads(args.draft_args)
@@ -502,9 +485,7 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
         arm_auditor_after_warmup=args.audit_recompiles == "arm",
         prefill_chunk=args.prefill_chunk,
         min_prefill_bucket=args.min_prefill_bucket,
-        prefix_cache_mb=0.0 if kv_pool_mb else args.prefix_cache_mb,
-        prefix_block_tokens=args.prefix_block,
-        kv_pool_mb=kv_pool_mb,
+        kv_pool_mb=args.kv_pool_mb,
         kv_block_tokens=args.kv_block_tokens,
         kv_host_tier_mb=args.kv_host_tier_mb,
         kv_disk_tier_dir=args.kv_disk_tier_dir,
@@ -543,10 +524,8 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
             "device_count": len(jax.devices()),
             "slots": args.slots, "max_queue": args.max_queue,
             "prefill_chunk": args.prefill_chunk,
-            "prefix_cache_mb": args.prefix_cache_mb,
-            "kv_pool_mb": kv_pool_mb,
-            "kv_pool_blocks": (engine.kv_pool.capacity
-                               if engine.kv_pool is not None else 0),
+            "kv_pool_mb": args.kv_pool_mb,
+            "kv_pool_blocks": engine.kv_pool.capacity,
             "draft_model": args.draft_model,
             "spec_k": args.spec_k if args.draft_model else 0,
             "mesh": engine.mesh_info(),
@@ -564,10 +543,7 @@ def serve_main(argv=None, prog="serve", default_replicas=1) -> int:
         await stop.wait()
         await server.stop(drain=True)
         summary = {k: round(v, 6) for k, v in metrics.summary().items()}
-        if engine.prefix_cache is not None:
-            summary["prefix_cache"] = engine.prefix_cache.stats()
-        if engine.kv_pool is not None:
-            summary["kv_pool"] = engine.kv_pool.stats()
+        summary["kv_pool"] = engine.kv_pool.stats()
         if auditor is not None:
             summary["recompile_audit"] = auditor.report()
         print(json.dumps(summary), flush=True)
@@ -629,12 +605,9 @@ def _serving_config_flags(args) -> list[str]:
     ``deploy``, so the whole fleet (and, in deploy's case, the canary
     replica, which is a drained member of that same fleet) runs the
     configuration the operator asked for. Before deploy used this, its
-    canary always validated candidates on the dense one-token default —
-    a paged or speculative production config shipped unvetted."""
-    extra = [
-        "--prefix-cache-mb", str(args.prefix_cache_mb),
-        "--prefix-block", str(args.prefix_block),
-    ]
+    canary always validated candidates on the one-token default — a
+    budgeted-pool or speculative production config shipped unvetted."""
+    extra = ["--kv-block-tokens", str(args.kv_block_tokens)]
     if args.top_k is not None:
         extra += ["--top-k", str(args.top_k)]
     if args.prefill_chunk is not None:
@@ -643,21 +616,18 @@ def _serving_config_flags(args) -> list[str]:
         extra += ["--min-prefill-bucket", str(args.min_prefill_bucket)]
     if getattr(args, "pipeline_depth", None) is not None:
         extra += ["--pipeline-depth", str(args.pipeline_depth)]
-    if args.paged or args.kv_pool_mb:
-        if args.paged:
-            extra += ["--paged"]
-        extra += ["--kv-pool-mb", str(args.kv_pool_mb),
-                  "--kv-block-tokens", str(args.kv_block_tokens)]
-        if getattr(args, "kv_host_tier_mb", 0.0):
-            extra += ["--kv-host-tier-mb", str(args.kv_host_tier_mb),
-                      "--kv-tier-watermark", str(args.kv_tier_watermark)]
-            if getattr(args, "kv_disk_tier_dir", None):
-                # One shared dir is safe: spill file names carry the
-                # replica pid.
-                extra += ["--kv-disk-tier-dir", args.kv_disk_tier_dir,
-                          "--kv-disk-tier-mb", str(args.kv_disk_tier_mb)]
-        if getattr(args, "constrained", False):
-            extra += ["--constrained"]
+    if args.kv_pool_mb:
+        extra += ["--kv-pool-mb", str(args.kv_pool_mb)]
+    if getattr(args, "kv_host_tier_mb", 0.0):
+        extra += ["--kv-host-tier-mb", str(args.kv_host_tier_mb),
+                  "--kv-tier-watermark", str(args.kv_tier_watermark)]
+        if getattr(args, "kv_disk_tier_dir", None):
+            # One shared dir is safe: spill file names carry the
+            # replica pid.
+            extra += ["--kv-disk-tier-dir", args.kv_disk_tier_dir,
+                      "--kv-disk-tier-mb", str(args.kv_disk_tier_mb)]
+    if getattr(args, "constrained", False):
+        extra += ["--constrained"]
     if args.max_context is not None:
         extra += ["--max-context", str(args.max_context)]
     if args.draft_model:
@@ -724,11 +694,6 @@ def cluster_main(args) -> int:
     _parse_mesh_shape_arg(args)
     roles = _parse_roles(getattr(args, "roles", None))
     if roles is not None:
-        if not (args.paged or args.kv_pool_mb):
-            raise SystemExit(
-                "--roles requires --paged or --kv-pool-mb: KV block "
-                "migration (the prefill->decode handoff) only exists "
-                "on the paged pool")
         args.replicas = len(roles)
     if getattr(args, "kv_push", False) and roles is None:
         raise SystemExit("--kv-push requires --roles: push scheduling "
@@ -801,7 +766,7 @@ def cluster_main(args) -> int:
         args.replicas, host=args.host, port=args.port, registry=registry,
         roles=roles,
         router_kwargs={
-            "affinity_tokens": args.prefix_block,
+            "affinity_tokens": args.kv_block_tokens,
             "affinity_slack": args.affinity_slack,
             "wire_mode": "jsonl" if args.wire == "jsonl" else "auto",
             "trace_capacity":
@@ -823,7 +788,7 @@ def cluster_main(args) -> int:
             "replicas": {rid: {"host": info.host, "port": info.port,
                                "role": info.role}
                          for rid, info in cluster.replicas.items()},
-            "slots": args.slots, "prefix_cache_mb": args.prefix_cache_mb,
+            "slots": args.slots, "kv_pool_mb": args.kv_pool_mb,
             "flight_dir": flight_dir,
         }), flush=True)
         stop = asyncio.Event()
@@ -889,8 +854,8 @@ def deploy_main(argv=None) -> int:
     # The fleet's REAL serving configuration, forwarded to every
     # replica (the canary is a drained member of this same fleet, so a
     # candidate is validated under the configuration production
-    # actually runs — paged KV, chunked prefill, speculation and all —
-    # not the dense one-token default).
+    # actually runs — pool budget, chunked prefill, speculation and all —
+    # not the one-token default).
     ap.add_argument("--top-k", type=int, default=None)
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="replica chunked-prefill size (tokens)")
@@ -898,16 +863,14 @@ def deploy_main(argv=None) -> int:
                     help="replica decode pipeline depth (1 overlaps host "
                          "bookkeeping with device compute; >=2 "
                          "micro-batches a pp mesh; 0 serializes)")
-    ap.add_argument("--prefix-cache-mb", type=float, default=0.0,
-                    help="replica prefix-cache byte budget (MB)")
-    ap.add_argument("--prefix-block", type=int, default=16,
-                    help="prefix-cache block granularity (tokens)")
     ap.add_argument("--paged", action="store_true",
-                    help="replicas serve with paged KV")
+                    help="accepted and ignored: every engine serves from "
+                         "the one KV block pool")
     ap.add_argument("--kv-pool-mb", type=float, default=0.0,
-                    help="replica paged-KV pool budget (MB); > 0 "
-                         "implies --paged")
-    ap.add_argument("--kv-block-tokens", type=int, default=16)
+                    help="replica KV pool budget (MB); 0 sizes a whole "
+                         "context for every slot")
+    ap.add_argument("--kv-block-tokens", type=int, default=16,
+                    help="replica KV block granularity (tokens)")
     ap.add_argument("--max-context", type=int, default=None)
     ap.add_argument("--draft-model", default=None,
                     help="replicas serve with speculative decoding "

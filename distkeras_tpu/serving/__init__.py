@@ -10,21 +10,19 @@ request path:
   decode step for the lifetime of the server, requests admitted into free
   slots mid-decode (no retrace, no drain), optionally with **chunked
   prefill** (``prefill_chunk``: long prompts admit one bounded chunk per
-  decode tick) and a **prefix cache** (``prefix_cache_mb``);
-- :class:`PrefixCache` — device-resident pool of fixed-size KV blocks
-  keyed by a radix trie over prompt prefixes (ref-counted, LRU-evicted
-  under a byte budget): a hit splices cached blocks instead of
-  recomputing the shared prefix's prefill;
-- :class:`KVBlockPool` — the paged-KV generalization
-  (``ServingEngine(kv_pool_mb=...)``): decode slots allocate their KV
-  from the SAME block pool through per-slot block tables, prefix hits
-  become zero-copy shared blocks, the pool may be oversubscribed
-  (preempt-and-requeue, typed ``kv_oom`` rejects past capacity), and
-  long-context requests chain blocks up to the trained context instead
-  of being bounded by a padded per-slot max;
+  decode tick);
+- :class:`KVBlockPool` — the engine's one KV cache: decode slots
+  allocate fixed-size KV blocks from ONE pool through per-slot block
+  tables, and the same blocks, keyed by a radix trie over prompt
+  prefixes (ref-counted, LRU-evicted), are the prefix cache — a hit is
+  zero-copy shared blocks. Sized by ``ServingEngine(kv_pool_mb=...)``
+  (or a whole context per slot when no budget is given); a budgeted
+  pool may be oversubscribed (preempt-and-requeue, typed ``kv_oom``
+  rejects past capacity), and long-context requests chain blocks up to
+  the trained context;
 - :class:`Scheduler` / :class:`Request` — priority-FIFO admission with
-  max-depth backpressure, per-request deadlines, and (with a prefix
-  cache) bounded cache-aware reordering within a priority class;
+  max-depth backpressure, per-request deadlines, and bounded
+  cache-aware reordering within a priority class;
 - :class:`ServingServer` / :class:`ServingClient` — asyncio TCP front end
   with newline-delimited-JSON streaming token output;
 - :class:`ServingMetrics` — TTFT / inter-token latency / occupancy
@@ -35,7 +33,7 @@ request path:
   zero-streamed retry on replica death, and zero-downtime rolling weight
   reloads;
 - :mod:`distkeras_tpu.serving.kv_transfer` — KV block migration: a
-  prompt's paged blocks serialized (bitwise, provenance-stamped) and
+  prompt's pool blocks serialized (bitwise, provenance-stamped) and
   adopted into a peer replica's pool, the primitive behind
   **disaggregated prefill/decode fleets** (``run.py cluster --roles
   prefill=N,decode=M``), cross-replica prefix sharing, and
@@ -63,7 +61,7 @@ from distkeras_tpu.serving.slo import (
     SLOEngine,
     default_objectives,
 )
-from distkeras_tpu.serving.prefix_cache import KVBlockPool, PrefixCache
+from distkeras_tpu.serving.prefix_cache import KVBlockPool
 from distkeras_tpu.serving.engine import ServingEngine
 from distkeras_tpu.serving.server import ServingServer
 from distkeras_tpu.serving.client import ServingClient
@@ -82,7 +80,6 @@ __all__ = [
     "ReplicaSupervisor",
     "LocalReplica",
     "ProcessReplica",
-    "PrefixCache",
     "KVBlockPool",
     "PoolExhausted",
     "Scheduler",

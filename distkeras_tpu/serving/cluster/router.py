@@ -409,7 +409,7 @@ class Router:
     """Front-port router over a :class:`ReplicaSupervisor`'s table.
 
     ``affinity_tokens``: prompt-family prefix length for cache affinity —
-    match it to the backend engines' ``prefix_block_tokens`` (a family
+    match it to the backend engines' ``kv_block_tokens`` (a family
     shorter than one cache block can't pin what the trie shares).
     ``affinity_slack``: max outstanding-request imbalance the pin may
     create before least-outstanding wins.
@@ -1515,7 +1515,7 @@ class Router:
         capacity — the gate on directory steering (a full holder would
         just preempt what it holds to admit the steered request, losing
         the very blocks we steered for). A replica whose healthz never
-        reported a pool (unpaged, or no probe yet) counts as capacious:
+        reported a pool (no probe yet) counts as capacious:
         steering is an optimization, not a correctness gate."""
         pool = (info.last_health or {}).get("kv_pool")
         if not isinstance(pool, dict) or "blocks_free" not in pool:

@@ -154,20 +154,6 @@ def _engine_section(dz: dict, indent: str = "") -> list[str]:
                                 ("avail", "quota_avail"),
                                 ("shed", "shed")]):
             lines.append(f"{indent}  {ln}")
-    pc = dz.get("prefix_cache")
-    if pc:
-        lines.append(
-            f"{indent}prefix_cache: {pc.get('blocks_used')}/"
-            f"{pc.get('capacity_blocks')} blocks "
-            f"({pc.get('families')} families)")
-        fams = pc.get("top_families", [])
-        if fams:
-            for ln in _table(fams, [("family_head", "family_head"),
-                                    ("blocks", "blocks"),
-                                    ("tokens", "tokens"),
-                                    ("pins", "pinned_refs"),
-                                    ("depth", "max_chain_depth")]):
-                lines.append(f"{indent}  {ln}")
     kp = dz.get("kv_pool")
     if kp:
         lines.append(

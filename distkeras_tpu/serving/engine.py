@@ -7,47 +7,50 @@ cannot do that — requests arrive whenever they arrive, and draining the
 batch to admit one request wastes every other slot's compute.
 
 This engine keeps the shape discipline that makes the offline path fast
-(static ``[B_slots, max_seq_len, H, D]`` KV caches, ONE compiled decode
-step for the lifetime of the server) while making the batch *open*:
+(static shapes, ONE compiled decode step for the lifetime of the server)
+while making the batch *open*:
 
 - each of the ``slots`` rows of the decode batch is an independent
   request at its **own** sequence position (``BertConfig.decode_slots``
-  turns the cache/positional indices into per-row vectors);
+  turns the positional indices into per-row vectors);
 - a finished request frees its row; a queued request is admitted between
   decode iterations by a **prefill** program (compiled once per
-  power-of-two prompt-length bucket) whose single-row KV cache is spliced
-  into the live batch cache with ``dynamic_update_slice`` — the decode
-  step itself never retraces and never stops for admission;
-- with a **prefix cache** (``prefix_cache_mb``), the prompt's longest
-  cached block-chain prefix is spliced from a device-resident pool
-  (:mod:`distkeras_tpu.serving.prefix_cache`) instead of recomputed —
-  only the uncached tail runs through the prefill program;
-- with **chunked prefill** (``prefill_chunk``), that tail is split into
+  power-of-two prompt-length bucket) that writes the prompt's K/V
+  straight into the request's blocks of the KV pool — the decode step
+  itself never retraces and never stops for admission;
+- with **chunked prefill** (``prefill_chunk``), the prompt is split into
   fixed-size chunks and ONE chunk runs per engine iteration, interleaved
   with decode ticks — admitting a long prompt never stalls the decode
   batch for more than one chunk's device time, bounding every in-flight
   request's inter-token latency;
-- free rows keep decoding garbage (their output is discarded) — the cost
-  of a fixed-shape batch, and exactly the trade the training side makes
-  with padded microbatches.
+- free rows keep decoding garbage (their output is discarded, and their
+  writes are dropped) — the cost of a fixed-shape batch, and exactly the
+  trade the training side makes with padded microbatches.
 
-**Paged KV mode** (``kv_pool_mb``/``paged``) replaces the dense
-``[slots, L, H, D]`` per-slot cache — which pays worst-case length for
-every slot up front — with ONE block pool shared by decode slots and the
-prefix cache (:class:`~distkeras_tpu.serving.prefix_cache.KVBlockPool`):
+**The KV cache is ONE block pool**, shared by the decode slots and the
+prefix cache (:class:`~distkeras_tpu.serving.prefix_cache.KVBlockPool`);
+there is no other layout. (``generate()`` keeps its dense
+``[B, L, H, D]`` cache as the offline path, and a speculative engine's
+draft model keeps a small dense per-slot cache of its own.)
 
-- each slot's KV lives in fixed-size blocks addressed through a per-slot
-  block table; the compiled decode step gathers K/V via traced table
-  indices (:func:`distkeras_tpu.ops.attention.paged_attention`), so the
-  single-compiled-decode-step invariant survives with paging on;
-- capacity scales with *resident tokens*, not ``slots × max_seq_len`` —
-  more concurrent slots per byte, and **long-context admission**:
-  requests may use the model's whole trained context because blocks are
-  chained on demand, never pre-reserved;
-- prefix-cache hits are **zero-copy** (the table points at the shared
-  ref-counted blocks) and a finished slot's blocks are **adopted** into
-  the trie in place (zero-copy insert);
-- the pool may be **oversubscribed**: when it runs dry, the engine
+- each slot's KV lives in fixed-size blocks (``kv_block_tokens``)
+  addressed through a per-slot block table; the compiled decode step
+  gathers K/V via traced table indices
+  (:func:`distkeras_tpu.ops.attention.paged_attention`), so ONE
+  executable serves every table layout and context length;
+- **sizing**: ``kv_pool_mb`` (a byte budget) or ``kv_pool_blocks`` sizes
+  the pool; with neither, it holds ``slots × ceil(limit /
+  kv_block_tokens)`` blocks — every slot can hold a whole context, so
+  nothing is ever preempted. A budget makes capacity scale with
+  *resident tokens*, not ``slots × max_seq_len``: more concurrent slots
+  per byte, and requests may use the model's whole trained context
+  because blocks are chained on demand, never pre-reserved;
+- **prefix sharing is inherent**: a prompt's longest cached block-chain
+  prefix is a **zero-copy** hit (the table points at the shared
+  ref-counted blocks, only the uncached tail is prefilled) and a
+  finished slot's blocks are **adopted** into the trie in place
+  (zero-copy insert);
+- a budgeted pool may be **oversubscribed**: when it runs dry, the engine
   preempts the lowest-priority youngest slot — its complete blocks are
   adopted (so re-admission re-matches them), the rest freed, and the
   request requeued at the front of its priority class
@@ -69,10 +72,9 @@ off the wide window's logits — the property that makes speculation
 token-identical to ``generate()`` instead of almost-identical (see
 ``_spec_accept``). A row that accepts zero drafts is owed a one-token
 fallback tick, so progress is unconditional. Everything is
-shape-stable in K: rejected drafts roll back by rewinding the per-row
-cache index leaves (dense) or by simply not advancing the host
-``_lens`` watermark (paged, where the OOB-drop scatter already
-guarantees an unallocated overhang cannot scribble) — so draft,
+shape-stable in K: rejected drafts roll back by simply not advancing
+the host ``_lens`` watermark (the OOB-drop scatter already guarantees
+an unallocated overhang cannot scribble) — so draft,
 verify, and the one-token fallback each stay at exactly one compiled
 executable under the armed ``RecompileAuditor``, variable acceptance
 lengths and all.
@@ -92,12 +94,10 @@ than one chip, served by the same engine:
   **placed shard-then-place** (:func:`...gspmd.place_sharded`): each
   device receives only its slice, at boot and at every hot swap — the
   arXiv:2004.13336 move applied to weight rollout;
-- the KV bytes — dense per-slot caches and the paged block pools alike
-  — shard over the **heads** dimension
+- the KV bytes — the block pools — shard over the **heads** dimension
   (:func:`...sharding.kv_pytree_shardings`), while block tables, slot
   state, the scheduler, and every index stay replicated host metadata
-  (the paged refactor is what makes this split clean: the pool's
-  *meaning* was already host-side bookkeeping);
+  (the pool's *meaning* is host-side bookkeeping);
 - every compiled callable — prefill, decode, draft, verify, fallback —
   is jitted with **explicit ``in_shardings``/``out_shardings``**, so
   layouts are pinned facts of each executable (stable across calls =
@@ -142,7 +142,7 @@ Pipelined, all of that runs while the device executes the next tick:
 - a slot that FINISHES at tick N is detected at N's harvest — after
   N+1 was dispatched, so the in-flight tick ran one speculative row
   for it. Its N+1 output is dropped exactly like a mid-prefill
-  garbage row, and (paged) the host watermark advance the dispatch
+  garbage row, and the host watermark advance the dispatch
   made for it is rolled back before teardown adopts its blocks, so
   pool accounting never claims the speculative in-flight write;
 - speculative ticks dispatch asynchronously too, but the NEXT dispatch
@@ -161,7 +161,7 @@ bounded dispatch→harvest lane for tracez/debugz. What it does NOT hide
 is counted: every barrier that found a tick in flight adds to
 ``serving_pipeline_barriers_total{reason}`` and is a ``barrier`` span
 (on the device's clock whenever a profiler session is live, see
-:mod:`distkeras_tpu.telemetry.spans`). A paged engine with many rows
+:mod:`distkeras_tpu.telemetry.spans`). An engine with many rows
 has some row crossing a block boundary on almost every tick; until
 PR 30 each such tick drained, and the overlap was mostly lost to
 ``paged_growth``. ``serving_paged_growth_blocks_total{drained}`` now
@@ -195,7 +195,7 @@ from distkeras_tpu.inference.generate import (
     sample_rows,
 )
 from distkeras_tpu.serving.metrics import ServingMetrics
-from distkeras_tpu.serving.prefix_cache import KVBlockPool, PrefixCache
+from distkeras_tpu.serving.prefix_cache import KVBlockPool
 from distkeras_tpu.telemetry import (
     FlightRecorder,
     RecompileAuditor,
@@ -221,77 +221,23 @@ from distkeras_tpu.serving.scheduler import (
 __all__ = ["ServingEngine"]
 
 
-def _prefill_fn(module, top_k, params, cache, padded, start, true_len, temp,
-                key):
-    """Run a right-padded ``[1, P]`` prompt *chunk* through the decode
-    module at cache offset ``start``, extending the slot's KV cache and
-    sampling the token that follows the chunk.
-
-    ``start`` and ``true_len`` are traced scalars, so ONE compiled program
-    serves every offset and every true length of a given pad width ``P``
-    — monolithic prefill is the ``start == 0, P == bucket(prompt)`` case,
-    a chunk of a longer prompt (or of the uncached tail after a
-    prefix-cache splice) is the same program at a non-zero start.
-
-    Padding is benign: causal attention means real positions never see the
-    pad tail, the sampled token comes from the logits at ``true_len - 1``,
-    and the garbage K/V at ``[start + true_len, start + P)`` is masked out
-    of every later step (``k_pos <= q_pos``) until overwritten by real
-    tokens. The index leaves are set to ``start`` on entry (so a
-    prefix-cache splice never has to touch them) and rewound from
-    ``start + P`` to ``start + true_len`` on exit so the next chunk — or
-    decode — resumes at the real end.
-    """
-    cache = cache_with_index(cache, start)
-    logits, mut = module.apply(
-        {"params": params, "cache": cache}, padded, train=False,
-        mutable=["cache"],
-    )
-    cache = cache_with_index(mut["cache"], start + true_len)
-    last = jnp.take(logits[0], true_len - 1, axis=0)[None]  # [1, V]
-    tok = sample_rows(last, temp[None], key, top_k)[0]
-    return cache, tok
-
-
-def _admit_fn(cache, tokens, temps, slot, pre_cache, first_tok, temp):
-    """Splice a prefilled single-row cache into batch row ``slot``.
-
-    ``slot`` is a traced scalar, so one compiled program serves every
-    slot; every cache leaf carries the batch dim first in decode_slots
-    mode, so the splice is a uniform leading-axis dynamic_update_slice.
-    """
-    cache = jax.tree.map(
-        lambda big, small: lax.dynamic_update_slice(
-            big, small.astype(big.dtype), (slot,) + (0,) * (small.ndim - 1)
-        ),
-        cache, pre_cache,
-    )
-    tokens = tokens.at[slot].set(first_tok)
-    temps = temps.at[slot].set(temp)
-    return cache, tokens, temps
-
-
-def _decode_fn(module, top_k, params, cache, tokens, temps, key):
-    """ONE decode iteration for the whole slot batch ``[B] -> [B]``."""
-    logits, mut = module.apply(
-        {"params": params, "cache": cache}, tokens[:, None], train=False,
-        mutable=["cache"],
-    )
-    nxt = sample_rows(logits[:, -1], temps, key, top_k)
-    return mut["cache"], nxt
-
-
 def _paged_prefill_fn(module, top_k, params, pools, padded, start, true_len,
                       table_row, temp, key):
-    """Paged twin of :func:`_prefill_fn`: the chunk's K/V writes straight
-    into the shared block pool through the slot's block table (no
-    single-row scratch cache, no splice afterwards — admission IS the
-    table row). ``start``/``true_len``/``table_row`` are traced, so ONE
-    program serves every offset, true length, and block layout of a
-    given pad width. Right-padded garbage past the table's allocated
-    blocks is dropped by the scatter; garbage inside the tail block is
-    masked (``k_pos <= q_pos``) until real tokens overwrite it — the
-    same discipline as the dense path."""
+    """Run a right-padded ``[1, P]`` prompt *chunk* through the decode
+    module at offset ``start`` and sample the token that follows it. The
+    chunk's K/V writes straight into the shared block pool through the
+    slot's block table (no scratch cache, no splice afterwards —
+    admission IS the table row). ``start``/``true_len``/``table_row``
+    are traced, so ONE program serves every offset, true length, and
+    block layout of a given pad width: monolithic prefill is the
+    ``start == 0, P == bucket(prompt)`` case, a chunk of a longer prompt
+    (or the uncached tail after a prefix hit) the same program at a
+    non-zero start. Padding is benign: causal attention means real
+    positions never see the pad tail, the sampled token comes from the
+    logits at ``true_len - 1``, right-padded garbage past the table's
+    allocated blocks is dropped by the scatter, and garbage inside the
+    tail block is masked (``k_pos <= q_pos``) until real tokens
+    overwrite it."""
     logits, mut = module.apply(
         {"params": params, "cache": pools}, padded, train=False,
         mutable=["cache"],
@@ -304,19 +250,20 @@ def _paged_prefill_fn(module, top_k, params, pools, padded, start, true_len,
 
 
 def _paged_admit_fn(tokens, temps, slot, tok, temp):
-    """Paged admission epilogue: only the sampling state changes — the
-    KV is already resident in the pool, so there is nothing to splice."""
+    """Admission epilogue: only the sampling state changes — the KV is
+    already resident in the pool, so there is nothing to splice.
+    ``slot`` is a traced scalar, so one program serves every slot."""
     return tokens.at[slot].set(tok), temps.at[slot].set(temp)
 
 
 def _paged_decode_fn(module, top_k, sentinel, params, pools, tokens, temps,
                      positions, tables, key):
-    """Paged twin of :func:`_decode_fn`: K/V appends scatter into the
-    pool at each row's (traced) position and attention gathers through
-    the (traced) block tables — one compiled executable for every table
-    layout, admission pattern, and context length, which is what keeps
-    the armed ``RecompileAuditor`` silent while blocks chain, slots are
-    preempted, and long contexts grow.
+    """ONE decode iteration for the whole slot batch ``[B] -> [B]``: K/V
+    appends scatter into the pool at each row's (traced) position and
+    attention gathers through the (traced) block tables — one compiled
+    executable for every table layout, admission pattern, and context
+    length, which is what keeps the armed ``RecompileAuditor`` silent
+    while blocks chain, slots are preempted, and long contexts grow.
 
     Positions advance DEVICE-SIDE: each row that is live in the masked
     table view (first table entry not the sentinel — exactly the rows
@@ -523,7 +470,7 @@ def _spec_draft_fn(module, K, params, cache, prev, tokens, start):
 
 def _spec_accept(logits, drafts, tokens, temps, spec_ok, remaining, key,
                  top_k):
-    """Shared accept epilogue of both verify twins: from the target's
+    """Accept epilogue of the verify programs: from the target's
     ``[B, K, V]`` logits over the window ``[last_tok, d_1..d_{K-1}]``,
     decide how many DRAFT tokens each row commits.
 
@@ -588,51 +535,26 @@ def _spec_accept(logits, drafts, tokens, temps, spec_ok, remaining, key,
     return out, commit
 
 
-def _spec_verify_fn(module, top_k, params, cache, tokens, drafts, temps,
-                    spec_ok, remaining, positions, key):
-    """Dense speculative verify: ONE target-model call scores all K
-    window positions (``[last_tok, d_1..d_{K-1}]``) per slot, a masked
-    accept commits the longest verify-consistent draft prefix, and the
-    rejected tail is rolled back by rewinding the per-row cache index
-    leaves (``cache_with_index`` with a per-row vector — the same
-    offset-rewind contract chunked prefill uses). Everything is
-    shape-stable in K, so variable acceptance lengths never retrace.
-
-    Returns ``(cache, new_tokens, out, commit)``: ``out[b, :commit[b]]``
-    are row ``b``'s committed stream tokens and ``new_tokens[b]`` its
-    next feed token (the last committed one; unchanged on a zero
-    commit, so re-running the tick is idempotent)."""
-    window = jnp.concatenate([tokens[:, None], drafts[:, :-1]], axis=1)
-    cache = cache_with_index(cache, positions)
-    logits, mut = module.apply(
-        {"params": params, "cache": cache}, window, train=False,
-        mutable=["cache"],
-    )
-    out, commit = _spec_accept(logits, drafts, tokens, temps, spec_ok,
-                               remaining, key, top_k)
-    # Rollback: fed tokens end at positions + commit; the garbage K/V at
-    # [positions + commit, positions + K) stays masked (k_pos <= q_pos)
-    # until real tokens overwrite it — prefill's right-pad rule.
-    cache = cache_with_index(mut["cache"], positions + commit)
-    new_tok = jnp.where(
-        commit > 0,
-        jnp.take_along_axis(
-            out, jnp.maximum(commit - 1, 0)[:, None], axis=1)[:, 0],
-        tokens)
-    return cache, new_tok, out, commit
-
-
 def _paged_spec_verify_fn(module, top_k, params, pools, tokens, drafts,
                           temps, spec_ok, remaining, room, positions,
                           tables, key):
-    """Paged twin of :func:`_spec_verify_fn`: the window's K/V scatters
-    through the block tables (writes past a row's allocated blocks are
-    dropped by ``paged_kv_update``), so ``room`` — the contiguous
+    """Speculative verify: ONE target-model call scores all K window
+    positions (``[last_tok, d_1..d_{K-1}]``) per slot and a masked
+    accept commits the longest verify-consistent draft prefix.
+    Everything is shape-stable in K, so variable acceptance lengths
+    never retrace. The window's K/V scatters through the block tables
+    (writes past a row's allocated blocks are dropped by
+    ``paged_kv_update``), so ``room`` — the contiguous
     allocated positions from each row's write offset, computed host-side
     — additionally clamps the commit: a row whose lookahead blocks could
     not be allocated under pool pressure commits fewer tokens instead of
     committing tokens whose K/V was dropped. Rollback is the caller NOT
-    advancing ``_lens`` past the commit; no device state to rewind."""
+    advancing ``_lens`` past the commit; no device state to rewind.
+
+    Returns ``(pools, new_tokens, out, commit)``: ``out[b, :commit[b]]``
+    are row ``b``'s committed stream tokens and ``new_tokens[b]`` its
+    next feed token (the last committed one; unchanged on a zero
+    commit, so re-running the tick is idempotent)."""
     window = jnp.concatenate([tokens[:, None], drafts[:, :-1]], axis=1)
     logits, mut = module.apply(
         {"params": params, "cache": pools}, window, train=False,
@@ -650,11 +572,12 @@ def _paged_spec_verify_fn(module, top_k, params, pools, tokens, drafts,
 
 
 def _draft_prefill_fn(module, params, cache, padded, start, true_len):
-    """Draft twin of :func:`_prefill_fn` minus the sampling epilogue:
-    extend the draft's single-row cache with a right-padded prompt chunk
-    at offset ``start`` and rewind the index leaves to the true end. The
-    draft never samples — its proposals come from the decode-time scan —
-    so prefill only has to materialize the prompt's K/V."""
+    """The draft's prefill: extend its dense single-row cache with a
+    right-padded prompt chunk at offset ``start`` (the index leaves are
+    set to ``start`` on entry) and rewind them from ``start + P`` to the
+    true end. The draft never samples — its proposals come from the
+    decode-time scan — so prefill only has to materialize the prompt's
+    K/V."""
     cache = cache_with_index(cache, start)
     _, mut = module.apply(
         {"params": params, "cache": cache}, padded, train=False,
@@ -665,7 +588,9 @@ def _draft_prefill_fn(module, params, cache, padded, start, true_len):
 
 def _draft_admit_fn(cache, slot, pre_cache):
     """Splice a prefilled single-row draft cache into batch row ``slot``
-    (the draft half of :func:`_admit_fn`; no sampling state to set)."""
+    (a traced scalar: one program serves every slot; every cache leaf
+    carries the batch dim first, so the splice is a uniform
+    leading-axis ``dynamic_update_slice``)."""
     return jax.tree.map(
         lambda big, small: lax.dynamic_update_slice(
             big, small.astype(big.dtype), (slot,) + (0,) * (small.ndim - 1)
@@ -685,55 +610,6 @@ def _draft_admit_fn(cache, slot, pre_cache):
 # entries key on actual argument placement, so source-consistency is
 # what keeps compile-count==1 per stage). ``stage`` is the static
 # ``(lo, hi, first, last)`` slice Bert.__call__ takes.
-
-def _pp_prefill_fn(module, stage, params, cache, x, start, true_len):
-    """Non-last-stage slice of :func:`_prefill_fn`: extend this stage's
-    single-row cache with the chunk and hand the activation on. ``x`` is
-    the padded ``[1, P]`` token chunk on stage 0, the previous stage's
-    ``[1, P, H]`` activation after; the index-leaf entry/rewind contract
-    is per stage (every stage owns its own layers' index leaves)."""
-    cache = cache_with_index(cache, start)
-    act, mut = module.apply(
-        {"params": params, "cache": cache}, x, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    return cache_with_index(mut["cache"], start + true_len), act
-
-
-def _pp_prefill_last_fn(module, stage, top_k, params, cache, act, start,
-                        true_len, temp, key):
-    """Last-stage slice of :func:`_prefill_fn`: trunk tail + head +
-    the sampling epilogue."""
-    cache = cache_with_index(cache, start)
-    logits, mut = module.apply(
-        {"params": params, "cache": cache}, act, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    cache = cache_with_index(mut["cache"], start + true_len)
-    last = jnp.take(logits[0], true_len - 1, axis=0)[None]  # [1, V]
-    tok = sample_rows(last, temp[None], key, top_k)[0]
-    return cache, tok
-
-
-def _pp_decode_fn(module, stage, params, cache, x):
-    """Non-last-stage slice of :func:`_decode_fn` (``x`` is ``tokens[:,
-    None]`` on stage 0, the previous activation after)."""
-    act, mut = module.apply(
-        {"params": params, "cache": cache}, x, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    return mut["cache"], act
-
-
-def _pp_decode_last_fn(module, stage, top_k, params, cache, act, temps, key):
-    """Last-stage slice of :func:`_decode_fn`: trunk tail + sampling."""
-    logits, mut = module.apply(
-        {"params": params, "cache": cache}, act, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    nxt = sample_rows(logits[:, -1], temps, key, top_k)
-    return mut["cache"], nxt
-
 
 def _pp_paged_prefill_fn(module, stage, params, pools, x, start, table_row):
     """Non-last-stage slice of :func:`_paged_prefill_fn` — this stage's
@@ -764,11 +640,12 @@ def _pp_paged_prefill_last_fn(module, stage, top_k, params, pools, act,
 
 def _pp_paged_decode_fn(module, stage, sentinel, params, pools, x, positions,
                         tables):
-    """Non-last-stage slice of :func:`_paged_decode_fn`. Every stage
-    returns its own ``positions + live`` vector (the advance rule is
-    pure table arithmetic, identical across stages), so each stage's
-    steady-state tick re-feeds its OWN returned vector and no positions
-    ever cross stages."""
+    """Non-last-stage slice of :func:`_paged_decode_fn` (``x`` is
+    ``tokens[:, None]`` on stage 0, the previous activation after).
+    Every stage returns its own ``positions + live`` vector (the advance
+    rule is pure table arithmetic, identical across stages), so each
+    stage's steady-state tick re-feeds its OWN returned vector and no
+    positions ever cross stages."""
     act, mut = module.apply(
         {"params": params, "cache": pools}, x, train=False,
         mutable=["cache"], positions=positions, block_tables=tables,
@@ -789,61 +666,6 @@ def _pp_paged_decode_last_fn(module, stage, top_k, sentinel, params, pools,
     nxt = sample_rows(logits[:, -1], temps, key, top_k)
     live = (tables[:, 0] != sentinel).astype(positions.dtype)
     return mut["cache"], nxt, positions + live
-
-
-def _pp_verify_first_fn(module, stage, params, cache, tokens, drafts,
-                        positions):
-    """Stage-0 slice of :func:`_spec_verify_fn`: build the verify window
-    and run this stage's layers over it. The index leaves are left at
-    ``positions + K``; the rewind to ``positions + commit`` happens in
-    :func:`_pp_index_rewind_fn` once the LAST stage has decided the
-    commit (the commit is a device scalar vector — the rewind jit
-    consumes it without a host sync)."""
-    window = jnp.concatenate([tokens[:, None], drafts[:, :-1]], axis=1)
-    cache = cache_with_index(cache, positions)
-    act, mut = module.apply(
-        {"params": params, "cache": cache}, window, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    return mut["cache"], act
-
-
-def _pp_verify_fn(module, stage, params, cache, act, positions):
-    """Middle-stage slice of :func:`_spec_verify_fn`."""
-    cache = cache_with_index(cache, positions)
-    act, mut = module.apply(
-        {"params": params, "cache": cache}, act, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    return mut["cache"], act
-
-
-def _pp_verify_last_fn(module, stage, top_k, params, cache, act, drafts,
-                       tokens, temps, spec_ok, remaining, positions, key):
-    """Last-stage slice of :func:`_spec_verify_fn`: head + accept +
-    THIS stage's index rewind (earlier stages rewind via
-    :func:`_pp_index_rewind_fn` with the returned commit)."""
-    cache = cache_with_index(cache, positions)
-    logits, mut = module.apply(
-        {"params": params, "cache": cache}, act, train=False,
-        mutable=["cache"], stage=stage,
-    )
-    out, commit = _spec_accept(logits, drafts, tokens, temps, spec_ok,
-                               remaining, key, top_k)
-    cache = cache_with_index(mut["cache"], positions + commit)
-    new_tok = jnp.where(
-        commit > 0,
-        jnp.take_along_axis(
-            out, jnp.maximum(commit - 1, 0)[:, None], axis=1)[:, 0],
-        tokens)
-    return cache, new_tok, out, commit
-
-
-def _pp_index_rewind_fn(cache, positions, commit):
-    """Roll a non-last stage's index leaves back from ``positions + K``
-    to ``positions + commit`` after a verify — the per-stage half of the
-    dense rollback contract."""
-    return cache_with_index(cache, positions + commit)
 
 
 def _pp_paged_verify_first_fn(module, stage, params, pools, tokens, drafts,
@@ -892,13 +714,11 @@ def _pp_paged_verify_last_fn(module, stage, top_k, params, pools, act,
 
 @dataclasses.dataclass
 class _PrefillJob:
-    """Partial-prefill progress for a slot still being admitted: the
-    single-row cache under construction, how far into the prompt it is
-    (prefix-cache splice included), and the pinned match to release."""
+    """Partial-prefill progress for a slot still being admitted: how far
+    into the prompt its pool blocks are written (the matched prefix
+    included)."""
 
-    cache: object                 # single-row KV cache pytree
-    pos: int                      # prompt tokens already in the cache
-    match: object | None          # PrefixMatch to release on completion
+    pos: int                      # prompt tokens already in the pool
     matched_tokens: int
     chunks_done: int = 0
     device_s: float = 0.0         # prefill device time (TTFT's other half)
@@ -923,7 +743,7 @@ class _InflightTick:
     harvest will read, the decodable rows the dispatch covered (the
     stream targets — the slot table may gain or lose entries before the
     harvest, and a row must stream iff it was decodable AT DISPATCH and
-    its slot is still alive), and — plain paged ticks — the slots whose
+    its slot is still alive), and — plain ticks — the slots whose
     host ``_lens`` watermark the dispatch optimistically advanced, so a
     teardown detected mid-flight can roll the advance back before
     adopting blocks. With ``pipeline_depth>1`` on a pp mesh each tick
@@ -961,9 +781,9 @@ class _SlotState:
     last_token_t: float
     # Non-None while the slot's prompt is still prefilling (chunked
     # admission): the row sits in the decode batch but its garbage output
-    # is discarded until the finished cache is spliced in.
+    # is discarded until the prompt's last chunk has run.
     prefill: _PrefillJob | None = None
-    # Paged mode: when this slot was admitted (preemption prefers the
+    # When this slot was admitted (preemption prefers the
     # YOUNGEST victim — least sunk work thrown away), the private block
     # ids it owns (block indices first_block, first_block+1, ... of its
     # table), and the pinned shared-prefix match its table head points
@@ -1028,36 +848,29 @@ class ServingEngine:
     (default) keeps monolithic admission. Greedy output is
     token-identical either way.
 
-    ``prefix_cache_mb``: > 0 enables the device-resident prefix cache
-    (:class:`~distkeras_tpu.serving.prefix_cache.PrefixCache`) under that
-    byte budget, with ``prefix_block_tokens``-token blocks: prompts
-    sharing a cached prefix (system prompts, few-shot templates) splice
-    the matched blocks instead of recomputing them, and the scheduler
-    prefers cache-hitting requests within a priority class. Pass
-    ``prefix_cache=`` to inject a pre-built pool (exact capacity
-    control, test fixtures); the cache is NOT thread-safe — it must be
-    driven by a single engine's loop at a time.
-
-    ``kv_pool_mb`` > 0 (or ``paged=True`` with ``kv_pool_blocks``)
-    selects **paged KV**: slots allocate fixed-size blocks
-    (``kv_block_tokens`` tokens) from ONE shared pool
-    (:class:`~distkeras_tpu.serving.prefix_cache.KVBlockPool`) instead
-    of a dense per-slot cache — see the module docstring for what that
-    buys (capacity ∝ resident tokens, zero-copy prefix sharing,
-    preempt-and-requeue oversubscription, long-context admission). In
-    paged mode prefix caching is inherent (``prefix_cache_mb`` is
-    subsumed by the pool budget; passing ``prefix_cache=`` is an error).
+    The KV cache is ONE block pool
+    (:class:`~distkeras_tpu.serving.prefix_cache.KVBlockPool`): slots
+    allocate fixed-size blocks (``kv_block_tokens`` tokens) from it and
+    the same blocks are the prefix cache — see the module docstring for
+    what that buys (zero-copy prefix sharing, capacity ∝ resident
+    tokens, preempt-and-requeue oversubscription, long-context
+    admission). ``kv_pool_mb`` sizes it by a byte budget,
+    ``kv_pool_blocks`` by a block count (exact capacity control, test
+    fixtures); with neither it holds ``slots × ceil(limit /
+    kv_block_tokens)`` blocks, a whole context for every slot, so the
+    pool never runs dry. Prompts sharing a cached prefix (system
+    prompts, few-shot templates) skip its prefill, and the scheduler
+    prefers cache-hitting requests within a priority class. The pool is
+    NOT thread-safe — it is driven by this engine's loop alone.
 
     ``max_context``: cap each request's context (prompt + new tokens)
-    below the model's trained length. In DENSE mode this also shrinks
-    the pre-reserved per-slot cache to ``max_context`` positions — the
-    knob that makes a fixed KV byte budget an explicit trade between
-    slots and padded max length (the trade paged mode removes).
+    below the model's trained length; it also bounds each slot's block
+    table, and so the pool derived when no budget is given.
 
     ``draft_model``/``draft_variables``/``spec_k``: speculative decoding
     (see the module docstring). The draft must share the target's vocab
     (proposals are target token ids) and keeps its own dense per-slot
-    cache whatever the target's paging; the zoo pairs gpt_tiny (draft)
+    cache beside the target's pool; the zoo pairs gpt_tiny (draft)
     with gpt_small (target). K trades draft work against acceptance:
     each tick costs one scanned draft dispatch (a heal apply + K
     proposal steps) + one K-wide verify and commits up to K tokens per
@@ -1070,7 +883,7 @@ class ServingEngine:
     ``mesh``: a :func:`distkeras_tpu.parallel.mesh.serving_mesh` turns
     this ONE engine into a GSPMD tensor-parallel replica (see the
     module docstring): params laid out per their logical axes, KV
-    leaves heads-sharded, tables/slot/scheduler state replicated host
+    pool leaves heads-sharded, tables/slot/scheduler state replicated host
     metadata, every compiled callable pinned to explicit in/out
     shardings. The model's ``num_heads``/``mlp_dim``/``vocab_size``
     must divide the mesh's ``tp`` axis (validated here, typed). Greedy
@@ -1106,10 +919,6 @@ class ServingEngine:
         auditor: RecompileAuditor | None = None,
         arm_auditor_after_warmup: bool = False,
         prefill_chunk: int | None = None,
-        prefix_cache_mb: float = 0.0,
-        prefix_block_tokens: int = 16,
-        prefix_cache: PrefixCache | None = None,
-        paged: bool = False,
         kv_pool_mb: float = 0.0,
         kv_block_tokens: int = 16,
         kv_pool_blocks: int | None = None,
@@ -1167,7 +976,6 @@ class ServingEngine:
         if self._spec and draft_variables is None:
             raise ValueError("draft_model needs draft_variables (the draft's "
                              "trained weights)")
-        self._paged = bool(paged or kv_pool_mb > 0 or kv_pool_blocks)
         # GSPMD-sharded serving: ONE replica spread over a device mesh's
         # "tp" axis. Validated up front — a bad mesh must be a typed
         # ValueError here, not a jax lowering error three layers down.
@@ -1199,16 +1007,10 @@ class ServingEngine:
                     f"cluster), not a dp mesh axis inside one engine")
             self._replicated = NamedSharding(mesh, P())
         # Constrained (structured) decoding: the decode executable takes
-        # a per-slot token-mask operand. Paged-only (the mask hook lives
-        # in the paged decode step) and single-stage only (the mask
+        # a per-slot token-mask operand. Single-stage only (the mask
         # lands on the LAST stage's logits; threading it through the pp
         # chain is future work).
         self._constrained_mode = bool(constrained)
-        if self._constrained_mode and not self._paged:
-            raise ValueError(
-                "constrained=True requires paged KV (kv_pool_mb / "
-                "kv_pool_blocks): the token-mask hook lives in the paged "
-                "decode step")
         if self._constrained_mode and self._pp > 1:
             raise ValueError(
                 "constrained=True is not supported on a pp mesh yet")
@@ -1242,7 +1044,7 @@ class ServingEngine:
                 "the host tier's gather/scatter programs span the whole "
                 "pool, which is stage-partitioned under pp")
         # Geometry probe: the plain decode-slots config, for the trained
-        # context limit and (paged) the per-token KV byte cost.
+        # context limit and the per-token KV byte cost.
         _, base_cfg = _decode_module(model, slots=True)
         if mesh is not None and self._tp > 1:
             bad = [f"{name}={val}"
@@ -1263,66 +1065,36 @@ class ServingEngine:
             self.limit = int(max_context)
         else:
             self.limit = base_limit
-        if self._paged:
-            if prefix_cache is not None:
-                raise ValueError(
-                    "paged mode subsumes the prefix cache (the KV pool "
-                    "IS the prefix cache); do not pass prefix_cache=")
-            if kv_block_tokens < 1:
-                raise ValueError(
-                    f"kv_block_tokens must be >= 1, got {kv_block_tokens}")
-            bt = int(kv_block_tokens)
-            table_blocks = -(-self.limit // bt)
-            # Per-token KV byte cost: the model's own statement (K and V
-            # of one token through every layer, as the pool holds them).
-            bytes_per_block = bt * base_cfg.kv_token_bytes
-            if kv_pool_blocks is not None:
-                capacity = int(kv_pool_blocks)
-            else:
-                capacity = int(kv_pool_mb * 2**20) // bytes_per_block
-            if capacity < 1:
-                raise ValueError(
-                    f"kv_pool_mb={kv_pool_mb} holds zero "
-                    f"{bt}-token blocks (one block = {bytes_per_block} "
-                    f"bytes)")
-            self._module, self._cfg = _decode_module(
-                model, slots=True, paged_blocks=capacity, page_tokens=bt,
-                page_table_blocks=table_blocks, tp_mesh=mesh)
-            # Prefill pad-width bound. NOT the table reach (table_blocks
-            # * bt, which rounds UP past the context when bt doesn't
-            # divide it): a pad width past max_seq_len would make the
-            # positional dynamic_slice clamp BACKWARD and embed the
-            # chunk's real tokens at wrong positions. submit() caps
-            # every sequence at self.limit, so this loses nothing.
-            self._cache_len = self.limit
-            self.kv_block_tokens = bt
-            self._table_blocks = table_blocks
-            # Table sentinel: an id one past the pool marks "unallocated"
-            # — paged_kv_update drops writes there, paged_attention masks
-            # the reads.
-            self._sentinel = capacity
+        if kv_block_tokens < 1:
+            raise ValueError(
+                f"kv_block_tokens must be >= 1, got {kv_block_tokens}")
+        bt = int(kv_block_tokens)
+        table_blocks = -(-self.limit // bt)
+        # Per-token KV byte cost: the model's own statement (K and V
+        # of one token through every layer, as the pool holds them).
+        bytes_per_block = bt * base_cfg.kv_token_bytes
+        if kv_pool_blocks is not None:
+            capacity = int(kv_pool_blocks)
+        elif kv_pool_mb > 0:
+            capacity = int(kv_pool_mb * 2**20) // bytes_per_block
         else:
-            dense_len = (int(max_context) if max_context is not None
-                         else base_cfg.max_seq_len)
-            overrides = {}
-            if max_context is not None or self._spec:
-                # Speculative headroom: a verify window writes K+1 K/V
-                # vectors starting at the row's fed count, which for a
-                # request using its whole context reaches past the
-                # request limit. Extending the CACHE (never the
-                # positional table — params stay layout-identical) by
-                # spec_k rows keeps those overhang writes from clamping
-                # backward over real prefix K/V; the overhang itself is
-                # rejected-draft garbage, rolled back by the index
-                # rewind and masked until overwritten.
-                overrides["decode_cache_len"] = dense_len + (
-                    self.spec_k if self._spec else 0)
-            self._module, self._cfg = _decode_module(
-                model, slots=True, tp_mesh=mesh, **overrides)
-            # Prefill pad-width bound: the REQUEST context, not the
-            # spec-extended cache — prefill programs stay identical to a
-            # non-speculating engine's.
-            self._cache_len = dense_len
+            # No budget given: a whole context for every slot, so the
+            # pool never runs dry and nothing is ever preempted.
+            capacity = int(slots) * table_blocks
+        if capacity < 1:
+            raise ValueError(
+                f"kv_pool_mb={kv_pool_mb} / kv_pool_blocks="
+                f"{kv_pool_blocks} holds zero {bt}-token blocks (one "
+                f"block = {bytes_per_block} bytes)")
+        self._module, self._cfg = _decode_module(
+            model, slots=True, paged_blocks=capacity, page_tokens=bt,
+            page_table_blocks=table_blocks, tp_mesh=mesh)
+        self.kv_block_tokens = bt
+        self._table_blocks = table_blocks
+        # Table sentinel: an id one past the pool marks "unallocated"
+        # — paged_kv_update drops writes there, paged_attention masks
+        # the reads.
+        self._sentinel = capacity
         if top_k is not None and not 1 <= top_k <= self._cfg.vocab_size:
             # Same bound generate() enforces: out-of-range top_k would
             # silently disable (or invert) the filtering via clamped
@@ -1375,9 +1147,6 @@ class ServingEngine:
                 # resolve their logical axes against the stage's OWN
                 # sub-mesh — so every param/KV leaf lands only on its
                 # stage's devices, at boot and at every later hot swap.
-                # The cache template is micro-batch-shaped: with
-                # pipeline_depth>1 each micro-batch owns an independent
-                # [mb_size, ...] cache tree per stage.
                 abstract = jax.eval_shape(
                     lambda r: self._module.init(
                         r, jnp.zeros((self._mb_size, 1), jnp.int32),
@@ -1441,45 +1210,25 @@ class ServingEngine:
         self._prefill_rr = 0  # round-robin cursor over prefilling slots
         self._key = jax.random.PRNGKey(seed)
 
-        # Device-resident batch state. In paged mode ``_cache`` holds the
-        # SHARED block pools (per-layer [capacity, bt, H*D] leaves, no
-        # per-slot index leaves — positions/tables are passed per call);
-        # in dense mode, the classic [slots, L, H, D] per-slot caches.
+        # Device-resident batch state. ``_cache`` holds the SHARED block
+        # pools (per-layer [capacity, bt, H*D] leaves, no per-slot index
+        # leaves — positions/tables are passed per call).
         # Sharded: the KV leaves are committed to their heads-sharded
         # layout at creation, and every compiled program's out_shardings
         # pins the same layout, so the bytes never migrate.
         if self._pp > 1:
-            # Stage-partitioned state. ``_cache`` is a per-stage list —
-            # paged: each stage's slice of the shared pools; dense: a
-            # per-stage list of per-MICRO-BATCH [mb_size, ...] trees
-            # (micro-batches must own disjoint device buffers so depth>1
-            # ticks never contend for a donated cache). ``_tokens`` /
+            # Stage-partitioned state. ``_cache`` is a per-stage list:
+            # each stage's slice of the shared pools. ``_tokens`` /
             # ``_temps`` are per-micro-batch vectors committed to the
             # LAST stage — where sampling produces and admission updates
             # them — so every feed of the stage-0 decode program carries
             # the same placement and its jit keys one cache entry.
-            plan = self._stage_plan
-            if self._paged:
-                self._cache = [
-                    jax.device_put(part, sh)
-                    for part, sh in zip(
-                        plan.split_tree(
-                            _empty_cache(self._module, self.slots)),
-                        self._cache_shardings)]
-            else:
-                mb_tree = plan.split_tree(
-                    _empty_cache(self._module, self._mb_size))
-                # Fresh zeros PER micro-batch: device_put of one shared
-                # source tree can alias, and an aliased buffer donated
-                # by micro-batch m's tick would be deleted out from
-                # under micro-batch m+1's.
-                self._cache = [
-                    [jax.device_put(
-                        jax.tree.map(
-                            lambda a: jnp.zeros(a.shape, a.dtype), part),
-                        sh)
-                     for _ in range(self._mb_count)]
-                    for part, sh in zip(mb_tree, self._cache_shardings)]
+            self._cache = [
+                jax.device_put(part, sh)
+                for part, sh in zip(
+                    self._stage_plan.split_tree(
+                        _empty_cache(self._module, self.slots)),
+                    self._cache_shardings)]
             rep_last = self._stage_rep[-1]
             self._tokens = [
                 jax.device_put(jnp.zeros((self._mb_size,), jnp.int32),
@@ -1508,167 +1257,88 @@ class ServingEngine:
                 self._temps = jax.device_put(self._temps, self._replicated)
         self._slot_state: list[_SlotState | None] = [None] * self.slots
 
-        self.kv_pool: KVBlockPool | None = None
+        self.kv_pool = KVBlockPool(
+            capacity, self.kv_block_tokens,
+            bytes_per_block=bytes_per_block,
+            registry=self.metrics.registry)
+        # Host-side per-slot paging state: block tables (row i =
+        # slot i's pool row per block index, sentinel = unallocated)
+        # and written-KV lengths. The decode step gets (masked)
+        # device views of these each tick.
+        self._tables = np.full((self.slots, self._table_blocks),
+                               self._sentinel, np.int32)
+        self._lens = np.zeros((self.slots,), np.int64)
+        # Admission parking: when a pop'd request could not get
+        # blocks (and nobody lower-priority was preemptible) it is
+        # requeued at its class head and admission pauses until the
+        # pool's version moves (a free, eviction-eligibility change,
+        # or adoption) — re-matching the same head request every
+        # iteration would only burn host time and skew hit stats.
+        self._parked_at_version: int | None = None
+        self._parked_req: Request | None = None
+        # Device-side masked table cache with a DIRTY flag: the
+        # masked view only changes when a table row mutates
+        # (admission reserve / growth / preemption / teardown) or a
+        # slot's decodable status flips (prefill completion) — each
+        # of those sites sets the flag, and the per-tick upload is
+        # skipped while it is clear. The flag replaces an
+        # O(slots × blocks) np.array_equal compare that ran on
+        # EVERY tick just to conclude "unchanged" bt-1 times out of
+        # bt.
+        self._mark_tables_dirty()
+        self._tables_dev = None
+        # Device-side positions with the SAME dirty gating: the
+        # decode step returns each live row's position + 1, so the
+        # steady-state tick re-feeds the returned device vector and
+        # the per-tick host build + H2D upload only happens when the
+        # decodable set or a watermark actually changed (admission,
+        # growth, preemption, teardown, prefill completion, spec
+        # commits).
+        self._positions_dev = None
+        self._positions_dirty = True
+        # Cache-aware admission: the scheduler may prefer (within one
+        # priority class, bounded window) the queued request whose
+        # prefix is already resident — see Scheduler.pop.
+        self.scheduler.cache_probe = self.kv_pool.probe
+        # Host-RAM (optionally disk-backed) spill tier under the
+        # pool: eviction victims spill D2H as exact KVX1 bytes and
+        # re-admit H2D on a trie miss during admission — see
+        # serving/kv_tier.py. The spill hook fires inside
+        # KVBlockPool._alloc, which only runs on the engine loop (or
+        # the executor while the loop awaits it) and always after a
+        # pipeline barrier (in-flight growth asks the pool's
+        # next_alloc_spills first), so the gather never races a
+        # donated in-flight tick.
         self.kv_tier = None
-        if self._paged:
-            self.kv_pool = KVBlockPool(
-                capacity, self.kv_block_tokens,
-                bytes_per_block=bytes_per_block,
+        if kv_host_tier_mb > 0:
+            from distkeras_tpu.serving.kv_tier import HostKVTier
+
+            self.kv_tier = HostKVTier(
+                int(kv_host_tier_mb * 2**20), bt,
+                disk_dir=kv_disk_tier_dir,
+                disk_budget_bytes=int(kv_disk_tier_mb * 2**20),
+                watermark=kv_tier_watermark,
                 registry=self.metrics.registry)
-            # Host-side per-slot paging state: block tables (row i =
-            # slot i's pool row per block index, sentinel = unallocated)
-            # and written-KV lengths. The decode step gets (masked)
-            # device views of these each tick.
-            self._tables = np.full((self.slots, self._table_blocks),
-                                   self._sentinel, np.int32)
-            self._lens = np.zeros((self.slots,), np.int64)
-            # Admission parking: when a pop'd request could not get
-            # blocks (and nobody lower-priority was preemptible) it is
-            # requeued at its class head and admission pauses until the
-            # pool's version moves (a free, eviction-eligibility change,
-            # or adoption) — re-matching the same head request every
-            # iteration would only burn host time and skew hit stats.
-            self._parked_at_version: int | None = None
-            self._parked_req: Request | None = None
-            # Device-side masked table cache with a DIRTY flag: the
-            # masked view only changes when a table row mutates
-            # (admission reserve / growth / preemption / teardown) or a
-            # slot's decodable status flips (prefill completion) — each
-            # of those sites sets the flag, and the per-tick upload is
-            # skipped while it is clear. The flag replaces an
-            # O(slots × blocks) np.array_equal compare that ran on
-            # EVERY tick just to conclude "unchanged" bt-1 times out of
-            # bt. (Positions still upload every tick — they advance
-            # with each decoded token.)
-            self._mark_tables_dirty()
-            self._tables_dev = None
-            # Device-side positions with the SAME dirty gating: the
-            # decode step returns each live row's position + 1, so the
-            # steady-state tick re-feeds the returned device vector and
-            # the per-tick host build + H2D upload only happens when the
-            # decodable set or a watermark actually changed (admission,
-            # growth, preemption, teardown, prefill completion, spec
-            # commits).
-            self._positions_dev = None
-            self._positions_dirty = True
-            self.prefix_cache = None
-            self.scheduler.cache_probe = self.kv_pool.probe
-            # Host-RAM (optionally disk-backed) spill tier under the
-            # pool: eviction victims spill D2H as exact KVX1 bytes and
-            # re-admit H2D on a trie miss during admission — see
-            # serving/kv_tier.py. The spill hook fires inside
-            # _BlockTrie._alloc, which only runs on the engine loop (or
-            # the executor while the loop awaits it) and always after a
-            # pipeline barrier (in-flight growth asks the pool's
-            # next_alloc_spills first), so the gather never races a
-            # donated in-flight tick.
-            if kv_host_tier_mb > 0:
-                from distkeras_tpu.serving.kv_tier import HostKVTier
-
-                self.kv_tier = HostKVTier(
-                    int(kv_host_tier_mb * 2**20), bt,
-                    disk_dir=kv_disk_tier_dir,
-                    disk_budget_bytes=int(kv_disk_tier_mb * 2**20),
-                    watermark=kv_tier_watermark,
-                    registry=self.metrics.registry)
-                self.kv_pool.spill_hook = self._spill_block
-                # Allocation BURSTS (a multi-block admission or import
-                # evicting several victims at once) spill through the
-                # batched hook: one D2H gather for the whole burst,
-                # mirroring _readmit_from_tier's one-scatter H2D path.
-                self.kv_pool.spill_many_hook = self._spill_blocks
-            # Trace context for spill exemplars: the admission /
-            # growth / import currently driving allocations.
-            self._tier_trace_id: str | None = None
-        else:
-            # Single-row cache geometry, captured ONCE: eval_shape traces
-            # the module's init, far too slow to re-run per admission.
-            # Derived from the SERVING module (so a max_context cap is
-            # reflected in the row length). The zeroed cache itself comes
-            # from ONE jitted factory (fused device-side zeros) — only
-            # paid on a prefix-cache MISS: a hit materializes its row
-            # cache straight from the matched pool blocks
-            # (PrefixCache.materialize), never building the covered
-            # leaves as zeros first.
-            self._row_shapes = jax.eval_shape(
-                lambda r: self._module.init(
-                    r, jnp.zeros((1, 1), jnp.int32), train=False),
-                jax.random.PRNGKey(0),
-            )["cache"]
-            self._row_shardings = None
-            if mesh is not None:
-                from distkeras_tpu.parallel.sharding import (
-                    kv_pytree_shardings,
-                )
-
-                if self._pp > 1:
-                    # A "row cache" under pp is a per-stage LIST of
-                    # single-row subtrees, each placed on its stage; the
-                    # prefill chain, the admit splice, and the prefix
-                    # cache all thread the list.
-                    self._row_shapes = self._stage_plan.split_tree(
-                        self._row_shapes)
-                    self._row_shardings = [
-                        kv_pytree_shardings(m, part)
-                        for m, part in zip(self._stage_meshes,
-                                           self._row_shapes)]
-                else:
-                    self._row_shardings = kv_pytree_shardings(
-                        mesh, self._row_shapes)
-            if self._pp > 1:
-                fresh_jits = [
-                    jax.jit(
-                        named_program(
-                            functools.partial(
-                                lambda shapes: jax.tree.map(
-                                    lambda s: jnp.zeros(s.shape, s.dtype),
-                                    shapes),
-                                part),
-                            f"serving_fresh_row_s{s}"),
-                        out_shardings=sh)
-                    for s, (part, sh) in enumerate(zip(
-                        self._row_shapes, self._row_shardings))]
-                self._fresh_row_cache = (
-                    lambda jits=fresh_jits: [f() for f in jits])
-            else:
-                self._fresh_row_cache = jax.jit(
-                    named_program(
-                        lambda: jax.tree.map(
-                            lambda s: jnp.zeros(s.shape, s.dtype),
-                            self._row_shapes),
-                        "serving_fresh_row"),
-                    **({} if mesh is None
-                       else {"out_shardings": self._row_shardings}))
-
-            # Prefix cache: a byte-budgeted pool of KV blocks shared
-            # across requests (serving/prefix_cache.py). An explicit
-            # instance wins (tests / multi-engine sharing);
-            # prefix_cache_mb > 0 builds one. Sharded engines hand the
-            # mesh down so the cache's device pools (and the rows its
-            # materialize builds) live in the same heads-sharded layout
-            # the batch cache does.
-            if prefix_cache is not None:
-                self.prefix_cache = prefix_cache
-            elif prefix_cache_mb > 0:
-                self.prefix_cache = PrefixCache(
-                    self._row_shapes, block_tokens=prefix_block_tokens,
-                    budget_bytes=int(prefix_cache_mb * 2**20),
-                    registry=self.metrics.registry, mesh=mesh,
-                    stage_meshes=self._stage_meshes)
-            else:
-                self.prefix_cache = None
-            if self.prefix_cache is not None:
-                # Cache-aware admission: the scheduler may prefer (within
-                # one priority class, bounded window) the queued request
-                # whose prefix is already resident — see Scheduler.pop.
-                self.scheduler.cache_probe = self.prefix_cache.probe
+            self.kv_pool.spill_hook = self._spill_block
+            # Allocation BURSTS (a multi-block admission or import
+            # evicting several victims at once) spill through the
+            # batched hook: one D2H gather for the whole burst,
+            # mirroring _readmit_from_tier's one-scatter H2D path.
+            self.kv_pool.spill_many_hook = self._spill_blocks
+        # Trace context for spill exemplars: the admission /
+        # growth / import currently driving allocations.
+        self._tier_trace_id: str | None = None
+        # Pending KV export/import operations, serviced by the run loop
+        # between iterations: (kind, arg, event, result). Always empty
+        # under pp (request_kv_export / _import refuse there).
+        self._pending_kv: list[tuple] = []
 
         # Speculative decoding: a small draft model proposes spec_k
         # tokens per tick (one scanned dispatch), ONE batched target
         # call verifies all K+1 positions, and a masked accept commits
         # the longest greedy-consistent prefix — token-identical to
         # generate() by construction. The draft keeps its own DENSE
-        # per-slot cache regardless of the target's paging (it is small
+        # per-slot cache beside the target's pool (it is small
         # by definition — gpt_tiny drafting for gpt_small — so paying
         # worst-case length for it is noise next to the target pool).
         if self._spec:
@@ -1710,12 +1380,6 @@ class ServingEngine:
                     "serving_fresh_draft_row"),
                 **({} if mesh is None
                    else {"out_shardings": self._draft_rep}))
-            # Host-side fed-token counts (int32 [slots], DENSE mode):
-            # the per-row position the draft's entry rewind and the
-            # dense verify's index rewind both derive from. Paged mode
-            # already tracks the same quantity as ``_lens`` and uses
-            # that instead.
-            self._spec_pos = np.zeros((self.slots,), np.int32)
             # Set when a live row accepted zero drafts: the next tick
             # runs the one-token fallback step (progress guarantee).
             self._spec_owe_fallback = False
@@ -1725,7 +1389,7 @@ class ServingEngine:
         # server's lifetime (see decode_compile_count()). The live batch
         # cache is donated — the engine rebinds it from each call's
         # outputs, and donation keeps the KV caches updating in place
-        # instead of copying per decoded token. For the paged pools
+        # instead of copying per decoded token. For the pools
         # that takes two things, and tests/test_tpu_compile.py holds
         # both in the programs compiled for a v5e: every leaf is in the
         # executable's input_output_alias, AND the leaf is stored
@@ -1742,9 +1406,8 @@ class ServingEngine:
         # invalidate the buffer ([slots] int32 — the extra copy per tick
         # is 4 bytes per slot). _temps is NOT donated either (it
         # persists across iterations). The
-        # prefill's incoming cache (single-row scratch in dense mode, the
-        # shared pools in paged mode) is donated too: a chunk chain
-        # threads it through every call, updating in place.
+        # prefill's incoming cache (the shared pools) is donated too: a
+        # chunk chain threads it through every call, updating in place.
         # Sharded engines jit every callable with EXPLICIT in_shardings/
         # out_shardings: params in their logical-axis layout, KV leaves
         # heads-sharded, every index/token/table operand replicated. The
@@ -1765,16 +1428,16 @@ class ServingEngine:
         rep = self._replicated
         psh = self._param_shardings
         csh = self._cache_shardings
-        # What the module's plain paged decode tick counts, by name (none
+        # What the module's plain decode tick counts, by name (none
         # for most models): harvested with the tokens, published as
         # ``<name>_total`` (ServingMetrics.record_tick_counters).
         self._tick_counters = tuple(
             getattr(self._module, "tick_counters", ())
-            if self._paged and self._pp == 1 and not self._constrained_mode
+            if self._pp == 1 and not self._constrained_mode
             else ())
         if self._pp > 1:
             self._build_pp_programs(top_k, auditor)
-        elif self._paged:
+        else:
             self._prefill = _sharded_jit(
                 "serving_prefill",
                 functools.partial(_paged_prefill_fn, self._module, top_k),
@@ -1849,25 +1512,6 @@ class ServingEngine:
             self._kv_scatter = _sharded_jit(
                 "serving_kv_scatter",
                 _kv_scatter_fn, (csh, rep, rep), csh, donate=(0,))
-            # Pending export/import operations, serviced by the run
-            # loop between iterations: (kind, arg, event, result).
-            self._pending_kv: list[tuple] = []
-        else:
-            rsh = self._row_shardings
-            self._prefill = _sharded_jit(
-                "serving_prefill",
-                functools.partial(_prefill_fn, self._module, top_k),
-                (psh, rsh, rep, rep, rep, rep, rep), (rsh, rep),
-                donate=(1,))
-            self._admit_jit = _sharded_jit(
-                "serving_admit",
-                _admit_fn,
-                (csh, rep, rep, rep, rsh, rep, rep), (csh, rep, rep),
-                donate=(0, 1, 2))
-            self._decode_step = _sharded_jit(
-                "serving_decode",
-                functools.partial(_decode_fn, self._module, top_k),
-                (psh, csh, rep, rep, rep), (csh, rep), donate=(1,))
         if self._spec and self._pp == 1:
             # Draft cache donated; tokens are NOT (the verify consumes
             # them right after). Verify donates cache + tokens exactly
@@ -1878,21 +1522,12 @@ class ServingEngine:
                 functools.partial(_spec_draft_fn, self._draft_module,
                                   self.spec_k),
                 (rep, rep, rep, rep, rep), (rep, rep), donate=(1,))
-            if self._paged:
-                self._verify_step = _sharded_jit(
-                    "serving_verify",
-                    functools.partial(_paged_spec_verify_fn, self._module,
-                                      top_k),
-                    (psh, csh, rep, rep, rep, rep, rep, rep, rep, rep,
-                     rep),
-                    (csh, rep, rep, rep), donate=(1, 2))
-            else:
-                self._verify_step = _sharded_jit(
-                    "serving_verify",
-                    functools.partial(_spec_verify_fn, self._module,
-                                      top_k),
-                    (psh, csh, rep, rep, rep, rep, rep, rep, rep),
-                    (csh, rep, rep, rep), donate=(1, 2))
+            self._verify_step = _sharded_jit(
+                "serving_verify",
+                functools.partial(_paged_spec_verify_fn, self._module,
+                                  top_k),
+                (psh, csh, rep, rep, rep, rep, rep, rep, rep, rep, rep),
+                (csh, rep, rep, rep), donate=(1, 2))
             self._draft_prefill = _sharded_jit(
                 "serving_draft_prefill",
                 functools.partial(_draft_prefill_fn, self._draft_module),
@@ -1915,24 +1550,23 @@ class ServingEngine:
         if auditor is not None and self._pp == 1:
             self._prefill = auditor.wrap(self._prefill, "serving_prefill")
             self._admit_jit = auditor.wrap(self._admit_jit, "serving_admit")
-            if self._paged:
-                # Report-only (never armed): export/import are rare
-                # control-path operations, but their compile counts
-                # still belong in the audit report.
-                self._kv_gather = auditor.wrap(
-                    self._kv_gather, "serving_kv_gather")
-                self._kv_scatter = auditor.wrap(
-                    self._kv_scatter, "serving_kv_scatter")
-                # Request-kind programs: report-only, like the pow2
-                # prefill buckets — admission-path work, never per-tick.
-                self._prefill_logits = auditor.wrap(
-                    self._prefill_logits, "serving_prefill_logits")
-                self._fork_sample = auditor.wrap(
-                    self._fork_sample, "serving_fork_sample")
-                self._score_chunk = auditor.wrap(
-                    self._score_chunk, "serving_score_chunk")
-                self._embed_chunk = auditor.wrap(
-                    self._embed_chunk, "serving_embed_chunk")
+            # Report-only (never armed): export/import are rare
+            # control-path operations, but their compile counts
+            # still belong in the audit report.
+            self._kv_gather = auditor.wrap(
+                self._kv_gather, "serving_kv_gather")
+            self._kv_scatter = auditor.wrap(
+                self._kv_scatter, "serving_kv_scatter")
+            # Request-kind programs: report-only, like the pow2
+            # prefill buckets — admission-path work, never per-tick.
+            self._prefill_logits = auditor.wrap(
+                self._prefill_logits, "serving_prefill_logits")
+            self._fork_sample = auditor.wrap(
+                self._fork_sample, "serving_fork_sample")
+            self._score_chunk = auditor.wrap(
+                self._score_chunk, "serving_score_chunk")
+            self._embed_chunk = auditor.wrap(
+                self._embed_chunk, "serving_embed_chunk")
             self._decode_step = auditor.wrap(
                 self._decode_step, "serving_decode")
             if self._spec:
@@ -2028,7 +1662,7 @@ class ServingEngine:
     def _build_pp_programs(self, top_k, auditor) -> None:
         """Compile the per-stage serving programs for a ``tp=N,pp=M``
         mesh: each pipeline stage gets its OWN jits (prefill slice,
-        decode slice, admit splice, spec verify slice) at explicit
+        decode slice, spec verify slice) at explicit
         in/out shardings against the stage's sub-mesh, and thin host
         wrappers chain them under the monolithic call signatures the
         dispatch paths already use.
@@ -2038,13 +1672,12 @@ class ServingEngine:
         argument's actual committed placement, so every argument
         position must always ARRIVE placed the same way. The invariants
         here: tokens/temps always live on the LAST stage (ctor
-        device_put, admit + decode out_shardings); paged positions/
+        device_put, admit + decode out_shardings); positions/
         tables are device_put per stage once and then re-fed from that
         stage's own outputs; fresh host values (chunk offsets, split
         keys, slot ids) are uncommitted — placement-free — every call;
         and every value that CROSSES a stage boundary (the residual
-        activation, last-stage tokens feeding stage 0, the commit
-        vector feeding non-last rewinds) goes through
+        activation, last-stage tokens feeding stage 0) goes through
         :meth:`_to_stage` — jax auto-transfers only single-device
         arrays between 1-device stages, and a committed tp-sharded
         array fed to another stage's sub-mesh is a runtime placement
@@ -2072,123 +1705,59 @@ class ServingEngine:
             fn = build(name)
             return fn if auditor is None else auditor.wrap(fn, name)
 
-        if self._paged:
-            sent = self._sentinel
-            pf = [wrap(sjit(
-                functools.partial(_pp_paged_prefill_fn, mods[s],
-                                  plan.stage_arg(s)),
-                (psh[s], csh[s], reps[s], reps[s], reps[s]),
-                (csh[s], reps[s]), (1,)), f"serving_prefill_s{s}")
-                for s in range(last)]
-            pf_last = wrap(sjit(
-                functools.partial(_pp_paged_prefill_last_fn, mods[last],
-                                  plan.stage_arg(last), top_k),
-                (psh[last], csh[last]) + (reps[last],) * 6,
-                (csh[last], rep_last), (1,)), f"serving_prefill_s{last}")
+        sent = self._sentinel
+        pf = [wrap(sjit(
+            functools.partial(_pp_paged_prefill_fn, mods[s],
+                              plan.stage_arg(s)),
+            (psh[s], csh[s], reps[s], reps[s], reps[s]),
+            (csh[s], reps[s]), (1,)), f"serving_prefill_s{s}")
+            for s in range(last)]
+        pf_last = wrap(sjit(
+            functools.partial(_pp_paged_prefill_last_fn, mods[last],
+                              plan.stage_arg(last), top_k),
+            (psh[last], csh[last]) + (reps[last],) * 6,
+            (csh[last], rep_last), (1,)), f"serving_prefill_s{last}")
 
-            def prefill(params, pools, padded, start, true_len, table_row,
-                        temp, key):
-                pools = list(pools)
-                x = padded
-                for s in range(last):
-                    if s:
-                        x = hop(x, s)
-                    pools[s], x = pf[s](params[s], pools[s], x, start,
-                                        table_row)
-                pools[last], tok = pf_last(params[last], pools[last],
-                                           hop(x, last) if last else x,
-                                           start, true_len, table_row,
-                                           temp, key)
-                return pools, tok
+        def prefill(params, pools, padded, start, true_len, table_row,
+                    temp, key):
+            pools = list(pools)
+            x = padded
+            for s in range(last):
+                if s:
+                    x = hop(x, s)
+                pools[s], x = pf[s](params[s], pools[s], x, start,
+                                    table_row)
+            pools[last], tok = pf_last(params[last], pools[last],
+                                       hop(x, last) if last else x,
+                                       start, true_len, table_row,
+                                       temp, key)
+            return pools, tok
 
-            self._prefill = prefill
-            admit = wrap(sjit(_paged_admit_fn, (rep_last,) * 5,
-                              (rep_last, rep_last), (0, 1)),
-                         "serving_admit")
+        self._prefill = prefill
+        admit = wrap(sjit(_paged_admit_fn, (rep_last,) * 5,
+                          (rep_last, rep_last), (0, 1)),
+                     "serving_admit")
 
-            def admit_wrap(tokens, temps, slot, tok, temp):
-                mb, local = divmod(int(slot), self._mb_size)
-                tokens, temps = list(tokens), list(temps)
-                tokens[mb], temps[mb] = admit(
-                    tokens[mb], temps[mb], jnp.int32(local), tok, temp)
-                return tokens, temps
+        def admit_wrap(tokens, temps, slot, tok, temp):
+            mb, local = divmod(int(slot), self._mb_size)
+            tokens, temps = list(tokens), list(temps)
+            tokens[mb], temps[mb] = admit(
+                tokens[mb], temps[mb], jnp.int32(local), tok, temp)
+            return tokens, temps
 
-            self._admit_jit = admit_wrap
-            self._decode_steps = [wrap(sjit(
-                functools.partial(_pp_paged_decode_fn, mods[s],
-                                  plan.stage_arg(s), sent),
-                (psh[s], csh[s], reps[s], reps[s], reps[s]),
-                (csh[s], reps[s], reps[s]), (1,)), f"serving_decode_s{s}")
-                for s in range(last)]
-            self._decode_steps.append(wrap(sjit(
-                functools.partial(_pp_paged_decode_last_fn, mods[last],
-                                  plan.stage_arg(last), top_k, sent),
-                (psh[last], csh[last]) + (reps[last],) * 5,
-                (csh[last], rep_last, reps[last]), (1,)),
-                f"serving_decode_s{last}"))
-            # KV export/import are gated off under pp (the gather/
-            # scatter programs span the whole pool); the run loop still
-            # drains this (always-empty) queue.
-            self._pending_kv = []
-        else:
-            rsh = self._row_shardings
-            pf = [wrap(sjit(
-                functools.partial(_pp_prefill_fn, mods[s],
-                                  plan.stage_arg(s)),
-                (psh[s], rsh[s], reps[s], reps[s], reps[s]),
-                (rsh[s], reps[s]), (1,)), f"serving_prefill_s{s}")
-                for s in range(last)]
-            pf_last = wrap(sjit(
-                functools.partial(_pp_prefill_last_fn, mods[last],
-                                  plan.stage_arg(last), top_k),
-                (psh[last], rsh[last]) + (reps[last],) * 5,
-                (rsh[last], rep_last), (1,)), f"serving_prefill_s{last}")
-
-            def prefill(params, cache, padded, start, true_len, temp, key):
-                cache = list(cache)
-                x = padded
-                for s in range(last):
-                    if s:
-                        x = hop(x, s)
-                    cache[s], x = pf[s](params[s], cache[s], x, start,
-                                        true_len)
-                cache[last], tok = pf_last(params[last], cache[last],
-                                           hop(x, last) if last else x,
-                                           start, true_len, temp, key)
-                return cache, tok
-
-            self._prefill = prefill
-            splice = [wrap(sjit(_draft_admit_fn,
-                                (csh[s], reps[s], rsh[s]), csh[s], (0,)),
-                           f"serving_admit_s{s}") for s in range(S)]
-            sample_admit = wrap(sjit(_paged_admit_fn, (rep_last,) * 5,
-                                     (rep_last, rep_last), (0, 1)),
-                                "serving_admit")
-
-            def admit_wrap(cache, tokens, temps, slot, pre_cache, tok,
-                           temp):
-                mb, local = divmod(int(slot), self._mb_size)
-                loc = jnp.int32(local)
-                cache = [list(c) for c in cache]
-                for s in range(S):
-                    cache[s][mb] = splice[s](cache[s][mb], loc,
-                                             pre_cache[s])
-                tokens, temps = list(tokens), list(temps)
-                tokens[mb], temps[mb] = sample_admit(
-                    tokens[mb], temps[mb], loc, tok, temp)
-                return cache, tokens, temps
-
-            self._admit_jit = admit_wrap
-            self._decode_steps = [wrap(sjit(
-                functools.partial(_pp_decode_fn, mods[s],
-                                  plan.stage_arg(s)),
-                (psh[s], csh[s], reps[s]), (csh[s], reps[s]), (1,)),
-                f"serving_decode_s{s}") for s in range(last)]
-            self._decode_steps.append(wrap(sjit(
-                functools.partial(_pp_decode_last_fn, mods[last],
-                                  plan.stage_arg(last), top_k),
-                (psh[last], csh[last], reps[last], rep_last, reps[last]),
-                (csh[last], rep_last), (1,)), f"serving_decode_s{last}"))
+        self._admit_jit = admit_wrap
+        self._decode_steps = [wrap(sjit(
+            functools.partial(_pp_paged_decode_fn, mods[s],
+                              plan.stage_arg(s), sent),
+            (psh[s], csh[s], reps[s], reps[s], reps[s]),
+            (csh[s], reps[s], reps[s]), (1,)), f"serving_decode_s{s}")
+            for s in range(last)]
+        self._decode_steps.append(wrap(sjit(
+            functools.partial(_pp_paged_decode_last_fn, mods[last],
+                              plan.stage_arg(last), top_k, sent),
+            (psh[last], csh[last]) + (reps[last],) * 5,
+            (csh[last], rep_last, reps[last]), (1,)),
+            f"serving_decode_s{last}"))
         self._decode_step = None  # per-stage under pp: _decode_steps
         self._decode_audit_names = [f"serving_decode_s{s}"
                                     for s in range(S)]
@@ -2213,94 +1782,45 @@ class ServingEngine:
             self._draft_admit = wrap(sjit(
                 _draft_admit_fn, (rep0, rep0, rep0), rep0, (0,)),
                 "serving_draft_admit")
-            if self._paged:
-                vf0 = wrap(sjit(
-                    functools.partial(_pp_paged_verify_first_fn, mods[0],
-                                      plan.stage_arg(0)),
-                    (psh[0], csh[0]) + (reps[0],) * 4,
-                    (csh[0], reps[0]), (1,)), "serving_verify_s0")
-                vmid = [wrap(sjit(
-                    functools.partial(_pp_paged_verify_fn, mods[s],
-                                      plan.stage_arg(s)),
-                    (psh[s], csh[s], reps[s], reps[s], reps[s]),
-                    (csh[s], reps[s]), (1,)), f"serving_verify_s{s}")
-                    for s in range(1, last)]
-                vlast = wrap(sjit(
-                    functools.partial(_pp_paged_verify_last_fn, mods[last],
-                                      plan.stage_arg(last), top_k),
-                    (psh[last], csh[last]) + (reps[last],) * 10,
-                    (csh[last], rep_last, rep_last, rep_last), (1,)),
-                    f"serving_verify_s{last}")
+            vf0 = wrap(sjit(
+                functools.partial(_pp_paged_verify_first_fn, mods[0],
+                                  plan.stage_arg(0)),
+                (psh[0], csh[0]) + (reps[0],) * 4,
+                (csh[0], reps[0]), (1,)), "serving_verify_s0")
+            vmid = [wrap(sjit(
+                functools.partial(_pp_paged_verify_fn, mods[s],
+                                  plan.stage_arg(s)),
+                (psh[s], csh[s], reps[s], reps[s], reps[s]),
+                (csh[s], reps[s]), (1,)), f"serving_verify_s{s}")
+                for s in range(1, last)]
+            vlast = wrap(sjit(
+                functools.partial(_pp_paged_verify_last_fn, mods[last],
+                                  plan.stage_arg(last), top_k),
+                (psh[last], csh[last]) + (reps[last],) * 10,
+                (csh[last], rep_last, rep_last, rep_last), (1,)),
+                f"serving_verify_s{last}")
 
-                def verify(params, pools, tokens, drafts, temps, spec_ok,
-                           remaining, room, start, tables, key):
-                    toks = tokens[0]
-                    pools = list(pools)
-                    pools[0], act = vf0(params[0], pools[0], hop(toks, 0),
-                                        drafts, start, tables[0])
-                    for s in range(1, last):
-                        pools[s], act = vmid[s - 1](params[s], pools[s],
-                                                    hop(act, s), start,
-                                                    tables[s])
-                    pools[last], new_tok, out, commit = vlast(
-                        params[last], pools[last], hop(act, last),
-                        hop(drafts, last), toks,
-                        temps[0], spec_ok, remaining, room, start,
-                        tables[last], key)
-                    return pools, [new_tok], out, commit
+            def verify(params, pools, tokens, drafts, temps, spec_ok,
+                       remaining, room, start, tables, key):
+                toks = tokens[0]
+                pools = list(pools)
+                pools[0], act = vf0(params[0], pools[0], hop(toks, 0),
+                                    drafts, start, tables[0])
+                for s in range(1, last):
+                    pools[s], act = vmid[s - 1](params[s], pools[s],
+                                                hop(act, s), start,
+                                                tables[s])
+                pools[last], new_tok, out, commit = vlast(
+                    params[last], pools[last], hop(act, last),
+                    hop(drafts, last), toks,
+                    temps[0], spec_ok, remaining, room, start,
+                    tables[last], key)
+                return pools, [new_tok], out, commit
 
-                self._verify_step = verify
-            else:
-                vf0 = wrap(sjit(
-                    functools.partial(_pp_verify_first_fn, mods[0],
-                                      plan.stage_arg(0)),
-                    (psh[0], csh[0], reps[0], reps[0], reps[0]),
-                    (csh[0], reps[0]), (1,)), "serving_verify_s0")
-                vmid = [wrap(sjit(
-                    functools.partial(_pp_verify_fn, mods[s],
-                                      plan.stage_arg(s)),
-                    (psh[s], csh[s], reps[s], reps[s]),
-                    (csh[s], reps[s]), (1,)), f"serving_verify_s{s}")
-                    for s in range(1, last)]
-                vlast = wrap(sjit(
-                    functools.partial(_pp_verify_last_fn, mods[last],
-                                      plan.stage_arg(last), top_k),
-                    (psh[last], csh[last]) + (reps[last],) * 8,
-                    (csh[last], rep_last, rep_last, rep_last), (1,)),
-                    f"serving_verify_s{last}")
-                rewind = [wrap(sjit(_pp_index_rewind_fn,
-                                    (csh[s], reps[s], reps[s]), csh[s],
-                                    (0,)), f"serving_verify_rewind_s{s}")
-                          for s in range(last)]
-
-                def verify(params, cache, tokens, drafts, temps, spec_ok,
-                           remaining, start, key):
-                    toks = tokens[0]
-                    rows = [c[0] for c in cache]
-                    rows[0], act = vf0(params[0], rows[0], hop(toks, 0),
-                                       drafts, start)
-                    for s in range(1, last):
-                        rows[s], act = vmid[s - 1](params[s], rows[s],
-                                                   hop(act, s), start)
-                    rows[last], new_tok, out, commit = vlast(
-                        params[last], rows[last], hop(act, last),
-                        hop(drafts, last), toks,
-                        temps[0], spec_ok, remaining, start, key)
-                    # Non-last stages left their index leaves at
-                    # positions + K; roll each back to the committed
-                    # length with the DEVICE commit vector — no host
-                    # sync on the dispatch path.
-                    for s in range(last):
-                        rows[s] = rewind[s](rows[s], start,
-                                            hop(commit, s))
-                    return [[c] for c in rows], [new_tok], out, commit
-
-                self._verify_step = verify
+            self._verify_step = verify
             self._decode_audit_names += (
                 ["serving_draft"]
-                + [f"serving_verify_s{s}" for s in range(S)]
-                + ([] if self._paged else
-                   [f"serving_verify_rewind_s{s}" for s in range(last)]))
+                + [f"serving_verify_s{s}" for s in range(S)])
 
     # -- introspection ------------------------------------------------------
     def decode_compile_count(self) -> int:
@@ -2422,7 +1942,7 @@ class ServingEngine:
         from distkeras_tpu.telemetry.device import publish_memory_gauges
 
         kv_bytes = kv_peak = None
-        if self.kv_pool is not None and self.kv_pool.bytes_per_block:
+        if self.kv_pool.bytes_per_block:
             kv_bytes = self.kv_pool.capacity * self.kv_pool.bytes_per_block
             kv_peak = (self.kv_pool.peak_blocks_used
                        * self.kv_pool.bytes_per_block)
@@ -2434,8 +1954,6 @@ class ServingEngine:
         if self.mesh is not None:
             try:
                 params_by_dev = self._bytes_by_device(self._params)
-                # KV leaves live in the engine's cache pytree in BOTH
-                # modes (paged pools and dense per-slot caches alike).
                 kv_by_dev = self._bytes_by_device(self._cache)
             except Exception:
                 params_by_dev = kv_by_dev = None
@@ -2516,12 +2034,9 @@ class ServingEngine:
                 "age_s": (round(now - req.t_submit, 6)
                           if req.t_submit is not None else None),
             }
-            if self._paged:
-                # Block-table depth: shared prefix blocks + private
-                # chain — the per-slot footprint the dense engine's
-                # fixed [L] rows could never show.
-                entry["blocks"] = st.first_block + len(st.blocks)
-                entry["shared_blocks"] = st.first_block
+            # Block-table depth: shared prefix blocks + private chain.
+            entry["blocks"] = st.first_block + len(st.blocks)
+            entry["shared_blocks"] = st.first_block
             if st.dfa is not None:
                 # Automaton column: where this constrained stream's
                 # host-side state machine sits right now — a stream
@@ -2579,40 +2094,37 @@ class ServingEngine:
                     self.metrics.spec_accepted_tokens / drafted, 4)
                     if drafted else None),
             }
-        if self.prefix_cache is not None:
-            out["prefix_cache"] = self.prefix_cache.debugz()
-        if self.kv_pool is not None:
-            out["kv_pool"] = {
-                **self.kv_pool.debugz(),
-                "blocks_free": self.kv_pool.blocks_free,
-                "preemptions": self.metrics.preemptions,
-                "oom_rejections": self.metrics.oom_rejections,
-                "kv_migrations": self.metrics.kv_migrations,
-                "kv_migration_fallbacks":
-                    self.metrics.kv_migration_fallbacks,
-                "kv_migration_bytes": self.metrics.kv_migration_bytes,
-                "kv_exports": self.metrics.kv_exports,
+        out["kv_pool"] = {
+            **self.kv_pool.debugz(),
+            "blocks_free": self.kv_pool.blocks_free,
+            "preemptions": self.metrics.preemptions,
+            "oom_rejections": self.metrics.oom_rejections,
+            "kv_migrations": self.metrics.kv_migrations,
+            "kv_migration_fallbacks":
+                self.metrics.kv_migration_fallbacks,
+            "kv_migration_bytes": self.metrics.kv_migration_bytes,
+            "kv_exports": self.metrics.kv_exports,
+        }
+        if self.kv_tier is not None:
+            # Tier section on the kv_pool page: occupancy of the
+            # host/disk levels plus the engine's traffic through
+            # them (device resident bytes ride along so all three
+            # tiers of the hierarchy read off one dict).
+            self.metrics.set_kv_tier_resident_bytes(
+                self.kv_pool.blocks_used
+                * (self.kv_pool.bytes_per_block or 0))
+            out["kv_tier"] = {
+                **self.kv_tier.stats(),
+                "resident_bytes": self.kv_pool.blocks_used
+                * (self.kv_pool.bytes_per_block or 0),
+                "spills": self.metrics.kv_spills,
+                "spill_bytes": self.metrics.kv_spill_bytes,
+                "readmits": self.metrics.kv_readmits,
+                "readmit_bytes": self.metrics.kv_readmit_bytes,
+                "pushes": self.metrics.kv_pushes,
+                "push_bytes": self.metrics.kv_push_bytes,
+                "push_fallbacks": self.metrics.kv_push_fallbacks,
             }
-            if self.kv_tier is not None:
-                # Tier section on the kv_pool page: occupancy of the
-                # host/disk levels plus the engine's traffic through
-                # them (device resident bytes ride along so all three
-                # tiers of the hierarchy read off one dict).
-                self.metrics.set_kv_tier_resident_bytes(
-                    self.kv_pool.blocks_used
-                    * (self.kv_pool.bytes_per_block or 0))
-                out["kv_tier"] = {
-                    **self.kv_tier.stats(),
-                    "resident_bytes": self.kv_pool.blocks_used
-                    * (self.kv_pool.bytes_per_block or 0),
-                    "spills": self.metrics.kv_spills,
-                    "spill_bytes": self.metrics.kv_spill_bytes,
-                    "readmits": self.metrics.kv_readmits,
-                    "readmit_bytes": self.metrics.kv_readmit_bytes,
-                    "pushes": self.metrics.kv_pushes,
-                    "push_bytes": self.metrics.kv_push_bytes,
-                    "push_fallbacks": self.metrics.kv_push_fallbacks,
-                }
         if self.flight_recorder is not None:
             out["flight_recorder"] = self.flight_recorder.stats()
         if self.trace_store is not None:
@@ -2661,10 +2173,9 @@ class ServingEngine:
             raise ValueError(
                 f"unknown request kind {kind!r}; expected one of "
                 f"{REQUEST_KINDS}")
-        if kind != "generate" and (not self._paged or self._pp > 1):
+        if kind != "generate" and self._pp > 1:
             raise ValueError(
-                f"kind={kind!r} requires a paged single-stage engine "
-                f"(kv_pool_mb / kv_pool_blocks, pp=1)")
+                f"kind={kind!r} requires a single-stage engine (pp=1)")
         if kind in SCORELIKE_KINDS:
             if max_new_tokens > 0:
                 raise ValueError(
@@ -2719,36 +2230,34 @@ class ServingEngine:
         if kind not in SCORELIKE_KINDS \
                 and prompt_arr.size + max_new_tokens > self.limit:
             # Tighter than the model's trained context: the engine's
-            # max_context cap (dense mode: the pre-reserved per-slot
-            # cache length under the byte budget).
+            # max_context cap.
             raise ValueError(
                 f"prompt ({prompt_arr.size}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds this engine's context cap "
                 f"{self.limit} (max_context)")
-        if self._paged:
-            # Resident K/V at completion: every position except the last
-            # sampled token's (never fed back). A request that can never
-            # fit the pool is a sizing error — reject typed, up front.
-            bt = self.kv_block_tokens
-            if kind in SCORELIKE_KINDS:
-                # Scorelike feeds every prompt token, so all of them
-                # are resident at completion.
-                need = -(-prompt_arr.size // bt)
-            elif kind == "sample":
-                # n forks share the prompt's COMPLETE blocks; each owns
-                # the rest (partial tail copy + decode growth) itself.
-                resident = prompt_arr.size + max_new_tokens - 1
-                shared = prompt_arr.size // bt
-                need = shared + n * (-(-resident // bt) - shared)
-            else:
-                resident = prompt_arr.size + max_new_tokens - 1
-                need = -(-resident // bt)
-            if need > self.kv_pool.capacity:
-                self.metrics.record_oom_reject()
-                raise PoolExhausted(
-                    f"request needs {need} KV blocks at completion; the "
-                    f"pool holds {self.kv_pool.capacity} — raise "
-                    f"--kv-pool-mb or lower max_new_tokens")
+        # Resident K/V at completion: every position except the last
+        # sampled token's (never fed back). A request that can never
+        # fit the pool is a sizing error — reject typed, up front.
+        bt = self.kv_block_tokens
+        if kind in SCORELIKE_KINDS:
+            # Scorelike feeds every prompt token, so all of them
+            # are resident at completion.
+            need = -(-prompt_arr.size // bt)
+        elif kind == "sample":
+            # n forks share the prompt's COMPLETE blocks; each owns
+            # the rest (partial tail copy + decode growth) itself.
+            resident = prompt_arr.size + max_new_tokens - 1
+            shared = prompt_arr.size // bt
+            need = shared + n * (-(-resident // bt) - shared)
+        else:
+            resident = prompt_arr.size + max_new_tokens - 1
+            need = -(-resident // bt)
+        if need > self.kv_pool.capacity:
+            self.metrics.record_oom_reject()
+            raise PoolExhausted(
+                f"request needs {need} KV blocks at completion; the "
+                f"pool holds {self.kv_pool.capacity} — raise "
+                f"--kv-pool-mb or lower max_new_tokens")
         req = Request(
             prompt_arr.tolist(), max_new_tokens, temperature=temperature,
             priority=priority, timeout=timeout, trace_id=trace_id,
@@ -2892,8 +2401,8 @@ class ServingEngine:
         The swap itself runs inside the engine loop at the first
         iteration with **no slot in flight** (the loop serializes all
         device work, so there is no race against a decode or prefill in
-        the executor): params are device_put, the prefix cache is flushed
-        (its pooled K/V was computed under the OLD weights), and one
+        the executor): params are device_put, the KV pool's prefix trie is
+        flushed (its K/V was computed under the OLD weights), and one
         decode tick rewarms the step — under an armed auditor that tick
         PROVES the swap did not retrace. Returns ``(event, result)``:
         await the event, then check ``result`` for ``"error"``. Under
@@ -2963,13 +2472,9 @@ class ServingEngine:
         ``(event, result)`` — await the event, then read ``result``
         (``payload`` bytes + ``matched_tokens``, or ``error``). Raises
         :class:`~distkeras_tpu.serving.kv_transfer.KVTransferError`
-        immediately on a dense engine (blocks only exist paged)."""
+        immediately on a pp mesh."""
         from distkeras_tpu.serving.kv_transfer import KVTransferError
 
-        if not self._paged:
-            raise KVTransferError(
-                "KV export requires a paged engine (--paged / "
-                "--kv-pool-mb): dense caches have no block bookkeeping")
         if self._pp > 1:
             raise KVTransferError(
                 "KV export is not supported on a pp mesh yet: the "
@@ -2991,10 +2496,6 @@ class ServingEngine:
         ``result`` rather than failing — import must only ever help."""
         from distkeras_tpu.serving.kv_transfer import KVTransferError
 
-        if not self._paged:
-            raise KVTransferError(
-                "KV import requires a paged engine (--paged / "
-                "--kv-pool-mb)")
         if self._pp > 1:
             raise KVTransferError(
                 "KV import is not supported on a pp mesh yet: the "
@@ -3141,7 +2642,7 @@ class ServingEngine:
     def _spill_block(self, chain_tokens, row: int) -> None:
         """Pool spill hook: serialize ONE eviction victim's pool row
         into the host tier as exact KVX1 bytes, keyed by its full
-        root→block token chain. Runs inside ``_BlockTrie._alloc`` —
+        root→block token chain. Runs inside ``KVBlockPool._alloc`` —
         always on the engine loop, or on the executor while the loop
         awaits it, and always after a pipeline barrier (growth with a
         tick in flight refuses an allocation that would spill:
@@ -3355,8 +2856,6 @@ class ServingEngine:
         bytes land (the import path fires the scheduler's tier-arrival
         event). Returns True when resident, False on timeout (caller
         pulls or re-prefills — counted fallbacks, never errors)."""
-        if not self._paged:
-            return False
         toks = [int(t) for t in tokens]
         loop = asyncio.get_running_loop()
         deadline = loop.time() + timeout_s
@@ -3393,18 +2892,14 @@ class ServingEngine:
             params = place_sharded(params, self._param_shardings)
         jax.block_until_ready(params)
         self._params = params
-        if self.prefix_cache is not None:
-            # Pooled K/V is a pure function of (weights, tokens): stale
-            # weights make every cached block wrong, so the whole pool is
-            # invalidated in one stroke.
-            self.prefix_cache.flush()
-        if self.kv_pool is not None:
-            # Safe for the same reason the swap itself is: zero active
-            # slots means zero slot-owned blocks, so the flush only
-            # drops (now-wrong) trie entries. flush() bypasses _alloc,
-            # so no spill fires — old-weight blocks never reach the
-            # host tier.
-            self.kv_pool.flush()
+        # Pooled K/V is a pure function of (weights, tokens): stale
+        # weights make every cached block wrong, so the whole trie is
+        # invalidated in one stroke. Safe for the same reason the swap
+        # itself is: zero active slots means zero slot-owned blocks, so
+        # the flush only drops (now-wrong) trie entries. flush()
+        # bypasses _alloc, so no spill fires — old-weight blocks never
+        # reach the host tier.
+        self.kv_pool.flush()
         if self.kv_tier is not None:
             # The host/disk tiers hold serialized KV from the OLD
             # weights — same purity argument, one stroke.
@@ -3468,16 +2963,14 @@ class ServingEngine:
                     if st.request.cancelled:
                         self._finish_error(st.request, RequestCancelled(
                             f"cancelled with {st.remaining} tokens undecoded"))
-                        self._release_prefill(st)
-                        self._free_slot_paged(i, st)
+                        self._free_slot(i, st)
                         self._slot_state[i] = None
                     elif dl is not None and now > dl:
                         self.metrics.record_expire()
                         self._finish_error(st.request, RequestTimeout(
                             f"deadline exceeded after {st.request.timeout}s "
                             f"with {st.remaining} tokens undecoded"))
-                        self._release_prefill(st)
-                        self._free_slot_paged(i, st)
+                        self._free_slot(i, st)
                         self._slot_state[i] = None
                 # 3. Shutdown: flush the queue with typed errors.
                 if self._stopping:
@@ -3531,7 +3024,7 @@ class ServingEngine:
                 # gather/scatter can never race a decode step's donated
                 # cache buffers. Device work in the executor, event
                 # resolution on the loop thread.
-                if self._paged and self._pending_kv:
+                if self._pending_kv:
                     # Barrier: the export gather / import scatter must
                     # never interleave with a tick that is mid-flight
                     # over the same pool rows.
@@ -3559,14 +3052,14 @@ class ServingEngine:
                 # events are not thread-safe).
                 if (not self._stopping and len(self.scheduler)
                         and self.free_slots
-                        and not (self._paged and self._parked_at_version
+                        and not (self._parked_at_version
                                  == self.kv_pool.version
                                  and self.scheduler.peek()
                                  is self._parked_req)):
-                    # Admission splices content into the batch (and,
-                    # paged, reserves/preempts pool blocks): barrier
+                    # Admission changes the batch's content (and
+                    # reserves/preempts pool blocks): barrier
                     # first so the reserve can never race an in-flight
-                    # tick's reads, and so the admit splice lands on
+                    # tick's reads, and so the admit lands on
                     # harvested token state. The barrier may free MORE
                     # slots (a finishing tick), which only helps. A
                     # parked queue head (dry pool, nothing freed since)
@@ -3577,7 +3070,7 @@ class ServingEngine:
                     await self._pipeline_barrier(loop, "admission")
                 if not self._stopping:
                     while self.free_slots and len(self.scheduler):
-                        if (self._paged and self._parked_at_version
+                        if (self._parked_at_version
                                 == self.kv_pool.version
                                 and self.scheduler.peek()
                                 is self._parked_req):
@@ -3608,15 +3101,13 @@ class ServingEngine:
                             self.scheduler.requeue(req)
                             break
                         slot = self._slot_state.index(None)
-                        paged_job = None
-                        if self._paged:
-                            paged_job = self._reserve_paged(req, slot)
-                            if paged_job is None:
-                                # Parked: requeued at its class head,
-                                # admission resumes when blocks free.
-                                break
-                            self._parked_at_version = None
-                            self._parked_req = None
+                        reserved = self._reserve_blocks(req, slot)
+                        if reserved is None:
+                            # Parked: requeued at its class head,
+                            # admission resumes when blocks free.
+                            break
+                        self._parked_at_version = None
+                        self._parked_req = None
                         # ADMISSION WAIT ends HERE (slot granted); the
                         # PREFILL DEVICE TIME is recorded separately when
                         # the prefill completes (record_prefill). The two
@@ -3662,9 +3153,8 @@ class ServingEngine:
                             req,
                             req.max_new_tokens - len(req.out_tokens),
                             now_t, t_admit=now_t)
-                        if paged_job is not None:
-                            (st.prefill, st.blocks, st.first_block,
-                             st.match) = paged_job
+                        (st.prefill, st.blocks, st.first_block,
+                         st.match) = reserved
                         if req.constraint is not None:
                             st.dfa = req.constraint
                             st.dfa_state = req.constraint.start
@@ -3684,14 +3174,10 @@ class ServingEngine:
                                   trace_id=req.trace_id,
                                   prompt_len=len(req.prompt),
                                   queue_wait_s=round(wait, 6)):
-                            # Prefix-cache lookup + splice: a hit makes
-                            # admission nearly free — the matched prefix's
-                            # prefill compute is skipped entirely. (Paged
-                            # admission already reserved its blocks and
-                            # pinned its match — zero device work.)
-                            if st.prefill is None:
-                                st.prefill = await self._in_executor(
-                                    loop, self._begin_prefill, req)
+                            # (The reservation above already pinned the
+                            # prompt's cached prefix and pointed the
+                            # slot's table at it: a hit skips that
+                            # prefix's prefill compute entirely.)
                             if self._chunk is None:
                                 # Monolithic prefill: the whole uncached
                                 # tail, admitted inline. Normally ONE
@@ -3715,7 +3201,7 @@ class ServingEngine:
                     pending = [i for i, st in enumerate(self._slot_state)
                                if st is not None and st.prefill is not None]
                     if pending:
-                        # A completing chunk admit-splices into the
+                        # A completing chunk admits into the
                         # batch (and donates the token buffer): barrier
                         # before the chunk runs. Chunked admission
                         # phases therefore serialize with the decode
@@ -3740,7 +3226,7 @@ class ServingEngine:
                     await self._pipeline_barrier(loop, "idle")
                     if self._stopping:
                         break
-                    if (self._paged and self._parked_req is not None
+                    if (self._parked_req is not None
                             and self._parked_at_version
                             == self.kv_pool.version
                             and self.scheduler.peek() is self._parked_req):
@@ -3773,8 +3259,7 @@ class ServingEngine:
                         if st is not None:
                             self._finish_error(st.request, EngineStopped(
                                 "engine shut down mid-decode"))
-                            self._release_prefill(st)
-                            self._free_slot_paged(i, st)
+                            self._free_slot(i, st)
                             self._slot_state[i] = None
                     break
                 # 5c. Paged growth: before the tick, every decoding slot
@@ -3795,11 +3280,10 @@ class ServingEngine:
                 #       (_chain_tail_block says which case is which) is
                 #       the barrier kept: preemption, a wedged row's
                 #       error and a spill all need harvested state.
-                if self._paged:
-                    dry = self._grow_in_flight()
-                    if dry:
-                        await self._pipeline_barrier(loop, "paged_growth")
-                        self._grow_drained(dry)
+                dry = self._grow_in_flight()
+                if dry:
+                    await self._pipeline_barrier(loop, "paged_growth")
+                    self._grow_drained(dry)
                 # 6. One decode iteration for the whole batch — skipped
                 # while EVERY active slot is still mid-prefill (the whole
                 # tick's output would be discarded; the chunk in 4b was
@@ -3819,8 +3303,7 @@ class ServingEngine:
                 self.metrics.sample(
                     len(self.scheduler), self.active_slots, self.slots,
                     rows_decoded=rows)
-                if self._paged:
-                    self.kv_pool.note_decode_tick()
+                self.kv_pool.note_decode_tick()
                 # Yield so the server can read sockets between iterations.
                 await asyncio.sleep(0)
         except BaseException as e:
@@ -3838,10 +3321,9 @@ class ServingEngine:
             for i, st in enumerate(self._slot_state):
                 if st is not None:
                     self._finish_error(st.request, err)
-                    self._release_prefill(st)
                     # Crash path: free only (no adoption) — keep the
                     # last-words path as simple as possible.
-                    self._free_slot_paged(i, st, adopt=False)
+                    self._free_slot(i, st, adopt=False)
                     self._slot_state[i] = None
             for req in self.scheduler.drain():
                 self._finish_error(req, err)
@@ -3855,7 +3337,7 @@ class ServingEngine:
                 ev.set()
             # Same for pending KV transfers: a peer awaiting an export
             # must get its typed failure now, not a hung timeout.
-            if self._paged and self._pending_kv:
+            if self._pending_kv:
                 ops, self._pending_kv = self._pending_kv, []
                 for _, _, ev, res in ops:
                     res["error"] = err
@@ -3922,15 +3404,14 @@ class ServingEngine:
                 return 0
             spec_tick = want_spec()
         if spec_tick:
-            if self._paged:
-                for i in decodable:
-                    req = self._slot_state[i].request
-                    # Lookahead only for rows that will actually
-                    # speculate — a sampled or opted-out row writes one
-                    # real token per tick and needs no window blocks.
-                    # (_alloc_lookahead never preempts, so no barrier.)
-                    if req.temperature <= 0 and req.speculate:
-                        self._alloc_lookahead(i)
+            for i in decodable:
+                req = self._slot_state[i].request
+                # Lookahead only for rows that will actually
+                # speculate — a sampled or opted-out row writes one
+                # real token per tick and needs no window blocks.
+                # (_alloc_lookahead never preempts, so no barrier.)
+                if req.temperature <= 0 and req.speculate:
+                    self._alloc_lookahead(i)
             with span("spec_tick", active=self.active_slots,
                       k=self.spec_k):
                 self._inflight.append(await self._dispatch(
@@ -4034,37 +3515,36 @@ class ServingEngine:
                 else:
                     self._push_token(st, int(nxt[i - tick.mb_start]), t)
                 if st.remaining == 0:
-                    if self._paged:
-                        # Still-dispatched later tick(s) optimistically
-                        # advanced this slot's watermark; the request is
-                        # finished, so roll every such advance back
-                        # BEFORE adoption — the trie must never claim an
-                        # in-flight speculative write; its block is
-                        # freed instead. Growth may hand that block to
-                        # another row for the NEXT tick with no barrier
-                        # between (step 5c), and the stale write is
-                        # still harmless: programs run on the device in
-                        # dispatch order, so it lands first; the new
-                        # owner starts at the block's offset 0 and
-                        # attention masks every position past a row's
-                        # length, so the stale offset is overwritten
-                        # before it is ever read; and the trie adopts
-                        # complete blocks only, every offset of which
-                        # its owner wrote. (Likewise a row that already
-                        # finished INSIDE the in-flight tick may be
-                        # given a tail block it never uses: teardown
-                        # frees it past the adopt watermark, like a
-                        # speculating row's unused lookahead block.)
-                        for later in self._inflight:
-                            if i in later.advanced:
-                                self._lens[i] -= 1
-                                later.advanced.discard(i)
-                                self._positions_dirty = True
+                    # Still-dispatched later tick(s) optimistically
+                    # advanced this slot's watermark; the request is
+                    # finished, so roll every such advance back
+                    # BEFORE adoption — the trie must never claim an
+                    # in-flight speculative write; its block is
+                    # freed instead. Growth may hand that block to
+                    # another row for the NEXT tick with no barrier
+                    # between (step 5c), and the stale write is
+                    # still harmless: programs run on the device in
+                    # dispatch order, so it lands first; the new
+                    # owner starts at the block's offset 0 and
+                    # attention masks every position past a row's
+                    # length, so the stale offset is overwritten
+                    # before it is ever read; and the trie adopts
+                    # complete blocks only, every offset of which
+                    # its owner wrote. (Likewise a row that already
+                    # finished INSIDE the in-flight tick may be
+                    # given a tail block it never uses: teardown
+                    # frees it past the adopt watermark, like a
+                    # speculating row's unused lookahead block.)
+                    for later in self._inflight:
+                        if i in later.advanced:
+                            self._lens[i] -= 1
+                            later.advanced.discard(i)
+                            self._positions_dirty = True
                     if st.fork_idx is not None:
                         self._finish_fork_row(i, st)
                     else:
                         self._finish_ok(st.request)
-                        self._free_slot_paged(i, st)
+                        self._free_slot(i, st)
                         self._slot_state[i] = None
 
     # -- internals ----------------------------------------------------------
@@ -4099,14 +3579,6 @@ class ServingEngine:
         while b < n:
             b *= 2
         return min(b, self.limit if cap is None else min(cap, self.limit))
-
-    def _release_prefill(self, st: _SlotState) -> None:
-        """Drop a slot's pending prefill (cancel/expiry/shutdown paths):
-        unpin its prefix-cache match so the blocks become evictable."""
-        if st.prefill is not None:
-            if self.prefix_cache is not None:
-                self.prefix_cache.release(st.prefill.match)
-            st.prefill = None
 
     def _route_admission(self, st: _SlotState, slot: int, tok0) -> None:
         """Dispatch a completed prefill to its kind's finisher: plain
@@ -4174,7 +3646,7 @@ class ServingEngine:
                    else np.zeros((1,), np.float64))
             req.embedding = [float(v) / s0 for v in vec]
         self._finish_ok(req)
-        self._free_slot_paged(slot, st)
+        self._free_slot(slot, st)
         self._slot_state[slot] = None
 
     def _finish_fork(self, st: _SlotState, slot: int, toks: list) -> None:
@@ -4261,7 +3733,7 @@ class ServingEngine:
         finish resolves the shared request (one DONE with all n)."""
         req = st.request
         req.fork_completions[st.fork_idx] = list(st.fork_tokens or [])
-        self._free_slot_paged(i, st, adopt=False)
+        self._free_slot(i, st, adopt=False)
         self._slot_state[i] = None
         if all(c is not None for c in req.fork_completions):
             self._finish_ok(req)
@@ -4271,7 +3743,7 @@ class ServingEngine:
         drop one refcount per row, so the pool drains exactly."""
         for i, s in enumerate(self._slot_state):
             if s is not None and s.request is req:
-                self._free_slot_paged(i, s, adopt=False)
+                self._free_slot(i, s, adopt=False)
                 self._slot_state[i] = None
 
     def _finish_admission(self, st: _SlotState, slot: int, tok0: int) -> None:
@@ -4290,42 +3762,16 @@ class ServingEngine:
             self._push_token(st, tok0, t)
         if st.remaining == 0:
             self._finish_ok(st.request)
-            self._free_slot_paged(slot, st)
+            self._free_slot(slot, st)
             self._slot_state[slot] = None
-
-    def _begin_prefill(self, req: Request) -> _PrefillJob:
-        """Start a prompt's prefill (executor thread, DENSE mode): build
-        the single-row cache — on a prefix-cache hit, materialized
-        straight from the matched pool blocks (the covered leaves are
-        never first built as zeros and re-written; see
-        PrefixCache.materialize), on a miss from the jitted zeros
-        factory. The uncached tail runs through :meth:`_prefill_step`
-        chunk by chunk."""
-        match, matched = None, 0
-        if self.prefix_cache is not None:
-            match = self.prefix_cache.match(req.prompt)
-            matched = match.matched_tokens
-        if matched:
-            with span("prefix_splice", blocks=len(match.ids),
-                      tokens=matched):
-                cache = self.prefix_cache.materialize(match.ids)
-        else:
-            cache = self._fresh_row_cache()
-        if req.trace is not None and matched:
-            req.trace.event("prefix_splice", tokens=matched,
-                            blocks=len(match.ids))
-        return _PrefillJob(cache=cache, pos=matched, match=match,
-                           matched_tokens=matched)
 
     def _prefill_step(self, st: _SlotState, slot: int) -> int | None:
         """Run ONE prefill chunk for the slot (executor thread; device
         work only). Returns None while the prompt is still incomplete;
-        on the final chunk, DENSE mode stores the prompt's new blocks
-        into the prefix cache and splices the finished single-row cache
-        into batch row ``slot``, while PAGED mode has nothing to move —
-        the chunks already wrote into the slot's pool blocks — and only
-        the sampling state (first token, temperature) is set. Either way
-        the request's first token comes back."""
+        on the final chunk there is nothing to move — the chunks already
+        wrote into the slot's pool blocks — so only the sampling state
+        (first token, temperature) is set and the request's first token
+        comes back."""
         req, job = st.request, st.prefill
         tokens = self._resident_tokens(req)
         s0 = len(tokens)
@@ -4337,11 +3783,12 @@ class ServingEngine:
             P = self._chunk  # full chunk: ONE fixed-size program
         else:
             P = self._bucket(c, cap=self._chunk)  # ragged final chunk
-        # The pad width must never overshoot the cache: with job.pos + P
-        # > cache length the dense per-slot KV write would clamp its
-        # start backward (bert.py's OOB discipline) and silently
-        # overwrite the spliced prefix rows (paged writes past the table
-        # are dropped, but the bound keeps the compile set shared).
+        # The pad width must never overshoot the context (self.limit,
+        # NOT the table reach, which rounds UP past it when the block
+        # size doesn't divide it): a chunk reaching past max_seq_len
+        # would make the positional dynamic_slice clamp BACKWARD and
+        # embed its real tokens at wrong positions. submit() caps every
+        # sequence at self.limit, so the bound loses nothing.
         # Rather than compiling a bespoke non-power-of-two width per
         # matched length, shrink to the largest power of two that fits
         # and let the NEXT call(s) finish the remainder — the compile
@@ -4349,7 +3796,7 @@ class ServingEngine:
         # (Monolithic admission loops on this method until it returns a
         # token, so near-context-limit prompts just take an extra
         # sub-chunk or two.)
-        room = self._cache_len - job.pos
+        room = self.limit - job.pos
         if P > room:
             P = self._pow2_fit(P, room)
             c = min(c, P)  # room >= rem >= 1, so P >= 1 and c >= 1
@@ -4366,15 +3813,14 @@ class ServingEngine:
         final = job.pos + c >= s0
         special = None
         with span("prefill", bucket=P, offset=job.pos, prompt_len=s0):
-            if self._paged and req.kind in SCORELIKE_KINDS:
+            if req.kind in SCORELIKE_KINDS:
                 # Prefill-only kinds: same KV writes, different epilogue
                 # (per-token logprobs / hidden-state sum) accumulated
                 # host-side per chunk.
                 tok = tok0 = None
                 self._scorelike_chunk(st, slot, padded, c, s0)
                 hg.tick_dispatched()
-            elif self._paged and final and (req.kind == "sample"
-                                            or st.dfa is not None):
+            elif final and (req.kind == "sample" or st.dfa is not None):
                 # The final chunk hands the LOGITS row back instead of a
                 # sampled token: the fork fan-out samples n first tokens
                 # from it; constrained admission masks it first.
@@ -4408,19 +3854,11 @@ class ServingEngine:
                         tok0 = int(np.argmax(row))
                     tok = jnp.int32(tok0)
                 hg.harvest_ended()
-            elif self._paged:
+            else:
                 self._cache, tok = self._prefill(
                     self._params, self._cache, jnp.asarray(padded),
                     jnp.int32(job.pos), jnp.int32(c),
                     jnp.asarray(self._tables[slot]), temp, sub)
-                hg.tick_dispatched()
-                hg.harvest_started()
-                tok0 = int(tok)  # blocks: honest device time per chunk
-                hg.harvest_ended()
-            else:
-                job.cache, tok = self._prefill(
-                    self._params, job.cache, jnp.asarray(padded),
-                    jnp.int32(job.pos), jnp.int32(c), temp, sub)
                 hg.tick_dispatched()
                 hg.harvest_started()
                 tok0 = int(tok)  # blocks: honest device time per chunk
@@ -4432,10 +3870,9 @@ class ServingEngine:
             req.trace.event("prefill_chunk", offset=job.pos, tokens=c,
                             bucket=P, dur_s=round(chunk_s, 9))
         job.pos += c
-        if self._paged:
-            # Written-KV watermark: a preemption between chunks adopts /
-            # frees exactly the positions written so far.
-            self._lens[slot] = job.pos
+        # Written-KV watermark: a preemption between chunks adopts /
+        # frees exactly the positions written so far.
+        self._lens[slot] = job.pos
         if job.pos < s0:
             return None
         # Prompt complete.
@@ -4454,30 +3891,14 @@ class ServingEngine:
                     cache_hit_tokens=job.matched_tokens)
             st.prefill = None
             return special if special is not None else _SCORELIKE_DONE
-        if self._paged:
-            with span("cache_admit", slot=slot):
-                self._tokens, self._temps = self._admit_jit(
-                    self._tokens, self._temps, jnp.int32(slot), tok, temp)
-            # The slot joins the decodable set: the masked table view
-            # gains its row, so the next tick must re-upload.
-            self._mark_tables_dirty()
-        else:
-            # Store the complete blocks this prefill computed (future
-            # requests sharing the prefix hit them), then splice the row
-            # into the live batch cache.
-            if self.prefix_cache is not None:
-                with span("prefix_insert", prompt_len=s0):
-                    self.prefix_cache.insert(req.prompt, job.cache)
-                self.prefix_cache.release(job.match)
-            with span("cache_splice", slot=slot):
-                self._cache, self._tokens, self._temps = self._admit_jit(
-                    self._cache, self._tokens, self._temps, jnp.int32(slot),
-                    job.cache, tok, temp)
+        with span("cache_admit", slot=slot):
+            self._tokens, self._temps = self._admit_jit(
+                self._tokens, self._temps, jnp.int32(slot), tok, temp)
+        # The slot joins the decodable set: the masked table view
+        # gains its row, so the next tick must re-upload.
+        self._mark_tables_dirty()
         self.metrics.record_prefill(
-            job.device_s, job.chunks_done,
-            job.matched_tokens if (self._paged or
-                                   self.prefix_cache is not None) else None,
-            s0)
+            job.device_s, job.chunks_done, job.matched_tokens, s0)
         if self._spec:
             # The draft's prompt K/V, built once the target prefill
             # finished (executor thread — the loop stays responsive).
@@ -4485,7 +3906,6 @@ class ServingEngine:
             # models; the first spec tick picks it up from here.
             with span("draft_prefill", slot=slot, prompt_len=s0):
                 self._draft_prefill_slot(slot, tokens)
-            self._spec_pos[slot] = s0
         req.prefill_device_s += job.device_s
         req.prefill_chunks += job.chunks_done
         req.prefix_hit_tokens = int(job.matched_tokens or 0)
@@ -4517,8 +3937,7 @@ class ServingEngine:
         """Device view of the block tables, MASKED to the sentinel for
         rows that must not write (free slots, mid-prefill slots — their
         garbage output is discarded, and the dropped scatter guarantees
-        it cannot scribble on live blocks the way the dense path lets a
-        free row scribble on its own). Rebuilt + re-uploaded only when
+        it cannot scribble on live blocks). Rebuilt + re-uploaded only when
         the dirty flag says the masked view could have changed — set at
         the sites that mutate a table row (admission reserve, growth,
         preemption, teardown) and at prefill completion (the decodable
@@ -4621,50 +4040,43 @@ class ServingEngine:
         self._key, sub = jax.random.split(self._key)
         rows = tuple(self._decodable())
         harvest = ()
-        if self._paged:
-            tables_dev = self._upload_tables(rows)
-            if self._positions_dirty or self._positions_dev is None:
-                positions = np.zeros((self.slots,), np.int32)
-                for i in rows:
-                    positions[i] = self._lens[i]
-                # Sharded: commit the rebuilt vector to the replicated
-                # layout the decode step's out_shardings pins — jit
-                # cache entries key on actual argument shardings, so an
-                # uncommitted host upload here would occupy a DIFFERENT
-                # executable than the steady-state ticks that re-feed
-                # the committed jit output (same reason the ctor
-                # commits tokens/temps).
-                if self.mesh is not None:
-                    self._positions_dev = jax.device_put(
-                        np.asarray(positions), self._replicated)
-                else:
-                    self._positions_dev = jnp.asarray(positions)
-                self._positions_dirty = False
-            if self._constrained_mode:
-                mask_dev = self._upload_mask()
-                self._cache, self._tokens, self._positions_dev = (
-                    self._decode_step(
-                        self._params, self._cache, self._tokens,
-                        self._temps, self._positions_dev, tables_dev,
-                        mask_dev, sub))
-            else:
-                # (a counting module's step hands back a fourth array,
-                # tokens + counts: the handle the harvest reads)
-                self._cache, self._tokens, self._positions_dev, *harvest = (
-                    self._decode_step(
-                        self._params, self._cache, self._tokens,
-                        self._temps, self._positions_dev, tables_dev,
-                        sub))
-            # Each decodable row appends exactly one K/V vector (the
-            # device advances its own positions copy identically).
+        tables_dev = self._upload_tables(rows)
+        if self._positions_dirty or self._positions_dev is None:
+            positions = np.zeros((self.slots,), np.int32)
             for i in rows:
-                self._lens[i] += 1
+                positions[i] = self._lens[i]
+            # Sharded: commit the rebuilt vector to the replicated
+            # layout the decode step's out_shardings pins — jit
+            # cache entries key on actual argument shardings, so an
+            # uncommitted host upload here would occupy a DIFFERENT
+            # executable than the steady-state ticks that re-feed
+            # the committed jit output (same reason the ctor
+            # commits tokens/temps).
+            if self.mesh is not None:
+                self._positions_dev = jax.device_put(
+                    np.asarray(positions), self._replicated)
+            else:
+                self._positions_dev = jnp.asarray(positions)
+            self._positions_dirty = False
+        if self._constrained_mode:
+            mask_dev = self._upload_mask()
+            self._cache, self._tokens, self._positions_dev = (
+                self._decode_step(
+                    self._params, self._cache, self._tokens,
+                    self._temps, self._positions_dev, tables_dev,
+                    mask_dev, sub))
         else:
-            self._cache, self._tokens = self._decode_step(
-                self._params, self._cache, self._tokens, self._temps, sub)
-            if self._spec:
-                for i in rows:
-                    self._spec_pos[i] += 1
+            # (a counting module's step hands back a fourth array,
+            # tokens + counts: the handle the harvest reads)
+            self._cache, self._tokens, self._positions_dev, *harvest = (
+                self._decode_step(
+                    self._params, self._cache, self._tokens,
+                    self._temps, self._positions_dev, tables_dev,
+                    sub))
+        # Each decodable row appends exactly one K/V vector (the
+        # device advances its own positions copy identically).
+        for i in rows:
+            self._lens[i] += 1
         t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="decode", rows=rows, t_dispatch=t,
                              tokens=harvest[0] if harvest else self._tokens,
@@ -4708,34 +4120,21 @@ class ServingEngine:
         lo = mb * self._mb_size
         last = self._pp - 1
         x = self._tokens[mb][:, None]
-        if self._paged:
-            tables = self._pp_tables(mb, rows)
-            pos = self._pp_positions(mb, rows)
-            new_pos = [None] * self._pp
-            for s in range(last):
-                self._cache[s], x, new_pos[s] = self._decode_steps[s](
-                    self._params[s], self._cache[s],
-                    self._to_stage(x, s), pos[s], tables[s])
-            self._cache[last], nxt, new_pos[last] = (
-                self._decode_steps[last](
-                    self._params[last], self._cache[last],
-                    self._to_stage(x, last),
-                    self._temps[mb], pos[last], tables[last], sub))
-            self._positions_dev[mb] = new_pos
-            for i in rows:
-                self._lens[i] += 1
-        else:
-            for s in range(last):
-                self._cache[s][mb], x = self._decode_steps[s](
-                    self._params[s], self._cache[s][mb],
-                    self._to_stage(x, s))
-            self._cache[last][mb], nxt = self._decode_steps[last](
-                self._params[last], self._cache[last][mb],
+        tables = self._pp_tables(mb, rows)
+        pos = self._pp_positions(mb, rows)
+        new_pos = [None] * self._pp
+        for s in range(last):
+            self._cache[s], x, new_pos[s] = self._decode_steps[s](
+                self._params[s], self._cache[s],
+                self._to_stage(x, s), pos[s], tables[s])
+        self._cache[last], nxt, new_pos[last] = (
+            self._decode_steps[last](
+                self._params[last], self._cache[last],
                 self._to_stage(x, last),
-                self._temps[mb], sub)
-            if self._spec:
-                for i in rows:
-                    self._spec_pos[i] += 1
+                self._temps[mb], pos[last], tables[last], sub))
+        self._positions_dev[mb] = new_pos
+        for i in rows:
+            self._lens[i] += 1
         self._tokens[mb] = nxt
         t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="decode", rows=rows, t_dispatch=t,
@@ -4793,8 +4192,7 @@ class ServingEngine:
             spec_ok[i] = (st.request.temperature <= 0
                           and st.request.speculate)
             remaining[i] = st.remaining
-            positions[i] = (self._lens[i] if self._paged
-                            else self._spec_pos[i])
+            positions[i] = self._lens[i]
             # The token at position fed-1, for the draft's heal apply:
             # the resident sequence's second-to-last element (the last
             # one is the unfed feed token) — read directly rather than
@@ -4813,47 +4211,40 @@ class ServingEngine:
         self._draft_cache, drafts = self._draft_step(
             self._draft_params, self._draft_cache, jnp.asarray(prev),
             self._tokens, start)
-        if self._paged:
-            # ``room`` doubles as the accounting cap: a commit clamped
-            # by allocation pressure must not read as draft rejection
-            # in the accept-rate metric.
-            caps = np.zeros((self.slots,), np.int32)
+        # ``room`` doubles as the accounting cap: a commit clamped
+        # by allocation pressure must not read as draft rejection
+        # in the accept-rate metric.
+        caps = np.zeros((self.slots,), np.int32)
+        for i in decodable:
+            caps[i] = self._spec_room(i)
+        if self._constrained_mode:
+            # Speculation under masks: forbidden drafts are
+            # rejected BEFORE the verify can commit them — each
+            # constrained greedy row's cap is clamped to the
+            # DFA-valid prefix of its draft window (one host sync
+            # of the drafts, only when a constrained row is live).
+            # Sampled constrained rows cap at 0: their one-token
+            # commit would come from UNMASKED verify logits, so
+            # they are served by masked fallback ticks instead.
+            drafts_host = None
             for i in decodable:
-                caps[i] = self._spec_room(i)
-            if self._constrained_mode:
-                # Speculation under masks: forbidden drafts are
-                # rejected BEFORE the verify can commit them — each
-                # constrained greedy row's cap is clamped to the
-                # DFA-valid prefix of its draft window (one host sync
-                # of the drafts, only when a constrained row is live).
-                # Sampled constrained rows cap at 0: their one-token
-                # commit would come from UNMASKED verify logits, so
-                # they are served by masked fallback ticks instead.
-                drafts_host = None
-                for i in decodable:
-                    sti = self._slot_state[i]
-                    if sti.dfa is None:
-                        continue
-                    if not spec_ok[i]:
-                        caps[i] = 0
-                        continue
-                    if drafts_host is None:
-                        drafts_host = np.asarray(drafts)
-                    caps[i] = min(
-                        int(caps[i]),
-                        sti.dfa.valid_prefix(sti.dfa_state,
-                                             drafts_host[i]))
-            tables_dev = self._upload_tables(decodable)
-            self._cache, self._tokens, out, commit = self._verify_step(
-                self._params, self._cache, self._tokens, drafts,
-                self._temps, jnp.asarray(spec_ok), jnp.asarray(remaining),
-                jnp.asarray(caps), start, tables_dev, sub)
-        else:
-            caps = np.full((self.slots,), self.spec_k, np.int32)
-            self._cache, self._tokens, out, commit = self._verify_step(
-                self._params, self._cache, self._tokens, drafts,
-                self._temps, jnp.asarray(spec_ok), jnp.asarray(remaining),
-                start, sub)
+                sti = self._slot_state[i]
+                if sti.dfa is None:
+                    continue
+                if not spec_ok[i]:
+                    caps[i] = 0
+                    continue
+                if drafts_host is None:
+                    drafts_host = np.asarray(drafts)
+                caps[i] = min(
+                    int(caps[i]),
+                    sti.dfa.valid_prefix(sti.dfa_state,
+                                         drafts_host[i]))
+        tables_dev = self._upload_tables(decodable)
+        self._cache, self._tokens, out, commit = self._verify_step(
+            self._params, self._cache, self._tokens, drafts,
+            self._temps, jnp.asarray(spec_ok), jnp.asarray(remaining),
+            jnp.asarray(caps), start, tables_dev, sub)
         t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="spec", rows=tuple(decodable),
                              t_dispatch=t, out=out, commit=commit,
@@ -4872,14 +4263,10 @@ class ServingEngine:
         if self._pp > 1:
             self.metrics.bubble.record(tick.t_dispatch, t, self._pp)
         for i in tick.rows:
-            if self._paged:
-                self._lens[i] += int(commit[i])
-            else:
-                self._spec_pos[i] += int(commit[i])
-        if self._paged:
-            # Commits are variable-width: the cached device positions
-            # no longer match _lens.
-            self._positions_dirty = True
+            self._lens[i] += int(commit[i])
+        # Commits are variable-width: the cached device positions
+        # no longer match _lens.
+        self._positions_dirty = True
         self._tick_log.append({
             "kind": tick.kind, "rows": len(tick.rows),
             "t_dispatch": tick.t_dispatch, "t_harvest": t,
@@ -4896,7 +4283,7 @@ class ServingEngine:
         rejected and the run loop owes the batch a fallback tick —
         exactly 1 for temperature>0 rows riding the same batch, 0 for
         garbage rows) and ``caps[i]`` is the draft budget the row
-        REALLY had (spec_k, minus paged allocation pressure) for honest
+        REALLY had (spec_k, minus allocation pressure) for honest
         accept accounting."""
         return self._harvest_spec(self._spec_dispatch())
 
@@ -4953,7 +4340,7 @@ class ServingEngine:
         pos, s0 = 0, len(tokens)
         while pos < s0:
             c = s0 - pos
-            P = self._pow2_fit(self._bucket(c), self._cache_len - pos)
+            P = self._pow2_fit(self._bucket(c), self.limit - pos)
             c = min(c, P)
             padded = np.zeros((1, P), np.int32)
             padded[0, :c] = tokens[pos:pos + c]
@@ -4964,7 +4351,7 @@ class ServingEngine:
         self._draft_cache = self._draft_admit(
             self._draft_cache, jnp.int32(slot), row)
 
-    # -- paged-KV internals (host bookkeeping; no device work) --------------
+    # -- KV-pool internals (host bookkeeping; no device work) ---------------
     @staticmethod
     def _resident_tokens(req: Request) -> list:
         """The slot's full resident sequence: prompt plus already-
@@ -4980,7 +4367,7 @@ class ServingEngine:
         bt = self.kv_block_tokens
         return last_token // bt - first_token // bt + 1
 
-    def _reserve_paged(self, req: Request, slot: int):
+    def _reserve_blocks(self, req: Request, slot: int):
         """Admission-time reservation (loop thread; zero device work):
         pin the longest shared prefix chain, allocate private blocks for
         the rest of the prompt, and point the slot's block table at
@@ -5042,7 +4429,7 @@ class ServingEngine:
         self._lens[slot] = m
         if req.trace is not None and m:
             req.trace.event("prefix_splice", tokens=m, blocks=first_block)
-        job = _PrefillJob(cache=None, pos=m, match=None, matched_tokens=m)
+        job = _PrefillJob(pos=m, matched_tokens=m)
         return job, ids, first_block, match
 
     def _needs_tail_block(self, i: int) -> bool:
@@ -5154,7 +4541,7 @@ class ServingEngine:
                 if st.fork_idx is not None or st.fork_wait:
                     self._teardown_fork(st.request)
                 else:
-                    self._free_slot_paged(i, st, adopt=False)
+                    self._free_slot(i, st, adopt=False)
                     self._slot_state[i] = None
                 return False
             j, _ = max(victims,
@@ -5198,17 +4585,13 @@ class ServingEngine:
                 "preempt", trace_id=req.trace_id, slot=i)
         self.scheduler.requeue(req)
 
-    def _free_slot_paged(self, i: int, st: _SlotState,
+    def _free_slot(self, i: int, st: _SlotState,
                          adopt: bool = True) -> None:
-        """Slot teardown (paged mode; dense no-op): adopt the complete
+        """Slot teardown: adopt the complete
         blocks of whatever K/V the slot computed into the prefix trie
         (zero-copy insert — a follow-up prompt sharing the prefix, or a
         multi-turn continuation sharing prompt+output, re-matches them),
         free the rest, and unpin the shared chain."""
-        if self._spec:
-            self._spec_pos[i] = 0
-        if not self._paged:
-            return
         self._set_slot_mask(i, None)
         req = st.request
         if st.fork_idx is not None or st.fork_wait:
@@ -5237,7 +4620,7 @@ class ServingEngine:
                      cap: int, t: float) -> None:
         """Stream one slot's committed tokens from a speculative tick
         and book the accept accounting. ``commit`` was clamped in-kernel
-        to the row's remaining budget (and, paged, its allocated room —
+        to the row's remaining budget (and its allocated room —
         ``cap``), so the push loop can never overshoot
         ``max_new_tokens``. The tokens of one tick share a timestamp —
         they really did arrive together, which is what the inter-token
@@ -5245,7 +4628,7 @@ class ServingEngine:
         req = st.request
         if req.temperature <= 0 and req.speculate:
             # Drafts the row could actually have used: spec_k clamped
-            # by BOTH its remaining budget and (paged) the allocated
+            # by BOTH its remaining budget and the allocated
             # room the commit was clamped to. Counting the clamped-away
             # drafts would dilute the accept rate with "request
             # finished" / "pool pressure" — neither is draft quality,

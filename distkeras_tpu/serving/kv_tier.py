@@ -7,7 +7,7 @@ promotion/demotion hierarchy:
 
     device pool  ⇄  host RAM  ⇄  (optional) disk
 
-- **Spill (device → host)**: when ``_BlockTrie._alloc`` evicts an
+- **Spill (device → host)**: when ``KVBlockPool._alloc`` evicts an
   unreferenced leaf, the engine's spill hook gathers that one block
   D2H and stores the exact ``kv_transfer`` KVX1 bytes here, keyed by
   the block's full root→leaf token chain. The payload is the same

@@ -1,6 +1,6 @@
-"""KV block migration: move a prompt's paged KV blocks between replicas.
+"""KV block migration: move a prompt's KV blocks between replicas.
 
-The paged engine's :class:`~distkeras_tpu.serving.prefix_cache.
+The engine's :class:`~distkeras_tpu.serving.prefix_cache.
 KVBlockPool` keeps exact per-block bookkeeping — which pool rows hold
 which token blocks' K/V — which makes a slot's (or a cached prefix's)
 KV **serializable**: gather the rows, stamp them with the block

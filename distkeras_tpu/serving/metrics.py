@@ -184,9 +184,8 @@ class BubbleTracker:
     tick in flight (``pipeline_depth<=1``) serializes the spans: wall ≈
     sum(spans) and the bubble sits near ``1 - 1/S`` (half the machine
     idle at S=2). ``pipeline_depth>=S`` overlaps micro-batches until
-    wall ≈ sum(spans)/S and the bubble approaches 0 — exactly the
-    ramp-up/drain-only residue the depth sweep in ``serving_bench
-    --pp-ab`` measures. Host-side stalls inside a span bias the
+    wall ≈ sum(spans)/S and the bubble approaches 0 — the
+    ramp-up/drain-only residue. Host-side stalls inside a span bias the
     estimate toward LESS reported idleness, never more (same
     conservative direction as the host-gap tracker), and the fraction
     is clamped to [0, 1]."""
@@ -660,8 +659,8 @@ class ServingMetrics:
 
     def record_fork_blocks(self, n: int) -> None:
         """``n`` extra copy-on-write block shares handed out at a fork
-        (blocks × (n_forks - 1)) — the block-sharing ratio's numerator
-        in serving_bench's fork rows."""
+        (blocks × (n_forks - 1)) — the block-sharing ratio's
+        numerator."""
         self._c_fork_blocks.inc(int(n))
 
     @property

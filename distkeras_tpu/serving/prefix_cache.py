@@ -1,21 +1,15 @@
 """KV block pool: one fixed-size-block memory manager for prefix caching
-AND paged decode slots.
+AND decode slots.
 
 Real serving traffic is dominated by shared prefixes — system prompts,
-few-shot templates, multi-turn histories. The PR 1 engine recomputed every
-request's KV cache from scratch; :class:`PrefixCache` (PR 3) lets
-admission *reuse* the computation: the KV rows of previously-prefilled
-prompt prefixes live in a fixed pool of **blocks** (``block_tokens``
-tokens each), keyed by a radix trie over the prompt's token blocks, and a
-cache hit splices the matched blocks straight into the request's prefill
-cache with ``dynamic_update_slice``.
-
-:class:`KVBlockPool` generalizes the same pool to be the engine's ONLY
-KV memory manager (paged decode, PR 6): decode slots allocate their KV in
-blocks from this pool too, addressed through per-slot block tables, so
+few-shot templates, multi-turn histories. :class:`KVBlockPool` is the
+serving engine's ONLY KV memory manager: decode slots allocate their KV
+in **blocks** (``block_tokens`` tokens each) from one pool, addressed
+through per-slot block tables, and the same blocks, keyed by a radix
+trie over the prompt's token blocks, are the prefix cache. So
 
 - capacity scales with *actual* resident tokens, not
-  ``slots × max_seq_len`` (no dense worst-case pre-reservation);
+  ``slots × max_seq_len`` (no worst-case pre-reservation);
 - a prefix-cache hit is **zero-copy**: the slot's block table simply
   points at the shared trie blocks (ref-counted so they cannot be
   evicted or overwritten from under a reader) — the copy-on-write
@@ -30,27 +24,20 @@ blocks from this pool too, addressed through per-slot block tables, so
 
 ``KVBlockPool`` is pure host bookkeeping — the device arrays live in the
 engine's cache pytree (the paged module's ``pool_key``/``pool_value``
-variables) and are threaded through its compiled programs; the pool
-decides *which rows mean what*. ``PrefixCache`` keeps owning its device
-arrays (the dense engine's splice/store path is unchanged).
+variables, ``[capacity, block_tokens, H*D]`` per layer) and are threaded
+through its compiled programs; the pool decides *which rows mean what*,
+and eviction is LRU over unreferenced trie leaves.
 
 Why sharing is safe: in a causal LM the K/V at position ``p`` depend only
 on tokens ``[0, p]``, so two prompts sharing a token prefix share that
-prefix's K/V exactly. A block is only ever stored/adopted from fully
-computed positions and only ever matched by the exact token sequence
-(trie edges are the block's token tuple), so a hit cannot alias a
-different prompt.
+prefix's K/V exactly. A block is only ever adopted from fully computed
+positions and only ever matched by the exact token sequence (trie edges
+are the block's token tuple), so a hit cannot alias a different prompt.
 
-Shape discipline (same stance as the engine's compiled programs): the
-pool is ONE allocation per KV leaf, ``[capacity, block_tokens, H, D]``,
-sized up-front from a **byte budget**; store/splice/materialize compile
-once per power-of-two block-count bucket; eviction is pure host
-bookkeeping (LRU over unreferenced trie leaves).
-
-Ref-counting pins a matched chain for as long as a reader needs it (an
-admission splicing it, or — paged — a slot whose block table points at
-it); LRU eviction only considers nodes with no live references and no
-children (evicting a mid-chain node would strand its descendants).
+Ref-counting pins a matched chain for as long as a slot's block table
+points at it; LRU eviction only considers nodes with no live references
+and no children (evicting a mid-chain node would strand its
+descendants).
 
 NOT thread-safe: the trie and pool are mutated without locks, relying on
 the owning :class:`~distkeras_tpu.serving.engine.ServingEngine`'s loop
@@ -61,84 +48,12 @@ running engines.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import heapq
 import itertools
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from distkeras_tpu.telemetry import named_program
-
-__all__ = ["KVBlockPool", "PrefixCache", "PrefixMatch"]
-
-
-def _store_fn(block_tokens, pool, cache, slots, off0):
-    """Copy the ``len(slots)`` consecutive cache blocks starting at token
-    ``off0`` into pool rows ``slots`` — ONE scatter per leaf for a whole
-    insert. An insert's new blocks are always a contiguous suffix of the
-    prompt's block chain (a trie node cannot exist without its parent),
-    so one batched program replaces per-block stores — that matters on
-    backends where donation cannot alias (CPU): each store call would
-    otherwise copy the entire pool. ``slots`` is padded to a power-of-two
-    bucket with out-of-range ids; ``mode="drop"`` discards those updates
-    (and their clamped garbage source blocks) wholesale."""
-    b = slots.shape[0]
-    offs = off0 + jnp.arange(b, dtype=jnp.int32) * block_tokens
-
-    def put(p, c):
-        if p.shape[0] == 0:  # index-leaf placeholder: no pooled storage
-            return p
-        blk = jax.vmap(
-            lambda o: lax.dynamic_slice(
-                c[0], (o,) + (0,) * (c.ndim - 2),
-                (block_tokens,) + c.shape[2:]))(offs)
-        return p.at[slots].set(blk.astype(p.dtype), mode="drop")
-
-    return jax.tree.map(put, pool, cache)
-
-
-def _splice_fn(block_tokens, cache, pool, ids):
-    """Write pool rows ``ids`` as the cache's token prefix
-    ``[0, len(ids) * block_tokens)``. ``ids`` is a concrete-length vector,
-    so one program compiles per (power-of-two-bucketed) match length; the
-    gather + one leading ``dynamic_update_slice`` per leaf is the whole
-    hit path — no attention, no matmuls."""
-
-    def sp(c, p):
-        if c.ndim == 1:  # index leaves: the prefill chunk sets these
-            return c
-        blk = p[ids]  # [n, block_tokens, ...]
-        flat = blk.reshape((1, ids.shape[0] * block_tokens) + blk.shape[2:])
-        return lax.dynamic_update_slice(
-            c, flat.astype(c.dtype), (0,) * c.ndim)
-
-    return jax.tree.map(sp, cache, pool)
-
-
-def _materialize_fn(block_tokens, shapes, pool, ids):
-    """Build a FRESH single-row cache whose token prefix is pool rows
-    ``ids`` and whose tail is zeros — in one fused program per pow2
-    bucket. The splice path this replaces first materialized a full
-    max-length zeros cache (``_fresh_row_cache``) and then overwrote its
-    prefix with a second (donating) program; on backends where donation
-    cannot alias (CPU) that copied the whole leaf per admission. Here
-    the spliced region is never built as zeros at all — gather + static
-    pad, leaves the splice fully covers cost nothing extra."""
-
-    def mk(s, p):
-        if s.ndim == 1:  # index leaves: the prefill chunk sets these
-            return jnp.zeros(s.shape, s.dtype)
-        blk = p[ids]  # [n, block_tokens, ...]
-        flat = blk.reshape(
-            (1, ids.shape[0] * block_tokens) + blk.shape[2:]).astype(s.dtype)
-        pad = [(0, 0), (0, s.shape[1] - flat.shape[1])]
-        pad += [(0, 0)] * (s.ndim - 2)
-        return jnp.pad(flat, pad)
-
-    return jax.tree.map(mk, shapes, pool)
+__all__ = ["KVBlockPool", "PrefixMatch"]
 
 
 class _Node:
@@ -158,10 +73,9 @@ class _Node:
 
 @dataclasses.dataclass
 class PrefixMatch:
-    """A pinned match: ``release()`` it (via :meth:`PrefixCache.release`)
-    once the matched blocks are no longer being read — after the splice
-    (dense mode) or when the slot whose table points at them frees/adopts
-    (paged mode)."""
+    """A pinned match: ``release()`` it (via :meth:`KVBlockPool.release`)
+    once the matched blocks are no longer being read — when the slot
+    whose table points at them frees/adopts."""
 
     nodes: list
     ids: np.ndarray  # pool slots of the matched chain, int32 [n]
@@ -169,13 +83,76 @@ class PrefixMatch:
     released: bool = False
 
 
-class _BlockTrie:
-    """Shared core of both pool classes: the block allocator (free list +
-    LRU eviction of unreferenced trie leaves) and the radix trie over
-    token blocks (probe/match/release). Subclasses call
-    :meth:`_init_trie` and provide ``_note_occupancy``."""
+def _register_trie_metrics(registry) -> dict:
+    """The prefix-sharing metric family (hit/miss/eviction series)."""
+    return {
+        "hit_tokens": registry.counter(
+            "prefix_cache_hit_tokens_total",
+            help="prompt tokens whose prefill was skipped via the "
+                 "prefix cache"),
+        "miss_tokens": registry.counter(
+            "prefix_cache_miss_tokens_total",
+            help="prompt tokens prefilled from scratch"),
+        "hit_requests": registry.counter(
+            "prefix_cache_hit_requests_total",
+            help="lookups matching at least one block"),
+        "lookups": registry.counter(
+            "prefix_cache_lookups_total", help="prefix lookups"),
+        "evictions": registry.counter(
+            "prefix_cache_evicted_blocks_total",
+            help="blocks evicted (LRU under the byte budget)"),
+        "inserts": registry.counter(
+            "prefix_cache_inserted_blocks_total",
+            help="blocks stored/adopted into the prefix trie"),
+        "used": registry.gauge(
+            "prefix_cache_blocks_used", help="pool blocks in use"),
+        "capacity": registry.gauge(
+            "prefix_cache_blocks_capacity",
+            help="pool block capacity"),
+        "bytes": registry.gauge(
+            "prefix_cache_bytes_used", help="pool bytes in use"),
+    }
 
-    def _init_trie(self, capacity: int, block_tokens: int) -> None:
+
+class KVBlockPool:
+    """Host-side allocator + prefix trie over ONE shared KV block pool —
+    the engine's single memory manager for decode slots AND the prefix
+    cache: a block allocator (free list + LRU eviction of unreferenced
+    trie leaves) and a radix trie over token blocks
+    (probe/match/release).
+
+    This class owns NO device arrays: the pools (``[capacity,
+    block_tokens, H*D]`` per layer K/V) are the paged module's cache
+    variables, threaded through the engine's compiled programs. The
+    pool hands out *row ids*:
+
+    - :meth:`alloc` — take ``n`` private blocks for a slot (all-or-
+      nothing; evicts LRU unreferenced trie leaves when the free list is
+      dry; ``None`` means the caller must preempt someone or park);
+    - :meth:`free` — return private blocks;
+    - :meth:`match`/:meth:`release` — pin/unpin a shared prefix chain
+      (the slot's block table points at the pinned rows, zero-copy);
+    - :meth:`adopt` — a finished/preempted slot's complete blocks become
+      trie nodes in place (zero-copy prefix-cache insert); already-
+      cached duplicates are freed instead.
+
+    Write-sharing is impossible by construction (block-aligned matches;
+    appends go to private blocks), so the copy-on-write refcount's only
+    job is to keep shared rows from being evicted/reallocated under a
+    reader — there is never a copy to make.
+
+    ``kv_pool_blocks_{total,used,free}`` gauges plus the
+    ``prefix_cache_*`` hit/miss series publish into ``registry``
+    (an optional :class:`~distkeras_tpu.telemetry.registry.
+    MetricsRegistry`).
+    """
+
+    def __init__(self, capacity: int, block_tokens: int, *,
+                 bytes_per_block: int = 0, registry=None):
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        if block_tokens < 1:
+            raise ValueError(f"block_tokens must be >= 1, got {block_tokens}")
         self.block_tokens = int(block_tokens)
         self.capacity = int(capacity)
         self._root = _Node(-1, None, None)
@@ -203,7 +180,7 @@ class _BlockTrie:
         # reconstructible) and its pool row still holds the KV bytes.
         self.spill_hook = None
         # Batched variant, ``hook(list[(chain_tokens, slot)])``: when
-        # set, multi-block allocation bursts (alloc(n), insert,
+        # set, multi-block allocation bursts (alloc(n),
         # adopt_foreign) COLLECT their victims and fire one call at the
         # end of the burst — one D2H gather for the whole burst instead
         # of one per victim. The victims' pool rows still hold their KV
@@ -214,6 +191,69 @@ class _BlockTrie:
         # open.
         self.spill_many_hook = None
         self._spill_batch: list | None = None  # open burst's victims
+        self.bytes_per_block = int(bytes_per_block)
+        # High-water mark of blocks in use — what a byte budget must
+        # actually cover.
+        self.peak_blocks_used = 0
+        # Copy-on-write sharing for forked sampling (kind="sample"):
+        # ``_fork_refs[row] = k`` means k ADDITIONAL owners share the row
+        # beyond the one that will eventually free it last. ``free`` on
+        # such a row decrements instead of returning it to the free list
+        # — the row truly frees only when its last owner lets go. Rows
+        # here are PRIVATE slot rows (complete, never-again-written
+        # prompt blocks shared by n fork rows), distinct from trie
+        # pinning (``_Node.refs``), which protects SHARED trie rows.
+        self._fork_refs: dict[int, int] = {}
+        self.forked_blocks_total = 0  # cumulative extra shares handed out
+        self.fork_cow_copies = 0      # tail blocks copied at fork time
+        self._g_pool = None
+        if registry is not None:
+            self._metrics = _register_trie_metrics(registry)
+            self._metrics["capacity"].set(self.capacity)
+            self._g_pool = {
+                "total": registry.gauge(
+                    "kv_pool_blocks_total",
+                    help="KV block pool capacity (blocks)"),
+                "used": registry.gauge(
+                    "kv_pool_blocks_used",
+                    help="KV blocks held by decode slots or the prefix "
+                         "trie"),
+                "free": registry.gauge(
+                    "kv_pool_blocks_free", help="KV blocks on the free "
+                                                "list"),
+                # A total and not a gauge: its delta over a window, over
+                # the decode iterations' delta and the capacity, is the
+                # pool's mean fill while the window decoded.
+                "block_ticks": registry.counter(
+                    "kv_pool_block_ticks_total",
+                    help="KV blocks in use, summed over decode loop "
+                         "iterations"),
+            }
+            self._g_pool["total"].set(self.capacity)
+            self._note_occupancy()
+
+    def stats(self) -> dict:
+        total = self.hit_tokens + self.miss_tokens
+        return {
+            "block_tokens": self.block_tokens,
+            "capacity_blocks": self.capacity,
+            "blocks_used": self.blocks_used,
+            "blocks_free": self.blocks_free,
+            "peak_blocks_used": self.peak_blocks_used,
+            "bytes_per_block": self.bytes_per_block,
+            "bytes_used": self.blocks_used * self.bytes_per_block,
+            "lookups": self.lookups,
+            "hit_requests": self.hit_requests,
+            "hit_tokens": self.hit_tokens,
+            "miss_tokens": self.miss_tokens,
+            "hit_rate": (self.hit_tokens / total) if total else 0.0,
+            "inserted_blocks": self.inserted_blocks,
+            "evicted_blocks": self.evicted_blocks,
+            "flushes": self.flushes,
+            "fork_shared_blocks": len(self._fork_refs),
+            "forked_blocks_total": self.forked_blocks_total,
+            "fork_cow_copies": self.fork_cow_copies,
+        }
 
     # -- introspection ------------------------------------------------------
     @property
@@ -267,18 +307,19 @@ class _BlockTrie:
         wrong). Host bookkeeping only — the device pools stay allocated
         and their rows are simply free to overwrite; cumulative hit/miss
         counters keep counting across the flush. Must be called with no
-        reader in flight (no pinned matches and — paged — no slot-owned
-        blocks): the engine's swap path guarantees that by running with
-        zero active slots; any match object still held afterwards
-        releases onto orphaned nodes, harmlessly."""
+        reader in flight (no pinned matches, no slot-owned blocks, and so
+        no fork group still sharing rows): the engine's swap path
+        guarantees that by running with zero active slots; any match
+        object still held afterwards releases onto orphaned nodes,
+        harmlessly."""
+        self._fork_refs.clear()
         self._root = _Node(-1, None, None)
         self._by_slot.clear()
         self._free = list(range(self.capacity - 1, -1, -1))
         self._lru = []
         self.flushes += 1
         self.version += 1
-        if self._metrics is not None:
-            self._note_occupancy()
+        self._note_occupancy()
 
     # -- trie walk ----------------------------------------------------------
     @staticmethod
@@ -451,381 +492,6 @@ class _BlockTrie:
             except Exception:  # pragma: no cover - defensive
                 pass
 
-    def _note_occupancy(self) -> None:  # pragma: no cover - overridden
-        pass
-
-
-class PrefixCache(_BlockTrie):
-    """Device-owning block pool + radix trie over prompt prefixes — the
-    DENSE engine's prefix cache (paged engines use :class:`KVBlockPool`,
-    where the device arrays live in the engine's cache pytree instead).
-
-    ``template``: the single-row decode cache pytree (concrete arrays or
-    ``jax.eval_shape`` structs) — KV leaves ``[1, L, H, D]`` define the
-    pool geometry; 1-D index leaves get no pooled storage.
-    ``block_tokens``: granularity of sharing — smaller blocks match more
-    of a prefix but cost more trie nodes and splice slots per hit.
-    ``budget_bytes``: hard cap on pool memory; capacity =
-    ``budget_bytes // bytes_per_block`` blocks, allocated up-front.
-    ``registry``: optional :class:`~distkeras_tpu.telemetry.registry.
-    MetricsRegistry` — hit/miss/eviction counters and occupancy gauges
-    for ``metricsz``.
-    ``mesh``: a serving mesh (GSPMD tensor-parallel engine) — the block
-    pools are then allocated heads-sharded over the mesh's ``tp`` axis
-    (:func:`distkeras_tpu.parallel.sharding.kv_pytree_shardings`, the
-    same rule the engine applies to its batch cache) and the rows
-    ``materialize``/``splice`` build are pinned to the engine's sharded
-    row layout — a cache hit never moves KV bytes between devices, only
-    row ids. Trie/allocator state is host bookkeeping either way.
-    ``stage_meshes``: a pp engine's per-stage tp submeshes — ``template``
-    is then a per-stage LIST of row subtrees (the engine's
-    ``StagePlan.split_tree`` carve of the single-row cache), the pool
-    becomes one per-stage pool placed on its stage's devices, and
-    ``splice``/``materialize``/``insert`` take/return per-stage cache
-    lists. ONE trie spans all stages: a block's trie node stands for the
-    same token positions in every stage's pool, so the host bookkeeping
-    (match/insert/evict) stays stage-agnostic while the bytes never
-    leave their stage.
-    """
-
-    def __init__(self, template, *, block_tokens: int = 16,
-                 budget_bytes: int = 64 * 2**20, registry=None, mesh=None,
-                 stage_meshes=None):
-        if block_tokens < 1:
-            raise ValueError(f"block_tokens must be >= 1, got {block_tokens}")
-        self._stages = len(stage_meshes) if stage_meshes is not None else 0
-        kv_leaves = [a for a in jax.tree.leaves(template) if a.ndim > 1]
-        if not kv_leaves:
-            raise ValueError("cache template has no KV leaves")
-        L = kv_leaves[0].shape[1]
-        if block_tokens > L:
-            raise ValueError(
-                f"block_tokens={block_tokens} exceeds cache length {L}")
-        self.max_blocks = L // int(block_tokens)
-        self.bytes_per_block = sum(
-            int(block_tokens) * int(np.prod(a.shape[2:])) * a.dtype.itemsize
-            for a in kv_leaves)
-        capacity = int(budget_bytes) // self.bytes_per_block
-        if capacity < 1:
-            raise ValueError(
-                f"budget_bytes={budget_bytes} holds zero blocks "
-                f"(one block = {self.bytes_per_block} bytes)")
-        self._init_trie(capacity, block_tokens)
-        self.mesh = mesh
-
-        def mk_pool(a):
-            return (jnp.zeros((0,), jnp.int32) if a.ndim == 1 else
-                    jnp.zeros((self.capacity, self.block_tokens)
-                              + a.shape[2:], a.dtype))
-
-        # Named for the profiler trace (a partial alone is jit__unknown).
-        store_fn = named_program(
-            functools.partial(_store_fn, self.block_tokens), "prefix_store")
-        splice_fn = named_program(
-            functools.partial(_splice_fn, self.block_tokens), "prefix_splice")
-        if self._stages:
-            from distkeras_tpu.parallel.sharding import kv_pytree_shardings
-
-            self._pool = [jax.tree.map(mk_pool, part) for part in template]
-            self._row_shapes = [
-                jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), part)
-                for part in template]
-            pool_sh = [kv_pytree_shardings(m, p)
-                       for m, p in zip(stage_meshes, self._pool)]
-            row_sh = [kv_pytree_shardings(m, r)
-                      for m, r in zip(stage_meshes, self._row_shapes)]
-            self._pool = [jax.device_put(p, sh)
-                          for p, sh in zip(self._pool, pool_sh)]
-            self._store = [
-                jax.jit(store_fn, donate_argnums=(0,), out_shardings=sh)
-                for sh in pool_sh]
-            self._splice = [
-                jax.jit(splice_fn, donate_argnums=(0,), out_shardings=sh)
-                for sh in row_sh]
-            self._materialize = [
-                jax.jit(named_program(
-                    functools.partial(_materialize_fn, self.block_tokens,
-                                      shapes), "prefix_materialize"),
-                        out_shardings=sh)
-                for shapes, sh in zip(self._row_shapes, row_sh)]
-        else:
-            self._pool = jax.tree.map(mk_pool, template)
-            self._row_shapes = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), template)
-            pool_sh = row_sh = None
-            if mesh is not None:
-                from distkeras_tpu.parallel.sharding import (
-                    kv_pytree_shardings,
-                )
-
-                pool_sh = kv_pytree_shardings(mesh, self._pool)
-                row_sh = kv_pytree_shardings(mesh, self._row_shapes)
-                self._pool = jax.device_put(self._pool, pool_sh)
-            self._store = jax.jit(
-                store_fn, donate_argnums=(0,),
-                **({} if mesh is None else {"out_shardings": pool_sh}))
-            self._splice = jax.jit(
-                splice_fn,
-                donate_argnums=(0,),  # the cache being built; pool persists
-                **({} if mesh is None else {"out_shardings": row_sh}))
-            self._materialize = jax.jit(
-                named_program(
-                    functools.partial(_materialize_fn, self.block_tokens,
-                                      self._row_shapes),
-                    "prefix_materialize"),
-                **({} if mesh is None else {"out_shardings": row_sh}))
-        if registry is not None:
-            self._metrics = _register_trie_metrics(registry)
-            self._metrics["capacity"].set(self.capacity)
-
-    def stats(self) -> dict:
-        total = self.hit_tokens + self.miss_tokens
-        return {
-            "block_tokens": self.block_tokens,
-            "capacity_blocks": self.capacity,
-            "blocks_used": self.blocks_used,
-            "bytes_used": self.blocks_used * self.bytes_per_block,
-            "bytes_per_block": self.bytes_per_block,
-            "lookups": self.lookups,
-            "hit_requests": self.hit_requests,
-            "hit_tokens": self.hit_tokens,
-            "miss_tokens": self.miss_tokens,
-            "hit_rate": (self.hit_tokens / total) if total else 0.0,
-            "inserted_blocks": self.inserted_blocks,
-            "evicted_blocks": self.evicted_blocks,
-            "flushes": self.flushes,
-        }
-
-    # -- device ops ---------------------------------------------------------
-    def _pad_ids(self, ids, fill: int) -> np.ndarray:
-        """Pad a pool-row id list to its power-of-two bucket (capped at
-        the per-cache block capacity) so store/splice compile once per
-        bucket. ``fill`` picks the padding semantics: a valid row id
-        (splice: reads garbage the mask hides) or an out-of-range id
-        (store: ``mode=\"drop\"`` discards those writes)."""
-        n = len(ids)
-        b = 1
-        while b < n:
-            b *= 2
-        b = min(b, self.max_blocks)
-        out = np.full((b,), fill, np.int32)
-        out[:n] = ids
-        return out
-
-    def splice(self, cache, ids: np.ndarray):
-        """Return ``cache`` with pool rows ``ids`` written as its token
-        prefix. ``ids`` is padded to a power-of-two bucket so compiles
-        stay bounded; rows written past the true match are garbage the
-        causal mask hides until the tail prefill / decode overwrites
-        them. Donates ``cache``."""
-        ids_dev = jnp.asarray(self._pad_ids(ids, 0))
-        if self._stages:
-            return [sp(c, p, ids_dev) for sp, c, p
-                    in zip(self._splice, cache, self._pool)]
-        return self._splice(cache, self._pool, ids_dev)
-
-    def materialize(self, ids: np.ndarray):
-        """Build a FRESH single-row cache with pool rows ``ids`` as its
-        token prefix and zeros past it — the hit-path replacement for
-        "allocate a full zeros cache, then splice": the leaves the
-        splice covers are never materialized as zeros first (and never
-        round-trip through a donation the backend may have to copy).
-        Same pad-width bucketing as :meth:`splice`."""
-        ids_dev = jnp.asarray(self._pad_ids(ids, 0))
-        if self._stages:
-            return [mk(p, ids_dev) for mk, p
-                    in zip(self._materialize, self._pool)]
-        return self._materialize(self._pool, ids_dev)
-
-    def insert(self, tokens, cache) -> int:
-        """Store every complete block of ``tokens`` not already cached,
-        copying K/V rows out of the fully-prefilled single-row ``cache``
-        in ONE batched device call. Allocation evicts LRU unreferenced
-        leaves; when nothing is evictable the insert stops early (the
-        chain must stay contiguous). Returns the newly stored count."""
-        keys = list(self._blocks(tokens, len(tokens) // self.block_tokens))
-        now = next(self._clock)
-        node, idx = self._root, 0
-        while idx < len(keys):  # walk (and touch) the existing prefix
-            child = node.children.get(keys[idx])
-            if child is None:
-                break
-            self._touch(child, now)
-            node = child
-            idx += 1
-        take: list[int] = []
-        opened = self._begin_spill_burst()
-        try:
-            for _ in keys[idx:]:
-                slot = self._alloc(protect=node)
-                if slot is None:
-                    break
-                take.append(slot)
-        finally:
-            if opened:
-                self._flush_spill_burst()
-        if not take:
-            return 0
-        n = len(take)
-        ids_dev = jnp.asarray(self._pad_ids(take, self.capacity))
-        off = jnp.int32(idx * self.block_tokens)
-        if self._stages:
-            self._pool = [st(p, c, ids_dev, off) for st, p, c
-                          in zip(self._store, self._pool, cache)]
-        else:
-            self._pool = self._store(self._pool, cache, ids_dev, off)
-        for key, slot in zip(keys[idx:idx + n], take):
-            child = _Node(slot, node, key)
-            node.children[key] = child
-            self._by_slot[slot] = child
-            self._touch(child, now)
-            node = child
-        self.inserted_blocks += n
-        self.version += 1
-        if self._metrics is not None:
-            self._metrics["inserts"].inc(n)
-            self._note_occupancy()
-        return n
-
-    def _note_occupancy(self) -> None:
-        self._metrics["used"].set(self.blocks_used)
-        self._metrics["bytes"].set(self.blocks_used * self.bytes_per_block)
-
-
-def _register_trie_metrics(registry) -> dict:
-    """The prefix-sharing metric family — shared by both pool classes so
-    an operator reads ONE set of hit/miss/eviction series whether the
-    engine runs dense (PrefixCache) or paged (KVBlockPool)."""
-    return {
-        "hit_tokens": registry.counter(
-            "prefix_cache_hit_tokens_total",
-            help="prompt tokens whose prefill was skipped via the "
-                 "prefix cache"),
-        "miss_tokens": registry.counter(
-            "prefix_cache_miss_tokens_total",
-            help="prompt tokens prefilled from scratch"),
-        "hit_requests": registry.counter(
-            "prefix_cache_hit_requests_total",
-            help="lookups matching at least one block"),
-        "lookups": registry.counter(
-            "prefix_cache_lookups_total", help="prefix lookups"),
-        "evictions": registry.counter(
-            "prefix_cache_evicted_blocks_total",
-            help="blocks evicted (LRU under the byte budget)"),
-        "inserts": registry.counter(
-            "prefix_cache_inserted_blocks_total",
-            help="blocks stored/adopted into the prefix trie"),
-        "used": registry.gauge(
-            "prefix_cache_blocks_used", help="pool blocks in use"),
-        "capacity": registry.gauge(
-            "prefix_cache_blocks_capacity",
-            help="pool block capacity"),
-        "bytes": registry.gauge(
-            "prefix_cache_bytes_used", help="pool bytes in use"),
-    }
-
-
-class KVBlockPool(_BlockTrie):
-    """Host-side allocator + prefix trie over ONE shared KV block pool —
-    the paged engine's single memory manager for decode slots AND the
-    prefix cache.
-
-    Unlike :class:`PrefixCache` this class owns NO device arrays: the
-    pools (``[capacity, block_tokens, H*D]`` per layer K/V) are the
-    paged module's cache variables, threaded through the engine's
-    compiled programs. The pool hands out *row ids*:
-
-    - :meth:`alloc` — take ``n`` private blocks for a slot (all-or-
-      nothing; evicts LRU unreferenced trie leaves when the free list is
-      dry; ``None`` means the caller must preempt someone or park);
-    - :meth:`free` — return private blocks;
-    - :meth:`match`/:meth:`release` — pin/unpin a shared prefix chain
-      (the slot's block table points at the pinned rows, zero-copy);
-    - :meth:`adopt` — a finished/preempted slot's complete blocks become
-      trie nodes in place (zero-copy prefix-cache insert); already-
-      cached duplicates are freed instead.
-
-    Write-sharing is impossible by construction (block-aligned matches;
-    appends go to private blocks), so the copy-on-write refcount's only
-    job is to keep shared rows from being evicted/reallocated under a
-    reader — there is never a copy to make.
-
-    ``kv_pool_blocks_{total,used,free}`` gauges plus the shared
-    ``prefix_cache_*`` hit/miss series publish into ``registry``.
-    """
-
-    def __init__(self, capacity: int, block_tokens: int, *,
-                 bytes_per_block: int = 0, registry=None):
-        if capacity < 1:
-            raise ValueError(f"capacity must be >= 1, got {capacity}")
-        if block_tokens < 1:
-            raise ValueError(f"block_tokens must be >= 1, got {block_tokens}")
-        self._init_trie(capacity, block_tokens)
-        self.bytes_per_block = int(bytes_per_block)
-        # High-water mark of blocks in use — what a byte budget must
-        # actually cover; serving_bench turns it into tokens-per-byte.
-        self.peak_blocks_used = 0
-        # Copy-on-write sharing for forked sampling (kind="sample"):
-        # ``_fork_refs[row] = k`` means k ADDITIONAL owners share the row
-        # beyond the one that will eventually free it last. ``free`` on
-        # such a row decrements instead of returning it to the free list
-        # — the row truly frees only when its last owner lets go. Rows
-        # here are PRIVATE slot rows (complete, never-again-written
-        # prompt blocks shared by n fork rows), distinct from trie
-        # pinning (``_Node.refs``), which protects SHARED trie rows.
-        self._fork_refs: dict[int, int] = {}
-        self.forked_blocks_total = 0  # cumulative extra shares handed out
-        self.fork_cow_copies = 0      # tail blocks copied at fork time
-        self._g_pool = None
-        if registry is not None:
-            self._metrics = _register_trie_metrics(registry)
-            self._metrics["capacity"].set(self.capacity)
-            self._g_pool = {
-                "total": registry.gauge(
-                    "kv_pool_blocks_total",
-                    help="KV block pool capacity (blocks)"),
-                "used": registry.gauge(
-                    "kv_pool_blocks_used",
-                    help="KV blocks held by decode slots or the prefix "
-                         "trie"),
-                "free": registry.gauge(
-                    "kv_pool_blocks_free", help="KV blocks on the free "
-                                                "list"),
-                # A total and not a gauge: its delta over a window, over
-                # the decode iterations' delta and the capacity, is the
-                # pool's mean fill while the window decoded.
-                "block_ticks": registry.counter(
-                    "kv_pool_block_ticks_total",
-                    help="KV blocks in use, summed over decode loop "
-                         "iterations"),
-            }
-            self._g_pool["total"].set(self.capacity)
-            self._note_occupancy()
-
-    def stats(self) -> dict:
-        total = self.hit_tokens + self.miss_tokens
-        return {
-            "block_tokens": self.block_tokens,
-            "capacity_blocks": self.capacity,
-            "blocks_used": self.blocks_used,
-            "blocks_free": self.blocks_free,
-            "peak_blocks_used": self.peak_blocks_used,
-            "bytes_per_block": self.bytes_per_block,
-            "bytes_used": self.blocks_used * self.bytes_per_block,
-            "lookups": self.lookups,
-            "hit_requests": self.hit_requests,
-            "hit_tokens": self.hit_tokens,
-            "miss_tokens": self.miss_tokens,
-            "hit_rate": (self.hit_tokens / total) if total else 0.0,
-            "inserted_blocks": self.inserted_blocks,
-            "evicted_blocks": self.evicted_blocks,
-            "flushes": self.flushes,
-            "fork_shared_blocks": len(self._fork_refs),
-            "forked_blocks_total": self.forked_blocks_total,
-            "fork_cow_copies": self.fork_cow_copies,
-        }
-
     # -- slot allocation ----------------------------------------------------
     @property
     def next_alloc_spills(self) -> bool:
@@ -861,8 +527,7 @@ class KVBlockPool(_BlockTrie):
             if opened:
                 self._flush_spill_burst()
         self.peak_blocks_used = max(self.peak_blocks_used, self.blocks_used)
-        if self._metrics is not None:
-            self._note_occupancy()
+        self._note_occupancy()
         return got
 
     def fork(self, ids, n: int) -> None:
@@ -910,14 +575,7 @@ class KVBlockPool(_BlockTrie):
             return
         self._free.extend(released)
         self.version += 1
-        if self._metrics is not None:
-            self._note_occupancy()
-
-    def flush(self) -> None:
-        """Pool flush additionally clears fork shares: a flush runs with
-        zero active slots, so no fork group can still own rows."""
-        self._fork_refs.clear()
-        super().flush()
+        self._note_occupancy()
 
     def adopt(self, tokens, ids, first_block: int) -> int:
         """Zero-copy prefix-cache insert: chain the slot's private rows

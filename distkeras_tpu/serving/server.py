@@ -869,34 +869,31 @@ class ServingServer:
                 # healthz rollups (and the deploy controller's verify)
                 # can spot a mixed-mesh fleet without an extra verb.
                 health["mesh"] = mesh
-            if engine.prefix_cache is not None:
-                health["prefix_cache"] = engine.prefix_cache.stats()
-            if engine.kv_pool is not None:
-                health["kv_pool"] = engine.kv_pool.stats()
-                # Block-migration rollup (the router sums these across
-                # the fleet; the "decode fleet starving" runbook reads
-                # them here first).
-                health["kv_migrations"] = {
-                    "migrations": engine.metrics.kv_migrations,
-                    "fallbacks": engine.metrics.kv_migration_fallbacks,
-                    "bytes": engine.metrics.kv_migration_bytes,
-                    "exports": engine.metrics.kv_exports,
+            health["kv_pool"] = engine.kv_pool.stats()
+            # Block-migration rollup (the router sums these across
+            # the fleet; the "decode fleet starving" runbook reads
+            # them here first).
+            health["kv_migrations"] = {
+                "migrations": engine.metrics.kv_migrations,
+                "fallbacks": engine.metrics.kv_migration_fallbacks,
+                "bytes": engine.metrics.kv_migration_bytes,
+                "exports": engine.metrics.kv_exports,
+            }
+            if engine.kv_tier is not None:
+                # Tiered-KV rollup: per-level occupancy + the
+                # spill/readmit/push traffic — the "host tier
+                # thrashing" runbook reads these here first.
+                health["kv_tier"] = {
+                    **engine.kv_tier.stats(),
+                    "spills": engine.metrics.kv_spills,
+                    "spill_bytes": engine.metrics.kv_spill_bytes,
+                    "readmits": engine.metrics.kv_readmits,
+                    "readmit_bytes": engine.metrics.kv_readmit_bytes,
+                    "pushes": engine.metrics.kv_pushes,
+                    "push_bytes": engine.metrics.kv_push_bytes,
+                    "push_fallbacks":
+                        engine.metrics.kv_push_fallbacks,
                 }
-                if engine.kv_tier is not None:
-                    # Tiered-KV rollup: per-level occupancy + the
-                    # spill/readmit/push traffic — the "host tier
-                    # thrashing" runbook reads these here first.
-                    health["kv_tier"] = {
-                        **engine.kv_tier.stats(),
-                        "spills": engine.metrics.kv_spills,
-                        "spill_bytes": engine.metrics.kv_spill_bytes,
-                        "readmits": engine.metrics.kv_readmits,
-                        "readmit_bytes": engine.metrics.kv_readmit_bytes,
-                        "pushes": engine.metrics.kv_pushes,
-                        "push_bytes": engine.metrics.kv_push_bytes,
-                        "push_fallbacks":
-                            engine.metrics.kv_push_fallbacks,
-                    }
             if engine.auditor is not None:
                 health["recompile_audit"] = engine.auditor.report()
             if engine.slo_s is not None:
@@ -985,11 +982,6 @@ class ServingServer:
         is a trie hit here and costs only the uncached tail. The reply
         carries what became exportable; failures are typed — the
         router falls back to monolithic dispatch."""
-        if self.engine.kv_pool is None:
-            return {"error": "kv_prefill requires a paged engine "
-                             "(--paged / --kv-pool-mb): only pooled "
-                             "blocks are exportable",
-                    "code": "kv_transfer"}
         prompt = spec.get("prompt") or []
         try:
             req = self.engine.submit(

@@ -9,9 +9,7 @@ through bucketing) turns a microseconds dispatch into a seconds-long
 compile — "it got slower and nobody noticed" until tail latency pages
 someone.
 
-Until this module, the only guard was a benchmark assertion
-(``benchmarks/serving_bench.py`` asserting ``decode_compile_count() == 1``).
-:class:`RecompileAuditor` moves the check into the runtime:
+:class:`RecompileAuditor` makes the check part of the runtime:
 
 - :meth:`RecompileAuditor.wrap` wraps a jitted callable; each compile is
   detected (via the jit cache-size probe when available, else by tracking

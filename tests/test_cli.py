@@ -80,35 +80,37 @@ def test_cli_train_statusz_and_stamped_out(job, capsys):
 
 def test_serving_config_flags_forwarded_to_replicas():
     """The ONE replica-flag builder shared by `cluster` and `deploy`:
-    paged/chunk/speculation configuration reaches every replica child —
+    pool/chunk/speculation configuration reaches every replica child —
     which is what makes deploy's canary validate candidates under the
-    fleet's REAL serving config instead of the dense one-token
-    default."""
+    fleet's REAL serving config instead of the one-token default."""
     import argparse
 
     from distkeras_tpu.run import _serving_config_flags
 
     args = argparse.Namespace(
-        top_k=8, prefill_chunk=32, prefix_cache_mb=0.0, prefix_block=16,
+        top_k=8, prefill_chunk=32,
         paged=True, kv_pool_mb=64.0, kv_block_tokens=8, max_context=48,
         draft_model="gpt_tiny", draft_args='{"seq_len": 64}',
         draft_weights=None, spec_k=6)
     flags = _serving_config_flags(args)
-    for pair in (["--paged"], ["--kv-pool-mb", "64.0"],
+    for pair in (["--kv-pool-mb", "64.0"],
                  ["--kv-block-tokens", "8"], ["--prefill-chunk", "32"],
                  ["--max-context", "48"], ["--top-k", "8"],
                  ["--draft-model", "gpt_tiny"],
                  ["--draft-args", '{"seq_len": 64}'], ["--spec-k", "6"]):
         joined = " ".join(flags)
         assert " ".join(pair) in joined, (pair, flags)
-    # Dense default: no paged/spec flags leak into the children.
+    # The default: no budget and no spec flags leak into the children
+    # (each sizes its own pool), and the no-op --paged is never passed on.
     plain = argparse.Namespace(
-        top_k=None, prefill_chunk=None, prefix_cache_mb=0.0,
-        prefix_block=16, paged=False, kv_pool_mb=0.0, kv_block_tokens=16,
+        top_k=None, prefill_chunk=None,
+        paged=False, kv_pool_mb=0.0, kv_block_tokens=16,
         max_context=None, draft_model=None, draft_args="{}",
         draft_weights=None, spec_k=4)
+    assert "--paged" not in flags
     flags = _serving_config_flags(plain)
-    assert "--paged" not in flags and "--draft-model" not in flags
+    assert "--kv-pool-mb" not in flags and "--draft-model" not in flags
+    assert flags[:2] == ["--kv-block-tokens", "16"]
 
     assert all(isinstance(f, str) for f in _serving_config_flags(args))
 
@@ -129,10 +131,62 @@ def test_deploy_and_serve_parsers_accept_serving_config(capsys):
         text = capsys.readouterr().out
         for flag in ("--draft-model", "--draft-args", "--spec-k",
                      "--paged", "--kv-pool-mb", "--kv-block-tokens",
-                     "--prefill-chunk", "--prefix-cache-mb",
+                     "--prefill-chunk",
                      "--max-context", "--mesh", "--mesh-shape",
                      "--force-host-devices"):
             assert flag in text, (main_fn.__name__, flag)
+
+
+def _serving_configs():
+    import glob
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = []
+    for path in sorted(glob.glob(
+            os.path.join(root, "chipbench", "configs", "*.json"))):
+        with open(path) as f:
+            config = json.load(f)
+        if config.get("entry") == "serve":
+            out.append(pytest.param(config["serve_flags"],
+                                    id=config["name"]))
+    return out
+
+
+@pytest.mark.parametrize("serve_flags", _serving_configs())
+def test_serve_parser_accepts_the_benchmark_configs_flags(serve_flags,
+                                                          monkeypatch):
+    """`run serve` takes each served configuration's ``serve_flags``
+    whole, the no-op ``--paged`` included: the driver starts parent and
+    change from the same files (read here, never edited). The seam is
+    the one `chipbench/harness/serve_child.py` uses: `run.load_model`,
+    reached only once the arguments have parsed."""
+    from distkeras_tpu import run
+
+    class Parsed(Exception):
+        pass
+
+    def stop(_name, _kwargs):
+        raise Parsed
+
+    monkeypatch.setattr(run, "load_model", stop)
+    assert "--paged" in serve_flags
+    with pytest.raises(Parsed):
+        run.serve_main(["--model", "gpt_tiny", "--port", "0", *serve_flags])
+
+
+@pytest.mark.parametrize("entry,argv", [
+    ("serve_main", []), ("deploy_main", ["--watch-dir", "unused"])])
+@pytest.mark.parametrize("flag", ["--prefix-cache-mb", "--prefix-block"])
+def test_parsers_refuse_the_removed_prefix_cache_flags(entry, argv, flag,
+                                                       capsys):
+    """A stale deployment script fails at start, not silently."""
+    from distkeras_tpu import run
+
+    with pytest.raises(SystemExit) as e:
+        getattr(run, entry)([*argv, flag, "1"])
+    assert e.value.code == 2
+    assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
 
 
 def test_serve_mesh_shape_typed_cli_errors():
@@ -167,8 +221,8 @@ def test_mesh_flags_forwarded_to_replicas():
     from distkeras_tpu.run import _serving_config_flags
 
     base = dict(
-        top_k=None, prefill_chunk=None, prefix_cache_mb=0.0,
-        prefix_block=16, paged=False, kv_pool_mb=0.0, kv_block_tokens=16,
+        top_k=None, prefill_chunk=None,
+        paged=False, kv_pool_mb=0.0, kv_block_tokens=16,
         max_context=None, draft_model=None, draft_args="{}",
         draft_weights=None, spec_k=4)
     shaped = argparse.Namespace(**base, mesh=False, mesh_shape="tp=2",

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distkeras_tpu.inference.generate import _decode_module
+from distkeras_tpu.inference.generate import _decode_module, generate
 from distkeras_tpu.models import cohere2_moe
 from distkeras_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
 from distkeras_tpu.ops import moe
@@ -362,10 +362,12 @@ def test_decode_module_takes_both_blocks_and_refuses_the_rest():
         _decode_module(mnist_mlp())
     with pytest.raises(ValueError, match="decode module"):
         _decode_module(object())
-    # no dense per-slot cache for this block: a typed refusal, not a shape error
+    # no dense cache for this block (the offline path's, the speculative
+    # draft's): a typed refusal, not a shape error
     model = cohere2_moe.command_a_plus_tiny()
     with pytest.raises(ValueError, match="paged"):
-        ServingEngine(model, model.init(0), slots=2)
+        generate(model, model.init(0), np.asarray([[1, 2, 3]], np.int32), 2,
+                 greedy=True)
     with pytest.raises(ValueError, match="held_experts"):
         Cohere2MoeConfig(num_experts=8, held_experts=(6, 4))
     with pytest.raises(ValueError, match="layer type"):

@@ -55,9 +55,9 @@ def _engine(tiny_lm, depth, **kw):
 
 
 MODES = {
-    "dense": {},
+    "derived_pool": {},  # no budget: a whole context for every slot
     "paged": {"kv_pool_blocks": 24, "kv_block_tokens": 8},
-    "chunked_prefix": {"prefill_chunk": 4, "prefix_cache_mb": 0.5},
+    "chunked_prefix": {"prefill_chunk": 4, "kv_block_tokens": 4},
 }
 
 

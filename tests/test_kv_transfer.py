@@ -67,7 +67,6 @@ def _engine(lm, **kw):
 
     model, variables = lm
     kw.setdefault("slots", 2)
-    kw.setdefault("paged", True)
     kw.setdefault("kv_pool_blocks", 64)
     kw.setdefault("kv_block_tokens", 4)
     return ServingEngine(model, variables, **kw)
@@ -289,15 +288,19 @@ def test_provenance_mismatch_is_a_typed_reject(lm, rng):
     asyncio.run(main())
 
 
-def test_dense_engine_rejects_kv_transfer_typed(lm):
-    from distkeras_tpu.serving import ServingEngine
+def test_pp_engine_rejects_kv_transfer_typed(lm):
+    """The gather and scatter programs span the whole pool, which a pp
+    mesh partitions by stage: export and import are refused typed."""
+    import jax
 
-    model, variables = lm
-    dense = ServingEngine(model, variables, slots=1)
-    with pytest.raises(KVTransferError):
-        dense.request_kv_export([1, 2, 3])
-    with pytest.raises(KVTransferError):
-        dense.request_kv_import(b"")
+    from distkeras_tpu.parallel.mesh import serving_mesh
+
+    staged = _engine(lm, mesh=serving_mesh({"tp": 1, "pp": 2},
+                                           devices=jax.devices()[:2]))
+    with pytest.raises(KVTransferError, match="pp mesh"):
+        staged.request_kv_export([1, 2, 3])
+    with pytest.raises(KVTransferError, match="pp mesh"):
+        staged.request_kv_import(b"")
 
 
 # -- fleet-level disaggregation ----------------------------------------------

@@ -1,10 +1,11 @@
-"""Paged KV memory: one block pool for decode slots AND the prefix cache.
+"""The KV cache: one block pool for decode slots AND the prefix cache.
 
 The invariants under test, all on CPU with a tiny causal LM:
 
-- paged greedy streams are token-identical to offline ``generate()`` AND
-  to the dense-cache engine, including prefix-cache hit/miss and
-  evict-round-trip cases (the paged pool doubles as the prefix cache);
+- greedy streams are token-identical to offline ``generate()``, from a
+  budgeted pool and from the pool an engine derives when given no
+  budget, including prefix-cache hit/miss and evict-round-trip cases
+  (the pool doubles as the prefix cache);
 - the single-compiled-decode-step invariant survives paging: an ARMED
   ``RecompileAuditor`` stays silent across admissions, block-table
   growth, preemptions, and long-context requests;
@@ -12,22 +13,24 @@ The invariants under test, all on CPU with a tiny causal LM:
   completes every request token-identically (preempt -> adopt blocks ->
   requeue -> resume prefill folds streamed tokens back in), and the
   request's timeline shows both admission hops under one trace_id;
-- long-context admission: a request longer than a dense engine's padded
-  max (same byte budget) is served to completion because blocks chain
-  on demand instead of being pre-reserved;
+- long-context admission: a request longer than the context a
+  whole-context-per-slot pool of the same bytes affords is served to
+  completion because blocks chain on demand instead of being
+  pre-reserved;
 - requests that can NEVER fit the pool are rejected with the typed
   ``kv_oom`` error at submit, before any device work;
 - pool health is observable: ``kv_pool_blocks_{total,used,free}``
   gauges, ``kv_preemptions_total`` / ``kv_oom_rejections_total``
   counters, and per-slot block-table depth in ``debugz``.
 
-``KVBlockPool`` unit behavior (alloc/free/adopt/match, model-free) rides
-along at the top — it is the host-side allocator everything above
-leans on.
+``KVBlockPool`` unit behavior (alloc/free/adopt/match, refcounts, LRU
+eviction, registry metrics; model-free) rides along at the top — it is
+the host-side allocator and prefix trie everything above leans on.
 """
 
 import asyncio
 
+import jax
 import numpy as np
 import pytest
 
@@ -131,6 +134,82 @@ def test_block_pool_version_moves_on_free_and_adopt():
     ids = pool.alloc(1)
     pool.adopt([1, 2], ids, 0)
     assert pool.version > v1
+
+
+BT = 4  # block tokens of the trie tests below
+
+
+def _cache_chain(pool, tokens):
+    """What a finished slot does: take a private row for every complete
+    block of ``tokens`` and adopt the chain into the trie. Returns the
+    blocks adopted (0 when the pool cannot give the rows)."""
+    ids = pool.alloc(len(tokens) // pool.block_tokens)
+    return 0 if ids is None else pool.adopt(tokens, ids, 0)
+
+
+def test_block_pool_match_caps_below_the_whole_prompt():
+    pool = KVBlockPool(4, BT)
+    prompt = list(range(10))  # 2 complete blocks + ragged tail
+    assert _cache_chain(pool, prompt) == 2
+    m = pool.match(prompt)
+    assert m.matched_tokens == 2 * BT and len(m.ids) == 2
+    pool.release(m)
+    # A prompt that IS exactly the cached blocks never fully matches:
+    # prefill needs >= 1 uncached token to produce the first logits.
+    m = pool.match(prompt[:8])
+    assert m.matched_tokens == BT
+    pool.release(m)
+    # Diverging after one block matches only the shared block.
+    m = pool.match(prompt[:4] + [99, 98, 97, 96, 95])
+    assert m.matched_tokens == BT
+    pool.release(m)
+    assert pool.probe(prompt) == 2 * BT  # probe agrees, no pinning
+    s = pool.stats()
+    assert s["lookups"] == 3 and s["hit_requests"] == 3
+    assert s["blocks_used"] == 2
+
+
+def test_block_pool_refcount_blocks_eviction_until_release():
+    pool = KVBlockPool(2, BT)
+    a, b = [1] * 9, [2] * 9  # 2 complete blocks each: the whole pool
+    assert _cache_chain(pool, a) == 2
+    m = pool.match(a)  # pins both blocks
+    assert _cache_chain(pool, b) == 0  # everything pinned
+    assert pool.stats()["evicted_blocks"] == 0
+    pool.release(m)
+    assert _cache_chain(pool, b) == 2  # LRU-evicts a's chain
+    assert pool.stats()["evicted_blocks"] == 2
+    assert pool.probe(a) == 0 and pool.probe(b) == 2 * BT
+    assert pool.blocks_used == 2  # never exceeds the capacity
+
+
+def test_block_pool_lru_prefers_least_recently_used_leaf():
+    pool = KVBlockPool(2, BT)
+    a, b = [1] * 5, [2] * 5  # one block each
+    _cache_chain(pool, a)
+    _cache_chain(pool, b)
+    pool.release(pool.match([1] * 5))  # touch a: b becomes the LRU leaf
+    _cache_chain(pool, [3] * 5)
+    assert pool.probe([1] * 4 + [0]) == BT  # a survived
+    assert pool.probe([2] * 4 + [0]) == 0  # b evicted
+    assert pool.probe([3] * 4 + [0]) == BT
+
+
+def test_block_pool_registry_metrics_published():
+    from distkeras_tpu.telemetry import MetricsRegistry
+
+    reg = MetricsRegistry()
+    pool = KVBlockPool(2, BT, registry=reg)
+    _cache_chain(pool, [1] * 9)
+    pool.release(pool.match([1] * 9))
+    snap = reg.snapshot()
+    assert snap["prefix_cache_blocks_capacity"]["value"] == 2
+    assert snap["prefix_cache_blocks_used"]["value"] == 2
+    assert snap["prefix_cache_hit_tokens_total"]["value"] == 2 * BT
+    assert snap["prefix_cache_inserted_blocks_total"]["value"] == 2
+    assert snap["prefix_cache_lookups_total"]["value"] == 1
+    assert snap["kv_pool_blocks_total"]["value"] == 2
+    assert snap["kv_pool_blocks_free"]["value"] == 0
 
 
 def _pool_with_two_cached_chains():
@@ -251,8 +330,6 @@ def test_paged_ops_match_dense_attention_over_the_same_kv(S, head_groups):
 
 
 def test_engine_pool_leaves_fold_heads_into_the_minor_dimension(lm):
-    import jax
-
     model, variables = lm
     engine = ServingEngine(model, variables, slots=2, kv_pool_blocks=12,
                            kv_block_tokens=4)
@@ -262,14 +339,72 @@ def test_engine_pool_leaves_fold_heads_into_the_minor_dimension(lm):
     assert {l.shape for l in leaves} == {(12, 4, cfg.hidden_size)}
 
 
-# -- paged engine: parity + the compile invariant ----------------------------
+# -- the pool's size ----------------------------------------------------------
 
-def test_paged_parity_vs_generate_and_dense_with_armed_auditor(lm, rng):
-    """THE tentpole invariant: paged greedy output is token-identical to
-    offline generate() and to the dense-cache engine across staggered
-    admissions into freed slots — and the ARMED auditor proves the
-    decode step compiled exactly once while block tables changed under
-    it every admission."""
+def test_pool_capacity_from_a_byte_budget(lm):
+    model, variables = lm
+    one = ServingEngine(model, variables, slots=1, kv_pool_blocks=1,
+                        kv_block_tokens=4)
+    per_block = one.kv_pool.bytes_per_block
+    # K and V of 4 tokens through every layer, as the pool stores them.
+    assert per_block == sum(
+        l.nbytes for l in jax.tree.leaves(one._cache) if l.ndim > 1)
+    engine = ServingEngine(model, variables, slots=1, kv_block_tokens=4,
+                           kv_pool_mb=(3 * per_block + 1) / 2**20)
+    assert engine.kv_pool.capacity == 3
+    with pytest.raises(ValueError, match="holds zero"):
+        ServingEngine(model, variables, slots=1, kv_block_tokens=4,
+                      kv_pool_mb=7 / 2**20)
+    with pytest.raises(ValueError, match="capacity"):
+        KVBlockPool(0, 4)
+
+
+def test_engine_given_no_budget_holds_a_whole_context_for_every_slot(
+        lm, rng):
+    """With neither ``kv_pool_mb`` nor ``kv_pool_blocks`` the pool is
+    ``slots x ceil(limit / kv_block_tokens)`` blocks: every slot serves
+    its full context at once, nothing is preempted, and the streams are
+    generate()'s."""
+    model, variables = lm  # trained context 32
+    engine = ServingEngine(model, variables, slots=3, max_queue=8,
+                           kv_block_tokens=5)
+    assert engine.kv_pool.capacity == 3 * 7  # ceil(32 / 5) a slot
+    capped = ServingEngine(model, variables, slots=2, max_context=16,
+                           kv_block_tokens=4)
+    assert capped.kv_pool.capacity == 2 * 4
+    prompts = [_prompt(rng, n) for n in (20, 7, 12, 9)]
+    new = [32 - len(p) for p in prompts]  # each to the end of its context
+
+    async def work():
+        reqs = [engine.submit(p, n) for p, n in zip(prompts, new)]
+        return [await r.result() for r in reqs]
+
+    outs = asyncio.run(_run_engine(engine, work()))
+    assert outs == [_want(lm, p, n) for p, n in zip(prompts, new)]
+    assert engine.metrics.preemptions == 0
+    assert engine.metrics.oom_rejections == 0
+    assert 0 < engine.kv_pool.peak_blocks_used <= engine.kv_pool.capacity
+    assert engine.decode_compile_count() in (1, -1)
+
+
+@pytest.mark.parametrize("removed", ["prefix_cache_mb", "prefix_block_tokens",
+                                     "prefix_cache", "paged"])
+def test_engine_refuses_the_removed_layout_options(lm, removed):
+    """A stale deployment script fails at start, not silently: the
+    options that selected or sized the dense cache are gone."""
+    model, variables = lm
+    with pytest.raises(TypeError, match=removed):
+        ServingEngine(model, variables, slots=1, **{removed: 1})
+
+
+# -- parity + the compile invariant -------------------------------------------
+
+def test_parity_vs_generate_with_armed_auditor(lm, rng):
+    """THE tentpole invariant: greedy output is token-identical to
+    offline generate(), from a budgeted pool and from the pool an
+    engine derives for itself, across staggered admissions into freed
+    slots — and the ARMED auditor proves the decode step compiled
+    exactly once while block tables changed under it every admission."""
     from distkeras_tpu.telemetry import RecompileAuditor
 
     model, variables = lm
@@ -277,7 +412,7 @@ def test_paged_parity_vs_generate_and_dense_with_armed_auditor(lm, rng):
     paged = ServingEngine(model, variables, slots=2, max_queue=8,
                           kv_pool_blocks=64, kv_block_tokens=4,
                           auditor=auditor, arm_auditor_after_warmup=True)
-    dense = ServingEngine(model, variables, slots=2, max_queue=8)
+    derived = ServingEngine(model, variables, slots=2, max_queue=8)
     prompts = [_prompt(rng, n) for n in (5, 9, 3, 7, 4)]
 
     async def work(engine):
@@ -288,10 +423,10 @@ def test_paged_parity_vs_generate_and_dense_with_armed_auditor(lm, rng):
         return [await r.result() for r in reqs]
 
     got_paged = asyncio.run(_run_engine(paged, work(paged)))
-    got_dense = asyncio.run(_run_engine(dense, work(dense)))
+    got_derived = asyncio.run(_run_engine(derived, work(derived)))
     want = [_want(lm, p, 6) for p in prompts]
     assert got_paged == want
-    assert got_dense == want
+    assert got_derived == want
     assert auditor.compiles("serving_decode") == 1
     assert auditor.report()["serving_decode"]["armed"]
     assert paged.decode_compile_count() in (1, -1)
@@ -301,14 +436,17 @@ def test_paged_parity_vs_generate_and_dense_with_armed_auditor(lm, rng):
     assert all((t == paged._sentinel).all() for t in paged._tables)
 
 
-def test_paged_prefix_hits_are_zero_copy_and_parity_exact(lm, rng):
-    """Paged prefix caching is inherent: repeated prompt prefixes match
-    the blocks ADOPTED from earlier slots (no store copy ever ran) and
-    the hit's output stays token-identical, chunked admission included."""
+@pytest.mark.parametrize("prefill_chunk", [4, None])
+def test_paged_prefix_hits_are_zero_copy_and_parity_exact(lm, rng,
+                                                          prefill_chunk):
+    """Prefix caching is inherent: repeated prompt prefixes match the
+    blocks ADOPTED from earlier slots (no store copy ever ran) and the
+    hit's output stays token-identical to generate(), under chunked and
+    under monolithic admission."""
     model, variables = lm
     engine = ServingEngine(model, variables, slots=1, max_queue=16,
                            kv_pool_blocks=32, kv_block_tokens=4,
-                           prefill_chunk=4)
+                           prefill_chunk=prefill_chunk)
     shared = _prompt(rng, 12)
     prompts = [shared + _prompt(rng, k) for k in (3, 4, 5, 3)]
 
@@ -324,9 +462,10 @@ def test_paged_prefix_hits_are_zero_copy_and_parity_exact(lm, rng):
     assert s["hit_requests"] >= 3  # every repeat matched the prefix
     assert s["hit_tokens"] >= 3 * 12
     # Zero-copy: blocks entered the trie by adoption, not a device store
-    # (the paged engine has no store program at all).
+    # (the engine has no store program at all).
     assert s["inserted_blocks"] > 0
     assert engine.decode_compile_count() in (1, -1)
+    assert engine.metrics.summary()["prefix_hit_rate"] > 0.4
 
 
 def test_paged_hit_after_evict_round_trip(lm, rng):
@@ -424,22 +563,24 @@ def test_oversubscribed_sequential_load_never_wedges(lm, rng):
 
 # -- long-context admission + typed OOM --------------------------------------
 
-def test_paged_serves_context_beyond_dense_padded_max(lm, rng):
-    """The capacity headline in miniature: at the SAME byte budget a
-    dense engine must shrink its padded per-slot max (max_context) to
-    afford its slots, rejecting longer requests up front — the paged
-    engine chains blocks on demand and serves the same request to
-    completion, token-identically."""
+def test_paged_serves_context_beyond_a_whole_context_per_slot(lm, rng):
+    """The capacity headline in miniature: at the SAME byte budget an
+    engine that holds a whole context for every slot must cap that
+    context (max_context) to afford its slots, rejecting longer requests
+    up front — the budgeted pool chains blocks on demand and serves the
+    same request to completion, token-identically."""
     model, variables = lm
-    # Dense at this budget: 2 slots x 16-position rows. 8 blocks x 4
-    # tokens is the same 32 positions' worth of KV bytes.
-    dense = ServingEngine(model, variables, slots=2, max_context=16)
+    # A whole context a slot at this budget: 2 slots x 16 positions.
+    # 8 blocks x 4 tokens is the same 32 positions' worth of KV bytes.
+    capped = ServingEngine(model, variables, slots=2, max_context=16,
+                           kv_block_tokens=4)
     paged = ServingEngine(model, variables, slots=2,
                           kv_pool_blocks=8, kv_block_tokens=4)
-    long_prompt = _prompt(rng, 20)  # + 6 new = 26 > dense's padded 16
+    assert capped.kv_pool.capacity == paged.kv_pool.capacity
+    long_prompt = _prompt(rng, 20)  # + 6 new = 26 > the capped 16
 
     with pytest.raises(ValueError, match="context cap"):
-        dense.submit(long_prompt, 6)
+        capped.submit(long_prompt, 6)
 
     async def drive():
         return await paged.submit(long_prompt, 6).result()
@@ -448,34 +589,43 @@ def test_paged_serves_context_beyond_dense_padded_max(lm, rng):
     assert got == _want(lm, long_prompt, 6)
 
 
-def test_paged_prefill_bucket_never_overshoots_trained_context(lm, rng):
-    """Regression: with a block size that does NOT divide the context
-    (table reach rounds UP past max_seq_len) a prefix hit near the
-    trained limit used to let the tail chunk's pad width overshoot the
-    positional table — the positional dynamic_slice then clamps
-    BACKWARD and embeds the chunk's real tokens at wrong positions.
-    The pad-width bound must be the context limit, not the table
-    reach."""
-    # seq 64 == the positional table's full length (no slack), and 12
-    # does not divide it: the table reach rounds up to 72 > 64.
+@pytest.mark.parametrize("block_tokens,prefix,tails,new,chunk,min_hit", [
+    # The block size does NOT divide the context (the table reach
+    # rounds up to 72 > 64): the second run of the same 61 tokens hits
+    # 60 of them and the tail chunk prefills 1 token at pos 60, padded
+    # past the end of the trained context.
+    (12, 61, (0, 0), 3, None, 60),
+    # Matched 8 + tail 41 -> bucket 64 > the 56 positions of room left,
+    # monolithic and as a chunk's ragged remainder.
+    (8, 8, (2, 41), 4, None, 8),
+    (8, 8, (2, 41), 4, 48, 8),
+])
+def test_paged_prefill_bucket_never_overshoots_trained_context(
+        rng, block_tokens, prefix, tails, new, chunk, min_hit):
+    """Regression: with max_seq_len == trained length (no slack in the
+    positional table) a prefix hit's tail chunk must be padded no wider
+    than the context room that is left — an overshooting pad width makes
+    the positional dynamic_slice clamp BACKWARD and embeds the chunk's
+    real tokens at wrong positions. The pad-width bound must be the
+    context limit, not the table reach."""
     model = gpt_tiny(seq_len=64, vocab_size=VOCAB)
     variables = model.init(0)
     engine = ServingEngine(model, variables, slots=1, max_queue=8,
-                           kv_pool_blocks=16, kv_block_tokens=12)
-    prompt = _prompt(rng, 61)  # + 3 new = the full trained context
+                           kv_pool_blocks=16, kv_block_tokens=block_tokens,
+                           prefill_chunk=chunk)
+    pre = _prompt(rng, prefix)
+    # The first prompt caches the prefix, the second one hits it.
+    first, second = (pre + _prompt(rng, k) for k in tails)
 
     async def drive():
-        outs = []
-        for _ in range(2):  # second run hits 60 cached tokens: the
-            # tail chunk prefills 1 token at pos 60, padded past it
-            outs.append(await engine.submit(prompt, 3).result())
-        return outs
+        return [await engine.submit(p, new).result()
+                for p in (first, second)]
 
     outs = asyncio.run(_run_engine(engine, drive()))
-    want = generate(model, variables, np.asarray([prompt], np.int32), 3,
+    want = generate(model, variables, np.asarray([second], np.int32), new,
                     greedy=True)[0].tolist()
-    assert outs == [want, want], "positional clamp corrupted the hit"
-    assert engine.kv_pool.stats()["hit_tokens"] >= 60
+    assert outs[1] == want, "positional clamp corrupted the hit"
+    assert engine.kv_pool.stats()["hit_tokens"] >= min_hit
 
 
 def test_pool_exhausted_is_typed_and_counted(lm, rng):

@@ -6,9 +6,9 @@ different forced count — tests read ``len(jax.devices())``, never
 assume 8):
 
 - a ``tp=1,pp=2`` engine is **token-identical** to the unsharded
-  ``generate()`` reference on greedy decode — dense, paged
-  (preempt/resume included), chunked + prefix-cached, and speculative
-  modes;
+  ``generate()`` reference on greedy decode — from the pool the engine
+  derives and from a tight one (preempt/resume included), chunked +
+  prefix-cached, and speculative modes;
 - **compile-once survives the stage split**: every per-stage callable
   stays at exactly one executable under an armed ``RecompileAuditor``;
 - per-stage placement is REAL: stage s's params and KV leaves live only
@@ -143,19 +143,18 @@ def test_engine_rejects_bad_depth_and_unsplittable_model(lm, pp2):
                       pipeline_depth=2)
 
 
-# -- parity: dense / paged / chunked+cached / speculative ---------------------
+# -- parity: derived pool / tight pool / chunked+cached / speculative ----------
 
-def test_pp_dense_greedy_parity_compile_once_per_stage(lm, pp2, rng):
+def test_pp_greedy_parity_compile_once_per_stage(lm, pp2, rng):
     model, variables = lm
     auditor = RecompileAuditor()
     engine = ServingEngine(model, variables, slots=4, max_queue=16,
                            mesh=pp2, auditor=auditor,
                            arm_auditor_after_warmup=True)
-    # Per-stage placement of params AND the dense KV cache (per
-    # micro-batch cache trees all stage-local).
+    # Per-stage placement of params AND the KV pool the engine sized
+    # for itself (a whole context for every slot).
     _assert_stage_placement(engine, engine._params)
-    for mb_caches in zip(*engine._cache):
-        _assert_stage_placement(engine, list(mb_caches))
+    _assert_stage_placement(engine, engine._cache)
     prompts = [_prompt(rng, n) for n in (3, 5, 8, 13, 6, 4, 9, 7)]
 
     async def work():
@@ -194,18 +193,18 @@ def test_pp_paged_preempt_resume_parity(lm, pp2, rng):
 
 
 def test_pp_prefix_cache_chunked_parity(lm, pp2, rng):
-    """pp engine with the device prefix cache AND chunked prefill: hits
-    splice per-stage pool rows, tails chunk through the staged prefill
-    — output still token-identical, one trie spanning all stages."""
+    """pp engine with prefix hits AND chunked prefill: a hit points the
+    slot's table at shared rows of every stage's pool, tails chunk
+    through the staged prefill — output still token-identical, one trie
+    spanning all stages."""
     model, variables = lm
     auditor = RecompileAuditor()
     engine = ServingEngine(model, variables, slots=2, max_queue=16,
-                           mesh=pp2, prefix_cache_mb=4.0,
-                           prefix_block_tokens=8, prefill_chunk=8,
+                           mesh=pp2, kv_block_tokens=8, prefill_chunk=8,
                            auditor=auditor,
                            arm_auditor_after_warmup=True)
-    # The prefix cache's pool is per-stage, each stage-local.
-    _assert_stage_placement(engine, engine.prefix_cache._pool)
+    # The pool is per-stage, each stage-local.
+    _assert_stage_placement(engine, engine._cache)
     shared = _prompt(rng, 16)
     prompts = [shared + _prompt(rng, 4) for _ in range(4)]
 
@@ -217,7 +216,7 @@ def test_pp_prefix_cache_chunked_parity(lm, pp2, rng):
 
     outs = asyncio.run(_run_engine(engine, work()))
     assert outs == [_want(lm, p, 6) for p in prompts]
-    assert engine.prefix_cache.hit_tokens > 0, "no prefix hit exercised"
+    assert engine.kv_pool.hit_tokens > 0, "no prefix hit exercised"
     assert _stage_compiles(auditor, 2) == [1, 1]
 
 

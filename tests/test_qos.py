@@ -160,7 +160,7 @@ def test_serving_config_flags_forward_wire_and_quotas():
     from distkeras_tpu.run import _parse_tenant_rates, _serving_config_flags
 
     args = argparse.Namespace(
-        prefix_cache_mb=0.0, prefix_block=16, top_k=None,
+        top_k=None,
         prefill_chunk=None, paged=False, kv_pool_mb=0.0,
         kv_block_tokens=16, max_context=None, draft_model=None,
         wire="bin1", tenant_quota=["acme=100", "free=10"],

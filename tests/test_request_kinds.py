@@ -289,9 +289,15 @@ def test_submit_validation_rejects_contradictions_typed(lm):
         # Mask hook not compiled in: reject up front, not mid-stream.
         eng.submit([1, 2], 4,
                    constraint={"start": 0, "edges": [[0, 1, 0]]})
-    dense = ServingEngine(model, variables, slots=2)
-    with pytest.raises(ValueError):
-        dense.submit([1, 2], 0, kind="score")  # kinds need paging
+    import jax
+
+    from distkeras_tpu.parallel.mesh import serving_mesh
+
+    staged = ServingEngine(
+        model, variables, slots=2,
+        mesh=serving_mesh({"tp": 1, "pp": 2}, devices=jax.devices()[:2]))
+    with pytest.raises(ValueError, match="single-stage"):
+        staged.submit([1, 2], 0, kind="score")  # kinds need pp=1
 
 
 def test_fork_parity_and_exact_cow_accounting(lm, rng):

@@ -157,7 +157,7 @@ def test_engine_timeline_debugz_and_auditor_silence(lm, rng, artifact_dir):
         64, dump_path=str(artifact_dir / "flight-single.json"), source="e0")
     engine = ServingEngine(
         model, variables, slots=2, max_queue=8,
-        prefix_cache_mb=1.0, prefix_block_tokens=4, prefill_chunk=4,
+        kv_block_tokens=4, prefill_chunk=4,
         auditor=RecompileAuditor(), arm_auditor_after_warmup=True,
         trace_store=store, flight_recorder=recorder,
         slo_s=1e-9)  # everything violates: exercises the slow ring
@@ -202,8 +202,8 @@ def test_engine_timeline_debugz_and_auditor_silence(lm, rng, artifact_dir):
     # debugz: slot/queue tables, prefix-cache families, recorder stats.
     assert [s["state"] for s in dz["slots"]] == ["free", "free"]
     assert dz["queue"]["depth"] == 0
-    assert dz["prefix_cache"]["families"] >= 1
-    fam = dz["prefix_cache"]["top_families"][0]
+    assert dz["kv_pool"]["families"] >= 1
+    fam = dz["kv_pool"]["top_families"][0]
     assert fam["blocks"] >= 1 and fam["tokens"] >= 4
     assert dz["flight_recorder"]["timelines_recorded"] == 2
     assert dz["slo_s"] == 1e-9

@@ -137,7 +137,7 @@ def test_continuous_batching_parity_and_single_compile(lm, rng):
     # 5 requests through 2 slots admitted mid-decode: still ONE compiled
     # decode executable (continuous batching never retraces). -1 means
     # the probe's private jax attribute vanished in an upgrade — tolerate
-    # it (same contract as serving_bench) rather than false-failing.
+    # it rather than false-failing.
     assert engine.decode_compile_count() in (1, -1)
     # Slot bookkeeping: everything freed after the drain.
     assert engine.active_slots == 0
@@ -324,7 +324,7 @@ def test_metrics_summary_and_stream(lm, rng, tmp_path):
     assert any("queue_depth" in rec for rec in lines)
 
 
-# -- chunked prefill + prefix cache ------------------------------------------
+# -- chunked prefill ----------------------------------------------------------
 
 def test_chunked_prefill_parity_and_ttft_split(lm, rng):
     """Chunked admission (one prefill chunk per decode tick) is greedy
@@ -360,99 +360,6 @@ def test_chunked_prefill_parity_and_ttft_split(lm, rng):
     snap = engine.metrics.registry.snapshot()
     assert snap["serving_prefill_device_seconds"]["count"] == len(prompts)
     assert snap["serving_queue_wait_seconds"]["count"] == len(prompts)
-
-
-def test_prefix_cache_hits_are_parity_exact_vs_monolithic_and_generate(
-        lm, rng):
-    """THE satellite invariant: chunked + prefix-cached admission is
-    token-identical to monolithic prefill and to offline generate(),
-    and repeat prompts actually hit (matched tokens recorded)."""
-    model, variables = lm
-    cached = ServingEngine(model, variables, slots=1, max_queue=16,
-                           prefill_chunk=4, prefix_cache_mb=1.0,
-                           prefix_block_tokens=4)
-    plain = ServingEngine(model, variables, slots=1, max_queue=16)
-    shared = _prompt(rng, 12)
-    prompts = [shared + _prompt(rng, k) for k in (3, 4, 5, 3)]
-
-    async def drive(engine):
-        outs = []
-        for p in prompts:  # sequential: later prompts can hit earlier ones
-            outs.append(await engine.submit(p, 5).result())
-        return outs
-
-    got_cached = asyncio.run(_run_engine(cached, drive(cached)))
-    got_plain = asyncio.run(_run_engine(plain, drive(plain)))
-    want = [_want(lm, p, 5) for p in prompts]
-    assert got_cached == want  # vs offline generate()
-    assert got_plain == want  # monolithic == chunked+cached == generate
-    stats = cached.prefix_cache.stats()
-    assert stats["hit_requests"] >= 3  # every repeat matched the prefix
-    assert stats["hit_tokens"] >= 3 * 12
-    assert cached.decode_compile_count() in (1, -1)
-    assert cached.metrics.summary()["prefix_hit_rate"] > 0.4
-
-
-def test_prefix_cache_hit_after_evict_round_trip(lm, rng):
-    """Evicting a cached prefix must only cost performance, never
-    correctness: A cached -> A evicted by B (tiny budget) -> A re-prefilled
-    from scratch and re-cached -> A hits again; parity holds throughout."""
-    from distkeras_tpu.serving import PrefixCache
-
-    model, variables = lm
-    probe_engine = ServingEngine(model, variables, slots=1)
-    probe = PrefixCache(probe_engine._row_shapes, block_tokens=4,
-                        budget_bytes=1 << 20)
-    pc = PrefixCache(probe_engine._row_shapes, block_tokens=4,
-                     budget_bytes=2 * probe.bytes_per_block)  # 2 blocks
-    engine = ServingEngine(model, variables, slots=1, max_queue=16,
-                           prefix_cache=pc)
-    a, b = _prompt(rng, 11), _prompt(rng, 11)
-
-    async def drive():
-        outs = []
-        for p in (a, a, b, a, a):  # hit, evict via b, miss, re-hit
-            outs.append(await engine.submit(p, 4).result())
-        return outs
-
-    outs = asyncio.run(_run_engine(engine, drive()))
-    wa, wb = _want(lm, a, 4), _want(lm, b, 4)
-    assert outs == [wa, wa, wb, wa, wa]
-    s = pc.stats()
-    assert s["evicted_blocks"] > 0  # b really displaced a
-    assert s["hit_requests"] >= 2  # the 2nd a (pre-evict) + 5th (post)
-    assert s["blocks_used"] <= 2  # budget held
-
-
-def test_prefill_bucket_never_overshoots_headroom_free_cache(rng):
-    """Regression: with max_seq_len == trained length (no accidental
-    cache headroom) a hit's tail bucket must be capped at the remaining
-    cache room — an overshooting pad width would make the per-slot KV
-    write clamp backward over the spliced prefix and silently corrupt
-    output. Covers both monolithic and chunked ragged-final-chunk
-    paths."""
-    model = gpt_tiny(seq_len=64, vocab_size=VOCAB)
-    variables = model.init(0)
-    pre = _prompt(rng, 8)
-    long_tail = pre + _prompt(rng, 41)  # matched 8 + tail 41 -> bucket 64
-
-    for kwargs in ({}, {"prefill_chunk": 48}):
-        engine = ServingEngine(model, variables, slots=1, max_queue=8,
-                               prefix_cache_mb=1.0, prefix_block_tokens=8,
-                               **kwargs)
-
-        async def drive():
-            outs = []
-            for p in (pre + _prompt(rng, 2), long_tail):  # cache, then hit
-                outs.append(await engine.submit(p, 4).result())
-            return outs
-
-        outs = asyncio.run(_run_engine(engine, drive()))
-        assert engine.prefix_cache.stats()["hit_tokens"] >= 8
-        want = generate(model, variables,
-                        np.asarray([long_tail], np.int32), 4,
-                        greedy=True)[0].tolist()
-        assert outs[1] == want, f"corrupted hit output with {kwargs}"
 
 
 def test_scheduler_cache_aware_pop_prefers_hits_within_class():

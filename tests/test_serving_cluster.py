@@ -454,13 +454,13 @@ def test_reload_rejects_mismatched_weights_and_keeps_serving(lm, rng,
 
 def test_engine_param_swap_flushes_prefix_cache_and_is_exact(lm, rng):
     """request_param_swap alone (no cluster): post-swap greedy output is
-    token-identical to generate() under the new params, the prefix cache
-    is flushed (old-weight K/V must never splice again), and a
+    token-identical to generate() under the new params, the pool's trie
+    is flushed (old-weight K/V must never be shared again), and a
     mismatched tree raises before touching engine state."""
     model, variables = lm
     new_vars = model.init(2)
     engine = ServingEngine(model, variables, slots=1, max_queue=8,
-                           prefix_cache_mb=1.0, prefix_block_tokens=4)
+                           kv_block_tokens=4)
     shared = _prompt(rng, 9)
     p1, p2 = shared + _prompt(rng, 2), shared + _prompt(rng, 3)
 
@@ -468,12 +468,12 @@ def test_engine_param_swap_flushes_prefix_cache_and_is_exact(lm, rng):
         task = asyncio.create_task(engine.run())
         try:
             out_old = await engine.submit(p1, 4).result()
-            assert engine.prefix_cache.blocks_used > 0
+            assert engine.kv_pool.blocks_used > 0
             event, result = engine.request_param_swap(new_vars)
             await asyncio.wait_for(event.wait(), 30)
             assert "error" not in result
-            assert engine.prefix_cache.blocks_used == 0  # flushed
-            assert engine.prefix_cache.stats()["flushes"] == 1
+            assert engine.kv_pool.blocks_used == 0  # flushed
+            assert engine.kv_pool.stats()["flushes"] == 1
             out_new1 = await engine.submit(p1, 4).result()
             out_new2 = await engine.submit(p2, 4).result()
             return out_old, out_new1, out_new2
@@ -486,7 +486,7 @@ def test_engine_param_swap_flushes_prefix_cache_and_is_exact(lm, rng):
     assert out_new1 == _want(lm, p1, 4, variables=new_vars)
     # Re-cached under the NEW weights, the second hit is still exact.
     assert out_new2 == _want(lm, p2, 4, variables=new_vars)
-    assert engine.prefix_cache.stats()["hit_requests"] >= 1
+    assert engine.kv_pool.stats()["hit_requests"] >= 1
     assert engine.decode_compile_count() in (1, -1)
 
     with pytest.raises(ValueError, match="leaf|leaves"):

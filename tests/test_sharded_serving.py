@@ -5,7 +5,7 @@ virtual CPU devices (the CI variant re-runs this file at a different
 forced count — tests read ``len(jax.devices())``, never assume 8):
 
 - a ``serving_mesh`` engine is **token-identical** to the unsharded
-  ``generate()`` reference on greedy decode — dense, paged
+  ``generate()`` reference on greedy decode — derived pool, tight pool
   (preempt/resume included), chunked + prefix-cached, and speculative
   modes — at tp=2 and tp=4;
 - **compile-once survives the mesh**: every callable (decode, draft,
@@ -129,15 +129,16 @@ def test_engine_rejects_unshardable_configs(lm, mesh2):
             ServingEngine(model, variables, slots=2, mesh=dp_mesh)
 
 
-# -- parity: dense / paged / chunked+cached / speculative ---------------------
+# -- parity: derived pool / tight pool / chunked+cached / speculative ----------
 
-def test_sharded_dense_greedy_parity_compile_once(lm, mesh2, rng):
+def test_sharded_greedy_parity_compile_once(lm, mesh2, rng):
     model, variables = lm
     auditor = RecompileAuditor()
     engine = ServingEngine(model, variables, slots=4, max_queue=16,
                            mesh=mesh2, auditor=auditor,
                            arm_auditor_after_warmup=True)
-    # Params and KV really sharded; sampling state replicated.
+    # Params and the KV pool the engine sized for itself really
+    # sharded; sampling state replicated.
     assert any("'tp'" in s for s in _tp_specs(engine._params))
     assert any("'tp'" in s for s in _tp_specs(engine._cache))
     assert _tp_specs(engine._tokens) == {"PartitionSpec()"}
@@ -155,21 +156,20 @@ def test_sharded_dense_greedy_parity_compile_once(lm, mesh2, rng):
 
 
 def test_sharded_prefix_cache_chunked_parity(lm, mesh2, rng):
-    """Dense sharded engine with the device prefix cache AND chunked
-    prefill: hits splice head-sharded pool rows, tails chunk through
-    the sharded prefill — output still token-identical."""
+    """Sharded engine with prefix hits AND chunked prefill: a hit points
+    the slot's table at shared head-sharded pool rows, tails chunk
+    through the sharded prefill — output still token-identical."""
     model, variables = lm
     auditor = RecompileAuditor()
     engine = ServingEngine(model, variables, slots=2, max_queue=16,
-                           mesh=mesh2, prefix_cache_mb=4.0,
-                           prefix_block_tokens=8, prefill_chunk=8,
+                           mesh=mesh2, kv_block_tokens=8, prefill_chunk=8,
                            auditor=auditor, arm_auditor_after_warmup=True)
-    assert any("'tp'" in s for s in _tp_specs(engine.prefix_cache._pool))
+    assert any("'tp'" in s for s in _tp_specs(engine._cache))
     shared = _prompt(rng, 16)
     prompts = [shared + _prompt(rng, 4) for _ in range(4)]
 
     async def work():
-        # Sequential: the 2nd+ requests hit the 1st's inserted blocks.
+        # Sequential: the 2nd+ requests hit the 1st's adopted blocks.
         outs = []
         for p in prompts:
             outs.append(await engine.submit(p, 6).result())
@@ -177,7 +177,7 @@ def test_sharded_prefix_cache_chunked_parity(lm, mesh2, rng):
 
     outs = asyncio.run(_run_engine(engine, work()))
     assert outs == [_want(lm, p, 6) for p in prompts]
-    assert engine.prefix_cache.hit_tokens > 0, "no prefix hit exercised"
+    assert engine.kv_pool.hit_tokens > 0, "no prefix hit exercised"
     assert auditor.compiles("serving_decode") == 1
 
 

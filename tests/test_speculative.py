@@ -8,7 +8,7 @@ ticks, and rollback dominate):
 
 - greedy streams are token-identical to one-shot ``generate()`` AND to
   a non-speculating engine, across plain, mixed-temperature, and
-  shared-prefix batches, dense and paged, including requests that use
+  shared-prefix batches, from a derived and from a tight pool, including requests that use
   the whole trained context (verify-window overhang);
 - the armed ``RecompileAuditor`` stays silent: draft, verify, and the
   one-token fallback decode each compile exactly once, no matter how
@@ -186,7 +186,7 @@ def test_spec_shared_prefix_chunked_parity(lm, rng):
     """Speculation composes with chunked prefill + the prefix cache:
     shared-prefix batches stay parity-exact and still hit."""
     engine = _spec_engine(lm, slots=2, max_queue=16, prefill_chunk=4,
-                          prefix_cache_mb=1.0, prefix_block_tokens=4)
+                          kv_block_tokens=4)
     shared = _prompt(rng, 12)
     prompts = [shared + _prompt(rng, k) for k in (3, 4, 5, 3)]
 
@@ -198,7 +198,7 @@ def test_spec_shared_prefix_chunked_parity(lm, rng):
 
     outs = asyncio.run(_run_engine(engine, drive()))
     assert outs == [_want(lm, p, 5) for p in prompts]
-    assert engine.prefix_cache.stats()["hit_requests"] >= 3
+    assert engine.kv_pool.stats()["hit_requests"] >= 3
     assert engine.metrics.summary()["spec_accept_rate"] == 1.0
 
 
