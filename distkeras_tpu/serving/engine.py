@@ -17,12 +17,14 @@ while making the batch *open*:
   decode iterations by a **prefill** program (compiled once per
   power-of-two prompt-length bucket) that writes the prompt's K/V
   straight into the request's blocks of the KV pool — the decode step
-  itself never retraces and never stops for admission;
+  itself never retraces and never stops for admission: the prefill is
+  enqueued behind the decode tick in flight and its first token is read
+  at the loop's next harvest (step 4 of :meth:`ServingEngine.run`);
 - with **chunked prefill** (``prefill_chunk``), the prompt is split into
   fixed-size chunks and ONE chunk runs per engine iteration, interleaved
-  with decode ticks — admitting a long prompt never stalls the decode
-  batch for more than one chunk's device time, bounding every in-flight
-  request's inter-token latency;
+  with decode ticks (step 4b) — admitting a long prompt never holds the
+  decode batch back by more than one chunk's device time, bounding every
+  in-flight request's inter-token latency;
 - free rows keep decoding garbage (their output is discarded, and their
   writes are dropped) — the cost of a fixed-shape batch, and exactly the
   trade the training side makes with padded microbatches.
@@ -129,22 +131,41 @@ Pipelined, all of that runs while the device executes the next tick:
   dispatching tick N+1 never invalidates the buffer tick N's harvest
   is still going to read (16 bytes per tick of extra alloc, nothing);
 - a **pipeline barrier** (harvest + stream + teardown of the in-flight
-  tick) runs only at the events that change batch shape or content
-  mid-flight: admission, chunked-prefill progress, paged growth from a
-  DRY pool (it preempts, or evicts with a spill to the host tier),
-  param swap (a swap still waits for zero in-flight ticks), KV
-  transfer, cancel/expire teardown, and engine idle/exit;
-- paged growth the pool can serve from its free list (or by evicting
-  an unreferenced trie leaf with no host tier) is NOT such an event:
-  the block is chained into the host table with the tick in flight,
-  which holds its own device copy of the tables, and the next dispatch
-  re-uploads (step 5c of :meth:`ServingEngine.run`);
+  tick) runs only at the events that need HARVESTED state: an
+  admission or paged growth from a DRY pool (it preempts or parks, or
+  evicts with a spill to the host tier), an admission that re-admits
+  blocks from the host tier, the admission and the chunks of a request
+  whose last chunk is read back on the host at once (``sample``,
+  constrained, ``score``, ``embed``), every admission of an engine
+  with a draft model or pipeline stages, param swap (a swap still
+  waits for zero in-flight ticks), KV transfer, cancel/expire
+  teardown, and engine idle/exit;
+- **an admission is a dispatch** (PR 33), not such an event, whenever
+  the pool serves its reservation from the free list (or by evicting
+  an unreferenced trie leaf with no host tier): programs run on the
+  device in dispatch order; the prefill writes only blocks the
+  reservation just gave this slot, which no live row's table names;
+  the tick in flight holds its own device copy of the masked tables,
+  in which the slot is the sentinel; the admit epilogue does not
+  donate the token vector (the tick's harvest handle); and the next
+  dispatch re-uploads tables and positions. The first token stays a
+  device scalar until the next harvest reads it beside the tick's
+  (:meth:`ServingEngine._resolve_admissions`); a row it finishes is
+  torn down there like any row that finishes with its next tick
+  already in flight. Both routes enter ``serving_prefill`` and
+  ``serving_admit`` through the same call with the same arguments, so
+  a warm-up through an idle engine warms the burst that follows;
+- paged growth the pool can serve the same way is NOT such an event
+  either (PR 30): the block is chained into the host table with the
+  tick in flight, and the next dispatch re-uploads (step 5c);
 - a slot that FINISHES at tick N is detected at N's harvest — after
   N+1 was dispatched, so the in-flight tick ran one speculative row
   for it. Its N+1 output is dropped exactly like a mid-prefill
-  garbage row, and the host watermark advance the dispatch
-  made for it is rolled back before teardown adopts its blocks, so
-  pool accounting never claims the speculative in-flight write;
+  garbage row (a tick remembers WHICH request each row was dispatched
+  for: an admission may have refilled the slot by then), and the host
+  watermark advance the dispatch made for it is rolled back before
+  teardown adopts its blocks, so pool accounting never claims the
+  speculative in-flight write;
 - speculative ticks dispatch asynchronously too, but the NEXT dispatch
   needs their commit counts (host-side position bookkeeping), so a
   spec tick is harvested before anything else is dispatched — spec
@@ -166,7 +187,9 @@ has some row crossing a block boundary on almost every tick; until
 PR 30 each such tick drained, and the overlap was mostly lost to
 ``paged_growth``. ``serving_paged_growth_blocks_total{drained}`` now
 says how many blocks were chained with the tick left in flight
-(``0``) against behind the barrier (``1``: a dry or spilling pool).
+(``0``) against behind the barrier (``1``: a dry or spilling pool),
+and ``serving_admissions_total{drained}`` the same of admissions (the
+``admit`` and ``prefill_tick`` spans carry ``drained`` too).
 """
 
 from __future__ import annotations
@@ -724,17 +747,22 @@ class _PrefillJob:
     device_s: float = 0.0         # prefill device time (TTFT's other half)
 
 
-def _tick_ready(tick) -> bool:
-    """True when every device buffer the tick's harvest will read has
-    already materialized — the harvest is then a plain memcpy, cheaper
-    run inline than through an executor round trip. Conservative on
-    jax versions without ``Array.is_ready`` (False → thread hop)."""
+def _is_ready(x) -> bool:
+    """True when the device buffer has already materialized: reading it
+    is then a plain memcpy, cheaper run inline than through an executor
+    round trip. Conservative on jax versions without ``Array.is_ready``
+    (False → thread hop)."""
     try:
-        if tick.kind == "spec":
-            return bool(tick.out.is_ready() and tick.commit.is_ready())
-        return bool(tick.tokens.is_ready())
+        return bool(x.is_ready())
     except AttributeError:
         return False
+
+
+def _tick_ready(tick) -> bool:
+    """:func:`_is_ready` of every buffer the tick's harvest will read."""
+    if tick.kind == "spec":
+        return _is_ready(tick.out) and _is_ready(tick.commit)
+    return _is_ready(tick.tokens)
 
 
 @dataclasses.dataclass
@@ -743,7 +771,9 @@ class _InflightTick:
     harvest will read, the decodable rows the dispatch covered (the
     stream targets — the slot table may gain or lose entries before the
     harvest, and a row must stream iff it was decodable AT DISPATCH and
-    its slot is still alive), and — plain ticks — the slots whose
+    its slot still holds the same request: ``states`` says which that
+    was, since an admission enqueued with the tick in flight can refill
+    a freed slot before the harvest), and — plain ticks — the slots whose
     host ``_lens`` watermark the dispatch optimistically advanced, so a
     teardown detected mid-flight can roll the advance back before
     adopting blocks. With ``pipeline_depth>1`` on a pp mesh each tick
@@ -752,6 +782,7 @@ class _InflightTick:
 
     kind: str                     # "decode" | "spec"
     rows: tuple                   # decodable slots at dispatch
+    states: tuple                 # their _SlotState, row for row
     t_dispatch: float
     tokens: object = None         # plain: device token vector to harvest
     out: object = None            # spec: device [B, K] committed tokens
@@ -790,6 +821,11 @@ class _SlotState:
     # at (released only at slot teardown — the pin is what stops
     # eviction from reallocating a block the decode step still reads).
     t_admit: float = 0.0
+    # Which route admitted the slot: False when its reservation needed
+    # nothing a harvest settles, so its prefill chunks go out beside the
+    # tick in flight; True when they run behind a pipeline barrier (the
+    # ``drained`` label of ``serving_admissions_total``).
+    drained: bool = True
     blocks: list = dataclasses.field(default_factory=list)
     first_block: int = 0
     match: object | None = None
@@ -820,6 +856,27 @@ class _SlotState:
 # request's prompt completed — the run loop routes it to
 # _finish_scorelike instead of _finish_admission.
 _SCORELIKE_DONE = object()
+
+
+# Returned by _reserve_blocks(in_flight=True) when the reservation needs
+# what only a drained pipeline gives (a victim, a spill, a host-tier
+# re-admission): nothing was touched, the caller drains and asks again.
+_NEEDS_BARRIER = object()
+
+
+@dataclasses.dataclass
+class _FirstTokenPending:
+    """Returned by _prefill_step(defer=True) when the prompt completed:
+    the first token is still a device scalar, sampled by a prefill that
+    was enqueued behind the tick in flight. The loop reads it at its
+    next harvest (:meth:`ServingEngine._resolve_admissions`) and only
+    then streams it and books the prefill's time."""
+    st: "_SlotState"
+    slot: int
+    tok: object                   # device int32 scalar
+    job: _PrefillJob
+    prompt_len: int
+    t_enqueued: float             # when the last chunk's dispatch returned
 
 
 @dataclasses.dataclass
@@ -959,6 +1016,9 @@ class ServingEngine:
         # timeline (the tracez tick lane).
         self._inflight: collections.deque = collections.deque()
         self._tick_log: collections.deque = collections.deque(maxlen=256)
+        # Admissions whose prefill was enqueued without waiting for it
+        # (_FirstTokenPending), read at the next harvest.
+        self._pending_admits: list = []
         # False until the first decode dispatch has run (and therefore
         # compiled): the FIRST dispatch goes through the executor so a
         # multi-second compile cannot freeze the event loop, every later
@@ -1306,8 +1366,8 @@ class ServingEngine:
         # serving/kv_tier.py. The spill hook fires inside
         # KVBlockPool._alloc, which only runs on the engine loop (or
         # the executor while the loop awaits it) and always after a
-        # pipeline barrier (in-flight growth asks the pool's
-        # next_alloc_spills first), so the gather never races a
+        # pipeline barrier (in-flight growth and admission ask
+        # the pool's alloc_spills first), so the gather never races a
         # donated in-flight tick.
         self.kv_tier = None
         if kv_host_tier_mb > 0:
@@ -1405,7 +1465,10 @@ class ServingEngine:
         # dispatched with them as input, so the dispatch must not
         # invalidate the buffer ([slots] int32 — the extra copy per tick
         # is 4 bytes per slot). _temps is NOT donated either (it
-        # persists across iterations). The
+        # persists across iterations). The admit epilogue leaves the
+        # token vector undonated for the same reason: an admission may
+        # be enqueued with a tick in flight, whose harvest handle that
+        # vector is. The
         # prefill's incoming cache (the shared pools) is donated too: a
         # chunk chain threads it through every call, updating in place.
         # Sharded engines jit every callable with EXPLICIT in_shardings/
@@ -1446,7 +1509,7 @@ class ServingEngine:
             self._admit_jit = _sharded_jit(
                 "serving_admit",
                 _paged_admit_fn,
-                (rep, rep, rep, rep, rep), (rep, rep), donate=(0, 1))
+                (rep, rep, rep, rep, rep), (rep, rep), donate=(1,))
             if self._constrained_mode:
                 # The engine's ONE decode executable IS the masked
                 # variant: the mask is a plain [slots, V] operand
@@ -2750,12 +2813,10 @@ class ServingEngine:
             return 0
         bt = self.kv_block_tokens
         toks = [int(t) for t in tokens]
-        # Same last-block holdback as match(): prefill needs >= 1
-        # uncached token, so a block match() won't use is a wasted row.
-        cap = max(0, (len(toks) - 1) // bt)
-        resident = pool.probe(toks) // bt
-        if resident >= cap or not tier.contains(toks[:(resident + 1) * bt]):
+        resident = self._tier_next_block(toks)
+        if resident is None:
             return 0
+        cap = (len(toks) - 1) // bt
         t0 = time.monotonic()
         staged: list[tuple[int, list]] = []  # (pool_row, per-leaf [1,bt,..])
         nbytes = 0
@@ -3049,25 +3110,23 @@ class ServingEngine:
                 # 4. Admission: prefill queued requests into free slots.
                 # Device work runs in the executor; stream/metrics
                 # bookkeeping stays on the loop thread (asyncio queues and
-                # events are not thread-safe).
-                if (not self._stopping and len(self.scheduler)
-                        and self.free_slots
-                        and not (self._parked_at_version
-                                 == self.kv_pool.version
-                                 and self.scheduler.peek()
-                                 is self._parked_req)):
-                    # Admission changes the batch's content (and
-                    # reserves/preempts pool blocks): barrier
-                    # first so the reserve can never race an in-flight
-                    # tick's reads, and so the admit lands on
-                    # harvested token state. The barrier may free MORE
-                    # slots (a finishing tick), which only helps. A
-                    # parked queue head (dry pool, nothing freed since)
-                    # admits nobody — the admission loop below breaks
-                    # on the same check — so it must NOT drain the
-                    # pipeline every iteration: that would pay the full
-                    # host gap per tick for the whole parked period.
-                    await self._pipeline_barrier(loop, "admission")
+                # events are not thread-safe). An admission is a
+                # DISPATCH: its reservation is host bookkeeping, its
+                # prefill writes only blocks the reservation just gave
+                # this slot (no live row's table names them, and the
+                # tick in flight holds its own device copy of the masked
+                # tables, where the slot is the sentinel), programs run
+                # on the device in dispatch order, and the next tick's
+                # dispatch re-uploads tables and positions. So the tick
+                # in flight stays there and the first token is read at
+                # the next harvest (_resolve_admissions). The barrier is
+                # kept for what needs harvested state, decided per
+                # request from what the loop can observe
+                # (_admits_in_flight, and _reserve_blocks(in_flight=True)
+                # for a pool that would preempt, park, spill or re-admit
+                # from the host tier). A parked queue head (dry pool,
+                # nothing freed since) admits nobody and must not drain
+                # the pipeline every iteration: the check comes first.
                 if not self._stopping:
                     while self.free_slots and len(self.scheduler):
                         if (self._parked_at_version
@@ -3092,6 +3151,11 @@ class ServingEngine:
                         req = self.scheduler.pop(time.monotonic())
                         if req is None:
                             break
+                        in_flight = self._admits_in_flight(req)
+                        if not in_flight:
+                            # The barrier may free MORE slots (a
+                            # finishing tick), which only helps.
+                            await self._pipeline_barrier(loop, "admission")
                         if (req.kind == "sample"
                                 and self.free_slots < req.n):
                             # Fork fan-out needs all n slots claimed UP
@@ -3101,7 +3165,12 @@ class ServingEngine:
                             self.scheduler.requeue(req)
                             break
                         slot = self._slot_state.index(None)
-                        reserved = self._reserve_blocks(req, slot)
+                        reserved = self._reserve_blocks(req, slot, in_flight)
+                        if reserved is _NEEDS_BARRIER:
+                            in_flight = False
+                            await self._pipeline_barrier(loop, "admission")
+                            slot = self._slot_state.index(None)
+                            reserved = self._reserve_blocks(req, slot)
                         if reserved is None:
                             # Parked: requeued at its class head,
                             # admission resumes when blocks free.
@@ -3152,7 +3221,8 @@ class ServingEngine:
                         st = _SlotState(
                             req,
                             req.max_new_tokens - len(req.out_tokens),
-                            now_t, t_admit=now_t)
+                            now_t, t_admit=now_t, drained=not in_flight)
+                        self.metrics.record_admission(st.drained)
                         (st.prefill, st.blocks, st.first_block,
                          st.match) = reserved
                         if req.constraint is not None:
@@ -3173,7 +3243,8 @@ class ServingEngine:
                         with span("admit", slot=slot,
                                   trace_id=req.trace_id,
                                   prompt_len=len(req.prompt),
-                                  queue_wait_s=round(wait, 6)):
+                                  queue_wait_s=round(wait, 6),
+                                  drained=int(st.drained)):
                             # (The reservation above already pinned the
                             # prompt's cached prefix and pointed the
                             # slot's table at it: a hit skips that
@@ -3183,11 +3254,16 @@ class ServingEngine:
                                 # tail, admitted inline. Normally ONE
                                 # call; near-context-limit prompts may
                                 # split into a few pow2 sub-chunks (see
-                                # _prefill_step's overshoot guard).
+                                # _prefill_step's overshoot guard). Off
+                                # the barrier route none of them waits
+                                # for its token: with nothing decodable
+                                # (a first wave) everything queued is
+                                # enqueued in this one iteration.
                                 tok0 = None
                                 while tok0 is None:
                                     tok0 = await self._in_executor(
-                                        loop, self._prefill_step, st, slot)
+                                        loop, self._prefill_step, st, slot,
+                                        in_flight)
                                 self._route_admission(st, slot, tok0)
                 # 4b. Chunked prefill: ONE chunk per iteration TOTAL,
                 # round-robin across prefilling slots, interleaved with
@@ -3201,22 +3277,30 @@ class ServingEngine:
                     pending = [i for i, st in enumerate(self._slot_state)
                                if st is not None and st.prefill is not None]
                     if pending:
-                        # A completing chunk admits into the
-                        # batch (and donates the token buffer): barrier
-                        # before the chunk runs. Chunked admission
-                        # phases therefore serialize with the decode
-                        # tick exactly as before — the pipeline's win is
-                        # the steady decode state between admissions.
-                        await self._pipeline_barrier(loop, "prefill_chunk")
                         start = self._prefill_rr
                         i = min(pending,
                                 key=lambda s: (s - start) % self.slots)
                         self._prefill_rr = (i + 1) % self.slots
                         st = self._slot_state[i]
+                        if st.drained:
+                            # This slot's chunks need settled state (its
+                            # kind reads logits back on the host, or its
+                            # reservation preempted): barrier before the
+                            # chunk runs, as every chunk did until PR 33.
+                            await self._pipeline_barrier(
+                                loop, "prefill_chunk")
+                        # Otherwise the chunk is enqueued behind the tick
+                        # in flight, whose harvest paces the loop. With
+                        # NO tick in flight (every row mid-prefill: a
+                        # first wave) nothing would, and the loop would
+                        # queue every chunk of every prompt at once: the
+                        # chunk's own token is waited for instead.
                         with span("prefill_tick", slot=i,
-                                  offset=st.prefill.pos):
+                                  offset=st.prefill.pos,
+                                  drained=int(st.drained)):
                             tok0 = await self._in_executor(
-                                loop, self._prefill_step, st, i)
+                                loop, self._prefill_step, st, i,
+                                not st.drained and bool(self._inflight))
                         if tok0 is not None:
                             self._route_admission(st, i, tok0)
                 # 5. Nothing active? Flush the pipeline (an in-flight
@@ -3318,6 +3402,7 @@ class ServingEngine:
             # dropped with the references; nothing host-side depends on
             # their results once every request below is errored out.
             self._inflight.clear()
+            self._pending_admits.clear()
             for i, st in enumerate(self._slot_state):
                 if st is not None:
                     self._finish_error(st.request, err)
@@ -3428,6 +3513,11 @@ class ServingEngine:
             # ``depth`` micro-batch ticks flowing through the stages.
             while len(self._inflight) > max(1, self.pipeline_depth):
                 await self._complete_tick(loop, self._inflight.popleft())
+            # Admissions enqueued on an EMPTY pipeline (a first wave, a
+            # request into an idle engine) met no harvest above: their
+            # first tokens are read now, with their rows' first tick
+            # already queued behind the prefills.
+            await self._resolve_admissions(loop)
         if self._arm_after_warmup and self.auditor is not None:
             # The first dispatch IS the warmup: compilation is
             # synchronous at the jit call (only execution is async), so
@@ -3465,13 +3555,47 @@ class ServingEngine:
         depth>1 this drains ALL stages' in-flight micro-batch ticks,
         oldest first. ``reason`` is one of ``BARRIER_REASONS``; a barrier
         that found a tick to drain (the overlap was lost) is counted under
-        it and is a ``barrier`` span, one that found none costs nothing."""
+        it and is a ``barrier`` span, one that found none costs nothing.
+        Either way no admission's first token is left unread behind it
+        (each harvest reads them; with no tick in flight they are read
+        here), so a teardown that follows sees every row's true state."""
         if not self._inflight:
+            await self._resolve_admissions(loop)
             return
         self.metrics.record_barrier(reason)
         with span("barrier", reason=reason):
             while self._inflight:
                 await self._complete_tick(loop, self._inflight.popleft())
+
+    async def _resolve_admissions(self, loop) -> None:
+        """Read the first tokens of the admissions whose prefill was
+        enqueued without waiting (one device-to-host copy for all of
+        them) and do their host half: stream the token, book the
+        prefill, and tear the row down if that token was all it was
+        owed. The prefills ran behind whatever tick was in flight when
+        they were enqueued, so this is called once that tick has been
+        harvested and streamed; a read that would still block takes the
+        executor hop, like a tick's harvest."""
+        if not self._pending_admits:
+            return
+        pending, self._pending_admits = self._pending_admits, []
+        toks = [p.tok for p in pending]
+        hg = self.metrics.host_gap
+        ready = all(_is_ready(t) for t in toks)
+        with span("harvest", kind="admit", ready=ready):
+            hg.harvest_started()
+            vals = (jax.device_get(toks) if ready
+                    else await self._in_executor(loop, jax.device_get, toks))
+            t = time.monotonic()
+        for p, tok0 in zip(pending, vals):
+            hg.harvest_ended()
+            # What the series can honestly hold off the barrier route:
+            # the host's dispatch time of every chunk, plus the wait
+            # from the last dispatch to this read (device time of the
+            # last chunk and of whatever ran before it).
+            p.job.device_s += t - p.t_enqueued
+            self._book_prefill(p.st.request, p.job, p.prompt_len)
+            self._finish_admission(p.st, p.slot, int(tok0))
 
     async def _complete_tick(self, loop, tick: _InflightTick) -> None:
         """Harvest one dispatched tick and do its host half: stream the
@@ -3479,7 +3603,10 @@ class ServingEngine:
         is still alive, then tear down rows that finished. A row whose
         slot emptied between dispatch and harvest (a finish processed
         while the next tick was already in flight) is dropped exactly
-        like a mid-prefill garbage row."""
+        like a mid-prefill garbage row; so is one whose slot an admission
+        has refilled since. Last, the first tokens of the admissions
+        enqueued behind this tick are read: their prefills ran while the
+        host streamed."""
         # Readiness fast path: when the device already finished the
         # tick (the pipelined steady state — the whole host iteration
         # ran while it computed), the harvest is a ready-buffer memcpy
@@ -3502,12 +3629,12 @@ class ServingEngine:
             self._spec_owe_fallback = False
         t = time.monotonic()
         with span("stream", active=self.active_slots):
-            for i in tick.rows:
+            for i, owner in zip(tick.rows, tick.states):
                 st = self._slot_state[i]
-                if st is None or st.prefill is not None:
-                    # The slot emptied (or was recycled into a new
-                    # prefill) since dispatch: this tick's row output is
-                    # speculative garbage.
+                if st is not owner:
+                    # The slot emptied (or was refilled by a new
+                    # admission) since dispatch: this tick's row output
+                    # is speculative garbage.
                     continue
                 if tick.kind == "spec":
                     self._stream_spec(st, out[i], int(commit[i]),
@@ -3515,37 +3642,38 @@ class ServingEngine:
                 else:
                     self._push_token(st, int(nxt[i - tick.mb_start]), t)
                 if st.remaining == 0:
-                    # Still-dispatched later tick(s) optimistically
-                    # advanced this slot's watermark; the request is
-                    # finished, so roll every such advance back
-                    # BEFORE adoption — the trie must never claim an
-                    # in-flight speculative write; its block is
-                    # freed instead. Growth may hand that block to
-                    # another row for the NEXT tick with no barrier
-                    # between (step 5c), and the stale write is
-                    # still harmless: programs run on the device in
-                    # dispatch order, so it lands first; the new
-                    # owner starts at the block's offset 0 and
-                    # attention masks every position past a row's
-                    # length, so the stale offset is overwritten
-                    # before it is ever read; and the trie adopts
-                    # complete blocks only, every offset of which
-                    # its owner wrote. (Likewise a row that already
-                    # finished INSIDE the in-flight tick may be
-                    # given a tail block it never uses: teardown
-                    # frees it past the adopt watermark, like a
-                    # speculating row's unused lookahead block.)
-                    for later in self._inflight:
-                        if i in later.advanced:
-                            self._lens[i] -= 1
-                            later.advanced.discard(i)
-                            self._positions_dirty = True
-                    if st.fork_idx is not None:
-                        self._finish_fork_row(i, st)
-                    else:
-                        self._finish_ok(st.request)
-                        self._free_slot(i, st)
-                        self._slot_state[i] = None
+                    self._retire_row(i, st)
+        await self._resolve_admissions(loop)
+
+    def _retire_row(self, i: int, st: _SlotState) -> None:
+        """A row has streamed its last token: finish its request and
+        free the slot. Still-dispatched later tick(s) optimistically
+        advanced this slot's watermark; the request is finished, so
+        roll every such advance back BEFORE adoption — the trie must
+        never claim an in-flight speculative write; its block is freed
+        instead. Growth may hand that block to another row for the NEXT
+        tick with no barrier between (step 5c), and so may an admission
+        (step 4), and the stale write is still harmless: programs run
+        on the device in dispatch order, so it lands first; the new
+        owner starts at the block's offset 0 and attention masks every
+        position past a row's length, so the stale offset is
+        overwritten before it is ever read; and the trie adopts
+        complete blocks only, every offset of which its owner wrote.
+        (Likewise a row that already finished INSIDE the in-flight tick
+        may be given a tail block it never uses: teardown frees it past
+        the adopt watermark, like a speculating row's unused lookahead
+        block.)"""
+        for later in self._inflight:
+            if i in later.advanced:
+                self._lens[i] -= 1
+                later.advanced.discard(i)
+                self._positions_dirty = True
+        if st.fork_idx is not None:
+            self._finish_fork_row(i, st)
+        else:
+            self._finish_ok(st.request)
+            self._free_slot(i, st)
+            self._slot_state[i] = None
 
     # -- internals ----------------------------------------------------------
     @staticmethod
@@ -3582,12 +3710,15 @@ class ServingEngine:
 
     def _route_admission(self, st: _SlotState, slot: int, tok0) -> None:
         """Dispatch a completed prefill to its kind's finisher: plain
-        int first token → decode admission; scorelike sentinel →
+        int first token → decode admission; a first token still on the
+        device → the list the next harvest reads; scorelike sentinel →
         prefill-only completion; fork tokens → fan-out."""
         if tok0 is _SCORELIKE_DONE:
             self._finish_scorelike(st, slot)
         elif isinstance(tok0, _ForkReady):
             self._finish_fork(st, slot, tok0.tokens)
+        elif isinstance(tok0, _FirstTokenPending):
+            self._pending_admits.append(tok0)
         else:
             self._finish_admission(st, slot, tok0)
 
@@ -3708,9 +3839,8 @@ class ServingEngine:
                 self._lens[i] = s0
             with span("cache_admit", slot=i):
                 self._tokens, self._temps = self._admit_jit(
-                    self._tokens, self._temps, jnp.int32(i),
-                    jnp.int32(int(toks[k])),
-                    jnp.float32(req.temperature))
+                    self._tokens, self._temps, np.int32(i),
+                    np.int32(toks[k]), np.float32(req.temperature))
         self._mark_tables_dirty()
         if dry:
             # Pool dry mid-fan-out (the admission precheck bounds the
@@ -3761,17 +3891,38 @@ class ServingEngine:
             # _push_token(first=False) decrements remaining itself.
             self._push_token(st, tok0, t)
         if st.remaining == 0:
-            self._finish_ok(st.request)
-            self._free_slot(slot, st)
-            self._slot_state[slot] = None
+            # (off the barrier route the row's first decode tick may
+            # already be in flight: its advance is rolled back and its
+            # output dropped, as for any row that finishes mid-flight)
+            self._retire_row(slot, st)
 
-    def _prefill_step(self, st: _SlotState, slot: int) -> int | None:
+    def _book_prefill(self, req: Request, job: _PrefillJob,
+                      prompt_len: int) -> None:
+        """A prompt's prefill is complete and timed: the metrics' series
+        and the request's wide-event and trace columns."""
+        self.metrics.record_prefill(
+            job.device_s, job.chunks_done, job.matched_tokens, prompt_len)
+        req.prefill_device_s += job.device_s
+        req.prefill_chunks += job.chunks_done
+        req.prefix_hit_tokens = int(job.matched_tokens or 0)
+        if req.trace is not None:
+            req.trace.data.update(
+                prefill_device_s=round(job.device_s, 9),
+                prefill_chunks=job.chunks_done,
+                cache_hit_tokens=job.matched_tokens)
+
+    def _prefill_step(self, st: _SlotState, slot: int, defer: bool = False):
         """Run ONE prefill chunk for the slot (executor thread; device
         work only). Returns None while the prompt is still incomplete;
         on the final chunk there is nothing to move — the chunks already
         wrote into the slot's pool blocks — so only the sampling state
         (first token, temperature) is set and the request's first token
-        comes back."""
+        comes back. With ``defer`` (a plain ``generate`` slot off the
+        barrier route) the chunk is only ENQUEUED: nothing waits for its
+        token, and the final chunk hands back a
+        :class:`_FirstTokenPending` for the loop's next harvest. The
+        programs and the arguments they are entered with are the same
+        either way, so a warm-up through one route warms the other."""
         req, job = st.request, st.prefill
         tokens = self._resident_tokens(req)
         s0 = len(tokens)
@@ -3803,7 +3954,11 @@ class ServingEngine:
         padded = np.zeros((1, P), np.int32)
         padded[0, :c] = tokens[job.pos:job.pos + c]
         self._key, sub = jax.random.split(self._key)
-        temp = jnp.float32(req.temperature)
+        # Host values go to the programs as NumPy scalars and arrays: jit
+        # uploads them with the call, where a jnp.int32() apiece is an
+        # eager transfer of its own (an admission's host time is what an
+        # admitting iteration adds to every row's gap between tokens).
+        temp = np.float32(req.temperature)
         t0 = time.monotonic()
         # The chunk counts in the host-gap tracker as dispatched device
         # work: without this, admission phases would book their (device-
@@ -3852,17 +4007,27 @@ class ServingEngine:
                         tok0 = int(rng.choice(row.shape[0], p=p))
                     else:
                         tok0 = int(np.argmax(row))
-                    tok = jnp.int32(tok0)
+                    tok = np.int32(tok0)
                 hg.harvest_ended()
             else:
+                # (the table row is handed over as a COPY: the upload
+                # may still be reading it when growth next writes the
+                # row, now that nothing waits for the chunk)
                 self._cache, tok = self._prefill(
-                    self._params, self._cache, jnp.asarray(padded),
-                    jnp.int32(job.pos), jnp.int32(c),
-                    jnp.asarray(self._tables[slot]), temp, sub)
-                hg.tick_dispatched()
-                hg.harvest_started()
-                tok0 = int(tok)  # blocks: honest device time per chunk
-                hg.harvest_ended()
+                    self._params, self._cache, padded,
+                    np.int32(job.pos), np.int32(c),
+                    self._tables[slot].copy(), temp, sub)
+                if defer:
+                    # (a chunk nobody reads is not booked as dispatched:
+                    # the tracker pairs each dispatch with a harvest)
+                    tok0 = None
+                    if final:
+                        hg.tick_dispatched()
+                else:
+                    hg.tick_dispatched()
+                    hg.harvest_started()
+                    tok0 = int(tok)  # blocks: honest device time per chunk
+                    hg.harvest_ended()
         chunk_s = time.monotonic() - t0
         job.device_s += chunk_s
         job.chunks_done += 1
@@ -3879,26 +4044,20 @@ class ServingEngine:
         if req.kind in SCORELIKE_KINDS or special is not None:
             # score/embed never join the decodable set; a fork parent's
             # per-row admits happen at fan-out on the loop thread.
-            self.metrics.record_prefill(
-                job.device_s, job.chunks_done, job.matched_tokens, s0)
-            req.prefill_device_s += job.device_s
-            req.prefill_chunks += job.chunks_done
-            req.prefix_hit_tokens = int(job.matched_tokens or 0)
-            if req.trace is not None:
-                req.trace.data.update(
-                    prefill_device_s=round(job.device_s, 9),
-                    prefill_chunks=job.chunks_done,
-                    cache_hit_tokens=job.matched_tokens)
+            self._book_prefill(req, job, s0)
             st.prefill = None
             return special if special is not None else _SCORELIKE_DONE
         with span("cache_admit", slot=slot):
             self._tokens, self._temps = self._admit_jit(
-                self._tokens, self._temps, jnp.int32(slot), tok, temp)
+                self._tokens, self._temps, np.int32(slot), tok, temp)
         # The slot joins the decodable set: the masked table view
         # gains its row, so the next tick must re-upload.
         self._mark_tables_dirty()
-        self.metrics.record_prefill(
-            job.device_s, job.chunks_done, job.matched_tokens, s0)
+        st.prefill = None
+        if defer:
+            return _FirstTokenPending(st, slot, tok, job, s0,
+                                      time.monotonic())
+        self._book_prefill(req, job, s0)
         if self._spec:
             # The draft's prompt K/V, built once the target prefill
             # finished (executor thread — the loop stays responsive).
@@ -3906,15 +4065,6 @@ class ServingEngine:
             # models; the first spec tick picks it up from here.
             with span("draft_prefill", slot=slot, prompt_len=s0):
                 self._draft_prefill_slot(slot, tokens)
-        req.prefill_device_s += job.device_s
-        req.prefill_chunks += job.chunks_done
-        req.prefix_hit_tokens = int(job.matched_tokens or 0)
-        if req.trace is not None:
-            req.trace.data.update(
-                prefill_device_s=round(job.device_s, 9),
-                prefill_chunks=job.chunks_done,
-                cache_hit_tokens=job.matched_tokens)
-        st.prefill = None
         return tok0
 
     def _decodable(self) -> list[int]:
@@ -3924,6 +4074,11 @@ class ServingEngine:
                 if self._slot_state[i] is not None
                 and self._slot_state[i].prefill is None
                 and not self._slot_state[i].fork_wait]
+
+    def _states_of(self, rows) -> tuple:
+        """The requests' slot states behind ``rows``, for a tick to
+        remember which it was dispatched for."""
+        return tuple(self._slot_state[i] for i in rows)
 
     def _mark_tables_dirty(self) -> None:
         """A table row (or the decodable set) changed: the next dispatch
@@ -4078,7 +4233,8 @@ class ServingEngine:
         for i in rows:
             self._lens[i] += 1
         t = self.metrics.host_gap.tick_dispatched()
-        return _InflightTick(kind="decode", rows=rows, t_dispatch=t,
+        return _InflightTick(kind="decode", rows=rows,
+                             states=self._states_of(rows), t_dispatch=t,
                              tokens=harvest[0] if harvest else self._tokens,
                              advanced=set(rows))
 
@@ -4137,7 +4293,8 @@ class ServingEngine:
             self._lens[i] += 1
         self._tokens[mb] = nxt
         t = self.metrics.host_gap.tick_dispatched()
-        return _InflightTick(kind="decode", rows=rows, t_dispatch=t,
+        return _InflightTick(kind="decode", rows=rows,
+                             states=self._states_of(rows), t_dispatch=t,
                              tokens=nxt, advanced=set(rows),
                              mb=mb, mb_start=lo)
 
@@ -4247,6 +4404,7 @@ class ServingEngine:
             jnp.asarray(caps), start, tables_dev, sub)
         t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="spec", rows=tuple(decodable),
+                             states=self._states_of(decodable),
                              t_dispatch=t, out=out, commit=commit,
                              caps=caps)
 
@@ -4367,13 +4525,54 @@ class ServingEngine:
         bt = self.kv_block_tokens
         return last_token // bt - first_token // bt + 1
 
-    def _reserve_blocks(self, req: Request, slot: int):
+    def _admits_in_flight(self, req: Request) -> bool:
+        """Whether ``req`` may be admitted with a decode tick left in
+        flight, by what engine and request are: its prefill only writes
+        blocks that are the slot's own and its first token can wait for
+        the next harvest. Not so for the kinds whose final chunk is read
+        back on the host at once (a fork fan-out, a constrained first
+        token, a score or an embedding), with a draft model (its prefill
+        follows the target's), across pipeline stages, or at
+        ``pipeline_depth=0`` (the serialized loop the tests compare
+        against). What the POOL needs is asked of
+        :meth:`_reserve_blocks`."""
+        return (self.pipeline_depth > 0 and self._pp == 1
+                and not self._spec and req.kind == "generate"
+                and req.constraint is None)
+
+    def _tier_next_block(self, toks: list) -> int | None:
+        """The index of the block that follows the device-resident
+        prefix of ``toks``, when the host tier holds it and a match
+        could use it (:meth:`_readmit_from_tier` would scatter it
+        back); else None."""
+        bt = self.kv_block_tokens
+        # Same last-block holdback as match(): prefill needs >= 1
+        # uncached token, so a block match() won't use is a wasted row.
+        cap = max(0, (len(toks) - 1) // bt)
+        resident = self.kv_pool.probe(toks) // bt
+        if resident < cap and self.kv_tier.contains(
+                toks[:(resident + 1) * bt]):
+            return resident
+        return None
+
+    def _reserve_blocks(self, req: Request, slot: int,
+                        in_flight: bool = False):
         """Admission-time reservation (loop thread; zero device work):
         pin the longest shared prefix chain, allocate private blocks for
         the rest of the prompt, and point the slot's block table at
         both. Returns ``(job, blocks, first_block, match)``, or None
         after parking the request (requeued at its class head) because
         the pool is dry and nobody strictly lower-priority is running.
+
+        With ``in_flight`` (a decode tick may be in flight and is to
+        stay there) it does only what is host bookkeeping: blocks off
+        the free list, or unreferenced trie leaves evicted with no host
+        tier. Where it would have to re-admit blocks from the host tier
+        (a scatter into the pool, evictions that spill), spill
+        (``alloc_spills``: a gather from the tick's output), or find a
+        victim or park (``alloc`` gives None), it leaves request, pool
+        pins and table as they were and returns ``_NEEDS_BARRIER``: the
+        same test growth makes (:meth:`_chain_tail_block`).
 
         Admission only preempts STRICTLY lower-priority slots — an
         equal-priority preemption would let a full pool thrash between
@@ -4382,6 +4581,10 @@ class ServingEngine:
         a block."""
         pool = self.kv_pool
         tokens = self._resident_tokens(req)
+        if (in_flight and self.kv_tier is not None
+                and self._tier_next_block([int(t) for t in tokens])
+                is not None):
+            return _NEEDS_BARRIER
         if self.kv_tier is not None:
             # Host-tier re-admission BEFORE the match: blocks the trie
             # evicted (but the tier kept) scatter back H2D and then
@@ -4401,7 +4604,11 @@ class ServingEngine:
         m = match.matched_tokens
         first_block = m // self.kv_block_tokens
         needed = self._blocks_for(m, len(tokens) - 1)
-        ids = pool.alloc(needed)
+        spills = in_flight and pool.alloc_spills(needed)
+        ids = None if spills else pool.alloc(needed)
+        if ids is None and in_flight:
+            pool.release(match)
+            return _NEEDS_BARRIER
         while ids is None:
             # Fork and scorelike rows are never preemption victims: a
             # fork row's private stream cannot resume through the
@@ -4462,7 +4669,7 @@ class ServingEngine:
 
         - a row off the free list, or an unreferenced trie leaf evicted
           with no host tier attached: host bookkeeping only — chained;
-        - a leaf evicted with a tier attached (``next_alloc_spills``):
+        - a leaf evicted with a tier attached (``alloc_spills``):
           the spill hook (:meth:`_spill_blocks`) gathers the victim's
           rows from ``self._cache``, the in-flight tick's output, and
           waits for them on the host — refused, left to the barrier;
@@ -4470,7 +4677,7 @@ class ServingEngine:
           growth has to preempt a slot or error the wedged row, both of
           which tear down state only a harvest settles — refused."""
         pool = self.kv_pool
-        if pool.next_alloc_spills:
+        if pool.alloc_spills(1):
             return False
         ids = pool.alloc(1)
         if ids is None:

@@ -296,6 +296,20 @@ class ServingMetrics:
                      "eviction that spills to the host tier)",
                 drained=str(int(drained)))
             for drained in (False, True)}
+        # Admissions, by the route the loop took for each.
+        self._c_admissions = {
+            drained: reg.counter(
+                "serving_admissions_total",
+                help="requests admitted to a slot: drained=0 with the "
+                     "prefill enqueued beside the tick in flight (or on "
+                     "an empty pipeline) and its first token read at the "
+                     "next harvest, drained=1 behind the admission and "
+                     "prefill_chunk barriers (a dry or spilling pool, a "
+                     "host-tier re-admission, a sample, constrained or "
+                     "score-like request, a draft model, pipeline "
+                     "stages, pipeline_depth 0)",
+                drained=str(int(drained)))
+            for drained in (False, True)}
         self._h = {
             "ttft": reg.histogram(
                 "serving_ttft_seconds", help="time to first token",
@@ -839,6 +853,10 @@ class ServingMetrics:
     def record_paged_growth(self, blocks: int, drained: bool) -> None:
         """``blocks`` tail blocks chained by one pass of paged growth."""
         self._c_growth[drained].inc(blocks)
+
+    def record_admission(self, drained: bool) -> None:
+        """One request given a slot, by the route its prefill takes."""
+        self._c_admissions[drained].inc()
 
     # -- per-iteration sampling --------------------------------------------
     def sample(self, queue_depth: int, slots_active: int, slots_total: int,

@@ -493,15 +493,15 @@ class KVBlockPool:
                 pass
 
     # -- slot allocation ----------------------------------------------------
-    @property
-    def next_alloc_spills(self) -> bool:
-        """True when the next :meth:`alloc` cannot be host bookkeeping
-        alone: the free list is empty, so it has to evict a trie leaf,
-        and a spill hook is attached, so the eviction reads the victim's
-        rows back from the device. The engine's growth asks before it
-        allocates with a decode tick in flight."""
-        return not self._free and (self.spill_many_hook is not None
-                                   or self.spill_hook is not None)
+    def alloc_spills(self, n: int) -> bool:
+        """True when :meth:`alloc` of ``n`` rows cannot be host
+        bookkeeping alone: the free list holds fewer, so it has to evict
+        a trie leaf, and a spill hook is attached, so the eviction reads
+        the victim's rows back from the device. The engine's growth and
+        its admission ask before they allocate with a decode tick in
+        flight."""
+        return len(self._free) < n and (self.spill_many_hook is not None
+                                        or self.spill_hook is not None)
 
     def alloc(self, n: int) -> list[int] | None:
         """Take ``n`` private block rows, evicting LRU unreferenced trie

@@ -384,6 +384,14 @@ def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
         reqs = [engine.submit(p, 12) for p in _prompts(6, seed=23)]
         for r in reqs:
             await r.result()
+        # a request whose prompt is read back on the host chunk by
+        # chunk still drains the pipeline before it is admitted
+        long = engine.submit(_prompts(1, seed=29)[0], 24)
+        while len(long.out_tokens) < 2:
+            await asyncio.sleep(0)
+        await engine.submit(_prompts(1, seed=37)[0], 0,
+                            kind="score").result()
+        await long.result()
         engine.shutdown(drain=True)
         await task
 
@@ -398,9 +406,13 @@ def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
     assert snap["kv_pool_block_ticks_total"]["value"] == sum(blocks)
     assert snap["serving_decode_iterations_total"]["value"] == len(blocks)
     assert set(barriers) <= set(BARRIER_REASONS)
-    # admission always drains; growth only from a dry pool, and 24 blocks
-    # are roomy (the dry case: test_paged_growth_from_a_dry_pool_...)
-    assert "admission" in barriers and "paged_growth" not in barriers
+    # of the eight admissions only the score request's drains (the rest
+    # are enqueued beside the tick in flight: PR 33); growth only from a
+    # dry pool, and 24 blocks are roomy (the dry case:
+    # test_paged_growth_from_a_dry_pool_...)
+    assert barriers.count("admission") == 1
+    assert "paged_growth" not in barriers
+    assert _admission_counts(engine) == (7, 1)
     grown = snap["serving_paged_growth_blocks_total{drained=0}"]["value"]
     assert grown > 0
     assert snap["serving_paged_growth_blocks_total{drained=1}"]["value"] == 0
@@ -415,9 +427,13 @@ def test_tick_counters_and_barrier_spans_equal_a_count_of_ones_own(tiny_lm):
     assert growth and all(a["drained"] == 0 for a in growth)
     assert sum(a["blocks"] for a in growth) == grown
     harvests = [attrs for name, attrs in begins if name == "harvest"]
-    assert len(harvests) == len(rows)
-    assert all(a["kind"] == "decode" and a["ready"] in (True, False)
-               for a in harvests)
+    assert all(a["kind"] in ("decode", "admit")
+               and a["ready"] in (True, False) for a in harvests)
+    assert sum(a["kind"] == "decode" for a in harvests) == len(rows)
+    # first tokens are read in batches: at most one read per admission
+    assert 1 <= sum(a["kind"] == "admit" for a in harvests) <= 7
+    admits = [attrs for name, attrs in begins if name == "admit"]
+    assert sorted(a["drained"] for a in admits) == [0] * 7 + [1]
     assert any(name == "loop_idle" for name, _ in begins)
     with pytest.raises(KeyError):
         engine.metrics.record_barrier("because")
@@ -447,11 +463,18 @@ def _watch(engine):
     return barriers, chained
 
 
-def _growth_counts(engine):
+def _drained_split(engine, counter):
     snap = engine.metrics.registry.snapshot()
-    return tuple(
-        snap["serving_paged_growth_blocks_total{drained=%d}" % d]["value"]
-        for d in (0, 1))
+    return tuple(snap["%s{drained=%d}" % (counter, d)]["value"]
+                 for d in (0, 1))
+
+
+def _growth_counts(engine):
+    return _drained_split(engine, "serving_paged_growth_blocks_total")
+
+
+def _admission_counts(engine):
+    return _drained_split(engine, "serving_admissions_total")
 
 
 def _generate(tiny_lm, prompt, new_tokens):
@@ -703,3 +726,378 @@ def test_fork_child_waiting_for_its_parent_is_given_no_block(tiny_lm):
     pool = engine.kv_pool
     assert pool._fork_refs == {}
     assert pool.blocks_used == len(pool._by_slot)
+
+
+# -- an admission is a dispatch (PR 33) --------------------------------------
+
+# Whole-prompt admission and chunked prefill are the one _prefill_step.
+ADMIT_MODES = {
+    "whole_prompt": {},
+    "chunked": {"prefill_chunk": 4},
+}
+
+
+def _watch_admissions(engine):
+    """``(barriers, steps)``: the barriers that found a tick in flight, by
+    reason, and for every prefill step ``(request, was a tick in flight,
+    was the step only enqueued)``."""
+    barriers, _chained = _watch(engine)
+    steps, step = [], engine._prefill_step
+
+    def recording_step(st, slot, defer=False):
+        steps.append((st.request, bool(engine._inflight), defer))
+        return step(st, slot, defer)
+
+    engine._prefill_step = recording_step
+    return barriers, steps
+
+
+def _serve(engine, background, arrivals):
+    """One request first; once it has streamed two tokens (so there is a
+    tick in flight from here on) the ``arrivals``, each ``(prompt,
+    new_tokens, submit kwargs)``. Returns the background's tokens and
+    the arrivals' finished requests."""
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        first = engine.submit(*background)
+        while len(first.out_tokens) < 2:
+            await asyncio.sleep(0)
+        reqs = [engine.submit(p, n, **kw) for p, n, kw in arrivals]
+        for r in reqs:
+            await asyncio.wait_for(r.result(), 60)
+        out = await asyncio.wait_for(first.result(), 60)
+        engine.shutdown(drain=True)
+        await asyncio.wait_for(task, 60)
+        return out, reqs
+
+    return asyncio.run(main())
+
+
+@pytest.mark.parametrize("mode", sorted(ADMIT_MODES))
+def test_arrivals_are_enqueued_with_the_tick_in_flight(tiny_lm, mode):
+    """Requests that arrive while another decodes are admitted without a
+    pipeline barrier: every prefill step is only enqueued, most of them
+    beside a tick in flight, ``serving_admissions_total{drained=0}``
+    counts all of them, and the streams are those of ``generate()`` and
+    of the ``pipeline_depth=0`` engine, which still drains for each."""
+    model, variables = tiny_lm
+    background = (_prompts(1, seed=81, lo=5, hi=6)[0], 40)
+    arrivals = [(p, 9, {}) for p in _prompts(5, seed=83, lo=3, hi=14)]
+    got = {}
+    for depth in (0, 1):
+        engine = ServingEngine(
+            model, variables, slots=4, pipeline_depth=depth,
+            kv_pool_blocks=64, kv_block_tokens=4,
+            auditor=RecompileAuditor(), arm_auditor_after_warmup=True,
+            **ADMIT_MODES[mode])
+        barriers, steps = _watch_admissions(engine)
+        out, reqs = _serve(engine, background, arrivals)
+        got[depth] = [out] + [r.out_tokens for r in reqs]
+        assert engine.auditor.compiles("serving_decode") == 1
+        if depth:
+            assert not {"admission", "prefill_chunk"} & set(barriers)
+            assert _admission_counts(engine) == (6, 0)
+            # the mechanism engaged: but for the first request's, and a
+            # chunk that found every row mid-prefill, the steps met a tick
+            # in flight and did not wait for their token
+            assert sum(inflight for _s, inflight, _d in steps) >= 5
+            assert all(defer == (inflight or mode == "whole_prompt")
+                       for _s, inflight, defer in steps)
+        else:
+            assert _admission_counts(engine) == (0, 6)
+            assert not any(defer for _s, _i, defer in steps)
+    assert got[0] == got[1]
+    assert got[1] == [_generate(tiny_lm, p, n)
+                      for p, n, *_ in [background] + arrivals]
+
+
+@pytest.mark.parametrize("mode", sorted(ADMIT_MODES))
+def test_a_first_token_that_finishes_its_request(tiny_lm, mode):
+    """One token owed: the row's first decode tick is already in flight
+    when the first token is read at the harvest and ends the request.
+    The row is torn down there like any finishing row (the advance of
+    the tick in flight rolled back, its output dropped), the slot is
+    refilled at once by the next arrival, and the pool ends with every
+    block either free or a node of the trie."""
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=2, pipeline_depth=1,
+                           kv_pool_blocks=64, kv_block_tokens=4,
+                           **ADMIT_MODES[mode])
+    barriers, steps = _watch_admissions(engine)
+    background = (_prompts(1, seed=85, lo=5, hi=6)[0], 30)
+    arrivals = [(p, n, {}) for p, n in zip(
+        _prompts(4, seed=87, lo=6, hi=11), (1, 1, 5, 1))]
+    out, reqs = _serve(engine, background, arrivals)
+    assert [out] + [r.out_tokens for r in reqs] == [
+        _generate(tiny_lm, p, n) for p, n, *_ in [background] + arrivals]
+    assert not {"admission", "prefill_chunk"} & set(barriers)
+    assert _admission_counts(engine) == (5, 0)
+    assert any(defer and inflight for _s, inflight, defer in steps)
+    assert np.all(np.asarray(engine._lens) == 0)
+    pool = engine.kv_pool
+    assert pool.blocks_used == len(pool._by_slot)
+    # each adopted chain is the harvested sequence's complete blocks
+    assert pool.blocks_used == sum(
+        (len(p) + n - 1) // 4 for p, n, *_ in [background] + arrivals)
+
+
+@pytest.mark.parametrize("mode", sorted(ADMIT_MODES))
+@pytest.mark.parametrize("ending", ["cancel", "shutdown"])
+def test_teardown_with_an_admission_pending(tiny_lm, mode, ending):
+    """A request is cancelled, or the engine shut down without a drain,
+    while its prefill is enqueued and its first token unread. The
+    teardown's barrier reads that token first, so the row is torn down
+    from its true state: typed errors, the background's stream whole (on
+    cancel), every block back."""
+    from distkeras_tpu.serving.scheduler import (EngineStopped,
+                                                 RequestCancelled)
+
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=2, pipeline_depth=1,
+                           kv_pool_blocks=64, kv_block_tokens=4,
+                           **ADMIT_MODES[mode])
+    background = (_prompts(1, seed=89, lo=5, hi=6)[0], 24)
+    arrival = _prompts(1, seed=91, lo=9, hi=10)[0]
+    step, seen = engine._prefill_step, []
+
+    def ending_step(st, slot, defer=False):
+        out = step(st, slot, defer)
+        if st.request.prompt == arrival and st.prefill is None:
+            # the arrival's last chunk has just been enqueued
+            assert defer and engine._inflight
+            seen.append(len(engine._pending_admits))
+            if ending == "cancel":
+                st.request.cancel()
+            else:
+                engine.shutdown(drain=False)
+        return out
+
+    engine._prefill_step = ending_step
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        first = engine.submit(*background)
+        while len(first.out_tokens) < 2:
+            await asyncio.sleep(0)
+        req = engine.submit(arrival, 12)
+        errors = []
+        for r in (req, first):
+            try:
+                await asyncio.wait_for(r.result(), 60)
+                errors.append(None)
+            except (RequestCancelled, EngineStopped) as e:
+                errors.append(type(e))
+        engine.shutdown(drain=True)
+        await asyncio.wait_for(task, 60)
+        return first, req, errors
+
+    first, req, errors = asyncio.run(main())
+    assert seen == [0]  # enqueued, and not yet handed to the loop
+    want = _generate(tiny_lm, arrival, 12)
+    if ending == "cancel":
+        assert errors == [RequestCancelled, None]
+        assert first.out_tokens == _generate(tiny_lm, *background)
+        # the token read before the teardown is the request's own
+        assert req.out_tokens == want[:len(req.out_tokens)]
+        assert 1 <= len(req.out_tokens) < 12
+    else:
+        assert errors == [EngineStopped, EngineStopped]
+        assert req.out_tokens == want[:1]
+    assert not engine._pending_admits and not engine._inflight
+    assert np.all(np.asarray(engine._lens) == 0)
+    pool = engine.kv_pool
+    assert pool.blocks_used == len(pool._by_slot)
+
+
+def _pp2_mesh():
+    from distkeras_tpu.parallel.mesh import serving_mesh
+
+    return serving_mesh({"tp": 1, "pp": 2}, devices=jax.devices()[:2])
+
+
+# What still needs settled state keeps its barrier: the engine's options,
+# the arrival's submit options, and whether the arrival itself is then
+# admitted behind the barrier (a parked request is admitted later, when
+# blocks have freed).
+BARRIER_KEPT = {
+    # 12 + 2 positions hold four of the pool's six blocks: the arrival's
+    # three need a victim, and its priority is the better one
+    "dry_pool_preempts": (dict(kv_pool_blocks=6), dict(priority=-1), True),
+    # the same pool with nobody to preempt: the arrival parks
+    "dry_pool_parks": (dict(kv_pool_blocks=6), {}, False),
+    "sample": ({}, dict(kind="sample", n=2, speculate=False), True),
+    "constrained": (dict(constrained=True),
+                    dict(constraint={"start": 0,
+                                     "edges": [[0, 1, 1], [1, 2, 0]]}),
+                    True),
+    "draft_model": (dict(draft=True), {}, True),
+    "pp2": (dict(mesh=_pp2_mesh), {}, True),
+}
+
+
+@pytest.mark.parametrize("mode", sorted(ADMIT_MODES))
+@pytest.mark.parametrize("case", sorted(BARRIER_KEPT))
+def test_the_barrier_is_kept_where_settled_state_is_needed(
+        tiny_lm, case, mode):
+    """Each of these arrivals finds a tick in flight and drains it before
+    it is admitted (``admission``; its chunks ``prefill_chunk``), counts
+    under ``drained=1``, never has a prefill step that is only enqueued,
+    and streams what it streamed before: ``generate()``'s tokens, or the
+    automaton's."""
+    model, variables = tiny_lm
+    options, submit, admitted_drained = BARRIER_KEPT[case]
+    options = dict(options)
+    if "mesh" in options:
+        if len(jax.devices()) < 2:
+            pytest.skip("needs >= 2 devices (tier-1 runs with virtual CPUs)")
+        options["mesh"] = options["mesh"]()
+        model = gpt_tiny(seq_len=64, vocab_size=64)
+        variables = model.init(0)
+    if options.pop("draft", False):
+        options.update(draft_model=model, draft_variables=variables,
+                       spec_k=3)
+    options.setdefault("kv_pool_blocks", 64)
+    engine = ServingEngine(model, variables, slots=4, pipeline_depth=1,
+                           kv_block_tokens=4, **options,
+                           **ADMIT_MODES[mode])
+    barriers, steps = _watch_admissions(engine)
+    background = (_prompts(1, seed=93, lo=12, hi=13)[0], 9)
+    arrival = _prompts(1, seed=95, lo=9, hi=10)[0]
+    out, (req,) = _serve(engine, background, [(arrival, 6, submit)])
+
+    def want(prompt, n):
+        return generate(model, variables, np.asarray([prompt], np.int32),
+                        n, greedy=True)[0].tolist()
+
+    assert out == want(*background)
+    if case == "sample":
+        assert req.fork_completions == [want(arrival, 6)] * 2
+    elif case == "constrained":
+        assert req.out_tokens == [1, 2, 1, 2, 1, 2]
+    else:
+        assert req.out_tokens == want(arrival, 6)
+    assert "admission" in barriers
+    undrained, drained = _admission_counts(engine)
+    assert drained >= admitted_drained
+    if case in ("draft_model", "pp2"):
+        assert undrained == 0  # no admission of such an engine is enqueued
+    if admitted_drained:
+        if mode == "chunked" and case != "dry_pool_preempts":
+            # (the preempted background was the only row decoding: the
+            # arrival's chunks then find no tick to drain)
+            assert "prefill_chunk" in barriers
+        assert not any(defer for r, _i, defer in steps if r is req)
+    assert np.all(np.asarray(engine._lens) == 0)
+
+
+@pytest.mark.parametrize("mode", sorted(ADMIT_MODES))
+def test_a_warm_up_through_an_idle_engine_warms_the_burst(tiny_lm, mode):
+    """What keeps ``setup_s`` where it is, off the chip. The benchmark
+    warms a server with one request per prefill bucket, sent one after
+    another into an idle engine; its clients then arrive in a burst, most
+    of them beside a tick in flight. Both routes must enter every program
+    with ONE argument signature (dtype, weak type, committedness), or the
+    burst would trace and lower each bucket's program again, which the
+    persistent compile cache does not spare. So: after the warm-up the
+    auditor is armed on the three programs of the path, the burst compiles
+    nothing, and each program's jit cache holds one entry per bucket."""
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=4, pipeline_depth=1,
+                           kv_pool_blocks=64, kv_block_tokens=4,
+                           auditor=RecompileAuditor(), **ADMIT_MODES[mode])
+    names = ("serving_prefill", "serving_admit", "serving_decode")
+    barriers, steps = _watch_admissions(engine)
+    # whole prompts pad to 8, 16 and 32; a chunk of 4 is one program
+    warm = [_prompts(1, seed=s, lo=n, hi=n + 1)[0]
+            for s, n in ((101, 5), (103, 12), (105, 20))]
+    burst = _prompts(6, seed=107, lo=3, hi=30)
+    buckets = 3 if mode == "whole_prompt" else 1
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        for p in warm:
+            await engine.submit(p, 4).result()
+            await asyncio.sleep(0.03)  # the engine idles between them
+        warmed = {n: engine.auditor.compiles(n) for n in names}
+        engine.auditor.arm(*names)
+        first = engine.submit(warm[0], 40)
+        while len(first.out_tokens) < 2:
+            await asyncio.sleep(0)
+        outs = [await r.result()
+                for r in [engine.submit(p, 6) for p in burst]]
+        await first.result()
+        engine.shutdown(drain=True)
+        await task
+        return warmed, outs
+
+    warmed, outs = asyncio.run(main())
+    assert warmed == {"serving_prefill": buckets, "serving_admit": 1,
+                      "serving_decode": 1}
+    assert {n: engine.auditor.compiles(n) for n in names} == warmed
+    assert engine._prefill._cache_size() == buckets
+    assert engine._admit_jit._cache_size() == 1
+    assert engine._decode_step._cache_size() == 1
+    assert outs == [_generate(tiny_lm, p, 6) for p in burst]
+    # the burst did take the other route: enqueued beside a tick in flight
+    assert sum(inflight and defer for _r, inflight, defer in steps) >= 6
+    assert not {"admission", "prefill_chunk"} & set(barriers)
+
+
+@pytest.mark.parametrize("mode", sorted(ADMIT_MODES))
+def test_a_spilling_pool_and_a_tier_readmission_keep_the_barrier(
+        tiny_lm, mode):
+    """With a host tier attached, a reservation the free list cannot
+    serve evicts a trie leaf WITH a spill (a gather from the tick's
+    output), and a prompt whose next block the tier holds is scattered
+    back into the pool first. Both admissions drain the tick in flight
+    (``admission``, ``drained=1``); every spill runs with nothing in
+    flight; the streams are generate()'s."""
+    model, variables = tiny_lm
+    engine = ServingEngine(model, variables, slots=2, pipeline_depth=1,
+                           kv_pool_blocks=8, kv_block_tokens=4,
+                           kv_host_tier_mb=4.0, **ADMIT_MODES[mode])
+    barriers, _steps = _watch_admissions(engine)
+    spills = []
+    for name in ("_spill_block", "_spill_blocks"):
+        def wrapped(*args, _fn=getattr(engine, name)):
+            spills.append(bool(engine._inflight))
+            return _fn(*args)
+        setattr(engine, name, wrapped)
+    engine.kv_pool.spill_hook = engine._spill_block
+    engine.kv_pool.spill_many_hook = engine._spill_blocks
+    first, decoding, arrival, later = (
+        _prompts(1, seed=s, lo=n, hi=n + 1)[0]
+        for s, n in ((111, 8), (119, 6), (115, 13), (117, 5)))
+
+    async def beside(background, new_tokens, prompt, n):
+        bg = engine.submit(background, new_tokens)
+        while len(bg.out_tokens) < 2:
+            await asyncio.sleep(0)
+        return await engine.submit(prompt, n).result(), await bg.result()
+
+    async def main():
+        task = asyncio.create_task(engine.run(idle_poll_s=0.01))
+        # 8 + 5 tokens leave three adopted leaves and five free blocks
+        outs = [await engine.submit(first, 5).result()]
+        assert _admission_counts(engine) == (1, 0)
+        # the arrival's four blocks: two are free, two leaves spill
+        outs += await beside(decoding, 10, arrival, 2)
+        counts = [(_admission_counts(engine), barriers.count("admission"))]
+        # the first prompt again: its blocks come back from the tier
+        outs += await beside(later, 10, first, 5)
+        counts.append((_admission_counts(engine),
+                       barriers.count("admission")))
+        engine.shutdown(drain=True)
+        await task
+        return outs, counts
+
+    outs, counts = asyncio.run(main())
+    assert outs == [_generate(tiny_lm, p, n) for p, n in (
+        (first, 5), (arrival, 2), (decoding, 10), (first, 5), (later, 10))]
+    assert counts[0] == ((2, 1), 1)
+    (_undrained, drained), admission_barriers = counts[1]
+    assert drained >= 2 and admission_barriers >= 2
+    assert spills and not any(spills)
+    assert engine.kv_tier.stats()["host_entries"] >= 2
+    assert engine.metrics.registry.snapshot()[
+        "kv_tier_readmits_total"]["value"] >= 1
