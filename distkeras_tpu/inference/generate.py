@@ -49,7 +49,8 @@ def _decode_module(model, slots: bool = False, **overrides):
         raise ValueError(
             "generate() needs a causal LM whose config has a decode module "
             "(the distkeras_tpu.models.bert zoo: gpt_tiny/gpt_small/...; "
-            "distkeras_tpu.models.cohere2_moe: command_a_plus_*); got "
+            "distkeras_tpu.models.cohere2_moe: command_a_plus_*; "
+            "distkeras_tpu.models.xing4: xing4_*); got "
             f"{getattr(model, 'name', model)!r}"
         )
     if not cfg.causal:

@@ -24,8 +24,10 @@ from jax import lax
 __all__ = [
     "constrain_heads",
     "dot_product_attention",
+    "latent_attention",
     "paged_attention",
     "paged_kv_update",
+    "paged_latent_attention",
     "ring_attention",
     "ring_self_attention",
     "sp_batch_spec",
@@ -298,6 +300,102 @@ def paged_attention(q, pool_k, pool_v, tables, positions,
         return _folded_heads_attention(q, k, v, mask, head_groups)
     return dot_product_attention(q, k.reshape(B, T * bt, H, D),
                                  v.reshape(B, T * bt, H, D), mask=mask)
+
+
+@jax.named_scope("latent_attention")
+def latent_attention(q_nope, q_rope, latent, w_ukv, scale: float):
+    """Causal multi-head latent attention of a whole sequence over its own
+    latent rows, the expanded form. ``q_nope`` ``[B, S, H, nope]``,
+    ``q_rope`` ``[B, S, H, rope]`` (turned), ``latent`` ``[B, S, R +
+    rope]``: the ``R`` lanes of ``c_kv`` then the shared turned ``k_rope``.
+    ``score_h(i, j) = scale * (q_nope_h(i) . k_nope_h(j) + q_rope_h(i) .
+    k_rope(j))``; returns ``sum_j p_h(i, j) v_h(j)`` ``[B, S, H, v]``."""
+    R, nope = w_ukv.shape[0], q_nope.shape[-1]
+    kv = jnp.einsum("blr,rhe->blhe", latent[..., :R], w_ukv,
+                    preferred_element_type=jnp.float32).astype(latent.dtype)
+    k_nope, v = kv[..., :nope], kv[..., nope:]  # [k_nope | v]_h = c W_UKV
+    scores = (jnp.einsum("bshd,blhd->bhsl", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshd,bld->bhsl", q_rope, latent[..., R:],
+                           preferred_element_type=jnp.float32)) * scale
+    S = q_nope.shape[1]
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    scores = jnp.where(causal, scores, -1e30)
+    weights = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhsl,blhd->bshd", weights, v)
+
+
+@jax.named_scope("latent_attention")
+def paged_latent_attention(q_nope, q_rope, pool, tables, positions, w_ukv,
+                           scale: float, key_block: int):
+    """Multi-head latent attention over a paged pool of latent rows, the
+    ABSORBED form: the serving engine's read, decode and prefill alike,
+    when a layer keeps ONE row a token, ``[c_kv | k_rope]``, shared by
+    every query head, in place of a K/V pair.
+
+    ``pool``: ``[C, bt, lanes]``, a row's ``R + rope`` values first and
+    zeros after (``lanes`` may pad them to a tile); ``tables`` /
+    ``positions`` as for :func:`paged_attention` (ids ``>= C``
+    unallocated, masked by position); ``q_nope`` ``[B, S, H, nope]``,
+    ``q_rope`` ``[B, S, H, rope]`` (turned), query ``j`` of row ``b`` at
+    ``positions[b] + j``; ``w_ukv`` ``[R, H, nope + v]``. Returns ``[B, S,
+    H, v]``.
+
+    ``q~_h = q_nope_h W_UK_h^T`` (``R`` wide) is scored against the latent
+    row itself, ``score = q~_h . c + q_rope_h . k_rope``; the values are
+    summed over the same rows, ``o~_h = sum p c``; ``o_h = o~_h W_UV_h``
+    once at the end. No per-head key or value of a cached position is
+    built. The rows are read in steps of ``key_block`` positions, a loop
+    whose trip count is the longest row's reach (no step past it), with
+    the softmax kept online in float32; each step gathers its rows once
+    and uses them for the scores and the values. (For a prefill chunk of
+    512 queries at position 32,768 this took 79.5 ms on a v5e where
+    building each step's per-head keys and values took 112 ms: PERF.md.)
+    """
+    B, S, H, nope = q_nope.shape
+    R, rope = w_ukv.shape[0], q_rope.shape[-1]
+    C, bt = pool.shape[:2]
+    T = tables.shape[1]
+    E = max(1, min(key_block // bt, T))  # table entries a step
+    steps_max = -(-T // E)
+    tables = jnp.pad(tables, ((0, 0), (0, steps_max * E - T)),
+                     constant_values=C)
+    q_pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
+    reach = jnp.max(positions) + S
+    steps = jnp.clip((reach + E * bt - 1) // (E * bt), 1, steps_max)
+    w_uk, w_uv = w_ukv[..., :nope], w_ukv[..., nope:]
+    q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk,
+                       preferred_element_type=jnp.float32)
+    query = jnp.concatenate([q_abs.astype(pool.dtype), q_rope], axis=-1)
+    query = jnp.pad(query, ((0, 0),) * 3 + ((0, pool.shape[-1] - R - rope),))
+
+    def step(j, carry):
+        acc, m, denom = carry
+        ids = lax.dynamic_slice_in_dim(tables, j * E, E, axis=1)
+        rows = pool[jnp.minimum(ids, C - 1)].reshape(B, E * bt, -1)
+        scores = jnp.einsum("bshc,blc->bhsl", query, rows,
+                            preferred_element_type=jnp.float32)
+        k_pos = j * E * bt + jnp.arange(E * bt)
+        seen = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]  # [B,1,S,L]
+        scores = jnp.where(seen, scores * scale, -1e30)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))  # [B, H, S]
+        p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
+        fade = jnp.exp(m - m_new)
+        denom = denom * fade + jnp.sum(p, axis=-1)
+        part = jnp.einsum("bhsl,blr->bshr", p.astype(pool.dtype), rows[..., :R],
+                          preferred_element_type=jnp.float32)
+        acc = acc * jnp.transpose(fade, (0, 2, 1))[..., None] + part
+        return acc, m_new, denom
+
+    acc, _, denom = lax.fori_loop(
+        0, steps, step,
+        (jnp.zeros((B, S, H, R), jnp.float32),
+         jnp.full((B, H, S), -1e30, jnp.float32),
+         jnp.zeros((B, H, S), jnp.float32)))
+    out = acc / jnp.maximum(jnp.transpose(denom, (0, 2, 1)), 1e-30)[..., None]
+    out = jnp.einsum("bshr,rhd->bshd", out.astype(pool.dtype), w_uv,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q_nope.dtype)
 
 
 def _block_attn_update(q, k_blk, v_blk, acc, m, denom, scale, mask=None):

@@ -42,14 +42,27 @@ _ROWS_PER_STEP = 128
 
 
 @jax.named_scope("router")
-def sigmoid_topk_route(logits, k: int):
+def sigmoid_topk_route(logits, k: int, bias=None, scale: float | None = None):
     """Sigmoid routing: ``logits`` ``[N, E]`` -> ``(ids [N, k] int32,
     weights [N, k] float32)``, the ``k`` largest of ``sigmoid(logits)``
     per token with their scores normalised to sum to 1 over the ``k``
-    chosen (whichever device holds them). Scores and choice in float32."""
+    chosen (whichever device holds them). Scores and choice in float32.
+
+    ``bias`` ``[E]`` (``noaux_tc``'s ``e_score_correction_bias``) moves
+    the CHOICE only: the ``k`` largest of ``sigmoid(logits) + bias`` are
+    chosen, and weighted by their unbiased scores, normalised. ``scale``
+    (``routed_scaling_factor``) multiplies the normalised weights. With
+    neither, the program is the plain one above, operation for operation."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    top, ids = lax.top_k(scores, k)
-    return ids.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+    if bias is None:
+        top, ids = lax.top_k(scores, k)
+    else:
+        _, ids = lax.top_k(scores + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    if scale is not None:
+        weights = weights * scale
+    return ids.astype(jnp.int32), weights
 
 
 @jax.named_scope("moe")

@@ -17,6 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "DEFAULT_RULES",
+    "LatentLeafError",
     "kv_pytree_shardings",
     "logical_axis_rules",
     "infer_variable_shardings",
@@ -69,6 +70,11 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+class LatentLeafError(ValueError):
+    """A latent KV pool leaf asked to shard over a mesh axis: it has no
+    heads to split (:func:`kv_pytree_shardings`)."""
+
+
 def kv_pytree_shardings(mesh: Mesh, tree, axis: str = "tp"):
     """Shardings for a decode KV cache/pool pytree: every K/V leaf is
     sharded over its **heads** dimension, everything else replicated.
@@ -86,10 +92,22 @@ def kv_pytree_shardings(mesh: Mesh, tree, axis: str = "tp"):
     (cache/pos counters) and anything else stay replicated host-ish
     metadata, mirroring the serving engine's stance that block tables
     and slot state are replicated while only the KV bytes shard.
-    ``tree`` may hold concrete arrays or ``eval_shape`` structs."""
+    ``tree`` may hold concrete arrays or ``eval_shape`` structs.
+
+    A LATENT leaf (``pool_latent`` ``[C, block_tokens, lanes]``: one row
+    a token shared by every head) has no heads dimension; splitting
+    its lanes over the axis would hand each device a slice of every
+    head's key, so it is refused (:class:`LatentLeafError`) wherever the
+    axis is wider than 1."""
     n = mesh.shape.get(axis, 1)
 
-    def rule(leaf):
+    def rule(path, leaf):
+        if (path and axis in mesh.axis_names and n > 1
+                and getattr(path[-1], "key", None) == "pool_latent"):
+            raise LatentLeafError(
+                f"a latent KV pool leaf {tuple(leaf.shape)} has no heads to "
+                f"split over {axis}={n}: serve a latent-attention model "
+                f"with {axis}=1")
         shape = getattr(leaf, "shape", ())
         heads_dim = -1 if len(shape) == 3 else -2
         if (axis in mesh.axis_names and n > 1 and len(shape) >= 3
@@ -99,7 +117,7 @@ def kv_pytree_shardings(mesh: Mesh, tree, axis: str = "tp"):
             return NamedSharding(mesh, P(*spec))
         return NamedSharding(mesh, P())
 
-    return jax.tree.map(rule, tree)
+    return jax.tree_util.tree_map_with_path(rule, tree)
 
 
 def unbox(variables):

@@ -44,6 +44,8 @@ MODEL_ZOO = {
                             "command_a_plus_tiny"),
     "command_a_plus_tp8ep8": ("distkeras_tpu.models.cohere2_moe",
                               "command_a_plus_tp8ep8"),
+    "xing4_tiny": ("distkeras_tpu.models.xing4", "xing4_tiny"),
+    "xing4_pp_stage": ("distkeras_tpu.models.xing4", "xing4_pp_stage"),
 }
 
 

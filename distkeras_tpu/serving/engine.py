@@ -3718,9 +3718,62 @@ class ServingEngine:
         elif isinstance(tok0, _ForkReady):
             self._finish_fork(st, slot, tok0.tokens)
         elif isinstance(tok0, _FirstTokenPending):
+            self._publish_prompt(st, slot)
             self._pending_admits.append(tok0)
         else:
+            self._publish_prompt(st, slot)
             self._finish_admission(st, slot, tok0)
+
+    def _publish_prompt(self, st: _SlotState, slot: int) -> None:
+        """Make a plain request's prompt shareable once its prefill is
+        written, not only when the request ends: the complete blocks of
+        what the slot holds join the prefix trie, pinned for the slot, so
+        a request on the same prefix admitted from now on hits them
+        instead of prefilling them again (and one admitted meanwhile
+        waited for this: :meth:`_prefix_in_flight`). Where the trie held
+        a block already, its copy wins and the slot's table follows it
+        (the same tokens under the same weights: the same rows). The
+        slot writes past its prompt only, so no published block is
+        written again."""
+        if (st.request.kind != "generate" or st.fork_idx is not None
+                or self._pp > 1):
+            return
+        tokens = self._resident_tokens(st.request)
+        n = int(self._lens[slot]) // self.kv_block_tokens
+        own = n - st.first_block
+        if own <= 0:
+            return
+        pool = self.kv_pool
+        head = tokens[:n * self.kv_block_tokens]
+        pool.adopt(head, st.blocks[:own], st.first_block)
+        match = pool.match_blocks(head)
+        pool.release(st.match)
+        self._tables[slot, :len(match.ids)] = match.ids
+        self._mark_tables_dirty()
+        st.match, st.blocks, st.first_block = match, st.blocks[own:], n
+
+    def _prefix_in_flight(self, tokens) -> bool:
+        """Whether a slot still prefilling a plain request is writing the
+        block of ``tokens`` that follows their cached prefix, behind the
+        same prefix: it publishes that block when its prompt is written
+        (:meth:`_publish_prompt`), so ``tokens`` had better wait for it
+        than prefill the same blocks a second time (a wave of requests on
+        a few long shared documents would otherwise each prefill the
+        whole document and exhaust the pool)."""
+        bt = self.kv_block_tokens
+        nxt = self.kv_pool.probe(tokens) // bt
+        if nxt >= max(0, (len(tokens) - 1) // bt):  # match()'s holdback
+            return False
+        lo, hi = nxt * bt, (nxt + 1) * bt
+        for st in self._slot_state:
+            if (st is None or st.prefill is None or st.fork_idx is not None
+                    or st.request.kind != "generate"):
+                continue
+            other = self._resident_tokens(st.request)
+            if (len(other) >= hi and other[lo:hi] == tokens[lo:hi]
+                    and other[:lo] == tokens[:lo]):
+                return True
+        return False
 
     def _scorelike_chunk(self, st: _SlotState, slot: int, padded,
                          c: int, s0: int) -> None:
@@ -4029,6 +4082,7 @@ class ServingEngine:
                     tok0 = int(tok)  # blocks: honest device time per chunk
                     hg.harvest_ended()
         chunk_s = time.monotonic() - t0
+        self.metrics.record_prefill_chunk(job.pos, c)
         job.device_s += chunk_s
         job.chunks_done += 1
         if req.trace is not None:
@@ -4596,6 +4650,14 @@ class ServingEngine:
                 self._readmit_from_tier(tokens, trace_id=req.trace_id)
             finally:
                 self._tier_trace_id = None
+        if (req.kind == "generate" and self._pp == 1
+                and self._prefix_in_flight(tokens)):
+            # Parked like a dry pool's head: the prefill it waits for
+            # publishes its blocks, which moves the pool's version.
+            self.scheduler.requeue(req)
+            self._parked_at_version = pool.version
+            self._parked_req = req
+            return None
         # Scorelike requests skip the prefix match: a matched prefix
         # skips its chunks' compute, and the whole point of a scoring
         # prefill is the per-position values that compute produces.

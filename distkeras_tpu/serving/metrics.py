@@ -310,6 +310,16 @@ class ServingMetrics:
                      "stages, pipeline_depth 0)",
                 drained=str(int(drained)))
             for drained in (False, True)}
+        # What prefill programs computed: positions (none served from
+        # cache) and the keys those positions attend to, cached ones
+        # included (the operations a prefill needed, whatever the model).
+        self._c_prefill_tokens = reg.counter(
+            "serving_prefill_tokens_total",
+            help="prompt positions a prefill program computed")
+        self._c_prefill_keys = reg.counter(
+            "serving_prefill_key_positions_total",
+            help="keys the computed prompt positions attend to, summed "
+                 "(a position p sees p + 1, cached ones included)")
         self._h = {
             "ttft": reg.histogram(
                 "serving_ttft_seconds", help="time to first token",
@@ -853,6 +863,11 @@ class ServingMetrics:
     def record_paged_growth(self, blocks: int, drained: bool) -> None:
         """``blocks`` tail blocks chained by one pass of paged growth."""
         self._c_growth[drained].inc(blocks)
+
+    def record_prefill_chunk(self, start: int, tokens: int) -> None:
+        """One prefill chunk of ``tokens`` positions from ``start``."""
+        self._c_prefill_tokens.inc(tokens)
+        self._c_prefill_keys.inc(tokens * start + tokens * (tokens + 1) // 2)
 
     def record_admission(self, drained: bool) -> None:
         """One request given a slot, by the route its prefill takes."""

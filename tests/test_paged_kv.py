@@ -468,6 +468,33 @@ def test_paged_prefix_hits_are_zero_copy_and_parity_exact(lm, rng,
     assert engine.metrics.summary()["prefix_hit_rate"] > 0.4
 
 
+@pytest.mark.parametrize("prefill_chunk", [None, 4])
+def test_a_prefix_in_flight_is_prefilled_once(lm, rng, prefill_chunk):
+    """Requests that arrive together on one long shared prefix: the first
+    prefills it, the others wait for its prompt to be written and
+    published to the trie, then hit it — the prefix's positions are
+    computed once (``serving_prefill_tokens_total``), every stream stays
+    token-identical, and a request on another prefix is not held back."""
+    model, variables = lm
+    engine = ServingEngine(model, variables, slots=4, max_queue=16,
+                           kv_pool_blocks=32, kv_block_tokens=4,
+                           prefill_chunk=prefill_chunk)
+    shared = _prompt(rng, 16)
+    prompts = [shared + _prompt(rng, k) for k in (3, 5, 2)] + [
+        _prompt(rng, 9)]
+
+    async def drive():
+        reqs = [engine.submit(p, 4) for p in prompts]  # one wave
+        return [await r.result() for r in reqs]
+
+    outs = asyncio.run(_run_engine(engine, drive()))
+    assert outs == [_want(lm, p, 4) for p in prompts]
+    computed = engine.metrics.registry.snapshot()[
+        "serving_prefill_tokens_total"]["value"]
+    assert computed == sum(map(len, prompts)) - 2 * 16
+    assert engine.kv_pool.stats()["hit_tokens"] >= 2 * 16
+
+
 def test_paged_hit_after_evict_round_trip(lm, rng):
     """Evicting a cached prefix under pool pressure costs performance,
     never correctness: A cached -> displaced by B/C -> A re-prefilled

@@ -304,3 +304,78 @@ def test_window_layers_gather_their_window_and_no_more_on_v5e(topo, which,
                           compiled.as_text())
     assert sorted(gathered) == sorted(
         [f"{lead}{entries}"] * 6 + [f"{lead}{_MOE_CONTEXT // _BLOCK_TOKENS}"] * 2)
+
+
+_XING_CONTEXT, _XING_SLOTS = 36864, 32
+
+
+def _xing_paged_program(topo, which):
+    from distkeras_tpu.inference.generate import _decode_module
+    from distkeras_tpu.models.xing4 import xing4_pp_stage
+    from distkeras_tpu.serving import engine
+
+    table = _XING_CONTEXT // _BLOCK_TOKENS
+    model = xing4_pp_stage()
+    # the cell's --kv-pool-mb 3072
+    blocks = 3072 * 2**20 // (_BLOCK_TOKENS * model.config.kv_token_bytes)
+    module, cfg = _decode_module(
+        model, slots=True, paged_blocks=blocks, page_tokens=_BLOCK_TOKENS,
+        page_table_blocks=table)
+    abstract = jax.eval_shape(
+        lambda r: module.init(r, jnp.zeros((_XING_SLOTS, 1), jnp.int32),
+                              train=False), jax.random.PRNGKey(0))
+    rep = SingleDeviceSharding(topo.devices[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    key = arg(jnp.uint32, 2)
+    if which == "decode":
+        fn = functools.partial(engine._paged_decode_fn, module, 0, blocks)
+        args = (arg(jnp.int32, _XING_SLOTS), arg(jnp.float32, _XING_SLOTS),
+                arg(jnp.int32, _XING_SLOTS),
+                arg(jnp.int32, _XING_SLOTS, table), key)
+    else:  # one prefill chunk of 512 tokens
+        fn = functools.partial(engine._paged_prefill_fn, module, 0)
+        args = (arg(jnp.int32, 1, 512), arg(jnp.int32), arg(jnp.int32),
+                arg(jnp.int32, table), arg(jnp.float32), key)
+    leaves = [a for a in jax.tree.leaves(abstract["cache"]) if a.ndim > 1]
+    return (jax.jit(fn, donate_argnums=(1,)),
+            (placed(abstract["params"]), placed(abstract["cache"])) + args,
+            leaves)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which):
+    """The latent block's stage at the cell's sizes: one leaf a layer of
+    576 lanes padded to 640 (a minor dimension off the 128-lane tile is
+    stored block-minor, and every program would convert the whole leaf
+    there and back: 3.2 GB of temporaries a decode tick when it was 576);
+    the donated leaves written in place; nothing but the scatters makes a
+    leaf-sized array; the latent read gathers one step of 128 table
+    entries a row at a time; parameters 8.10 GB in bfloat16, and the
+    program fits the chip."""
+    jitted, args, leaves = _xing_paged_program(topo, which)
+    assert len(leaves) == 5 and {l.shape[1:] for l in leaves} == {
+        (_BLOCK_TOKENS, 640)}
+    params = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                 for a in jax.tree.leaves(args[0]))
+    assert round(params / 1e9, 2) == 8.10
+    compiled = jitted.lower(*args).compile()
+    memory = compiled.memory_analysis()
+    leaf_bytes = int(np.prod(leaves[0].shape)) * leaves[0].dtype.itemsize
+    assert memory.alias_size_in_bytes >= len(leaves) * leaf_bytes
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+    text = compiled.as_text()
+    shape = ",".join(map(str, leaves[0].shape))
+    made = re.findall(rf"= bf16\[{shape}\]\S* (\S+)\(", text)
+    assert made and set(made) <= {"scatter", "fusion", "parameter",
+                                  "get-tuple-element", "bitcast"}, set(made)
+    rows = f"{_XING_SLOTS}," if which == "decode" else ""  # [1, ...] squeezed
+    assert re.search(rf"= bf16\[{rows}128,{_BLOCK_TOKENS},640\]\S* gather\(",
+                     text)
