@@ -66,7 +66,8 @@ __all__ = ["Xing4Config", "Xing4", "xing4_tiny", "xing4_pp_stage"]
 # What a decode tick counts, in the order the module sows it (the engine
 # publishes ``<name>_total`` counters; serving/engine.py, tick counters).
 TICK_COUNTERS = ("moe_pairs", "moe_pairs_held", "moe_experts_touched",
-                 "moe_expert_tokens_max", "kv_positions_resident")
+                 "moe_expert_tokens_max", "kv_positions_resident",
+                 "latent_blocks_read")
 
 @dataclasses.dataclass(frozen=True)
 class Xing4Config:
@@ -352,8 +353,13 @@ class LatentAttention(nn.Module):
                 q_nope, q_rope, pool.value, block_tables, positions, w_ukv,
                 cfg.softmax_scale, key_block=cfg.latent_key_block)
             if live is not None:
-                counts = {"kv_positions_resident": jnp.sum(
-                    jnp.where(live, positions + S, 0))}
+                # the pool blocks the decode read copied: those that
+                # cover positions 0..positions[b] (ops/pallas/paged_latent.py)
+                blocks = positions // cfg.page_tokens + 1
+                counts = {
+                    "kv_positions_resident": jnp.sum(
+                        jnp.where(live, positions + S, 0)),
+                    "latent_blocks_read": jnp.sum(jnp.where(live, blocks, 0))}
         else:
             out = latent_attention(q_nope, q_rope, latent, w_ukv,
                                    cfg.softmax_scale)

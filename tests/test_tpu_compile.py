@@ -10,6 +10,7 @@ compile that passes says nothing about results or speed.
 """
 
 import functools
+import json
 import os
 import re
 
@@ -349,16 +350,36 @@ def _xing_paged_program(topo, which):
             leaves)
 
 
+def _latent_read_pattern():
+    """The benchmark's name for the decode latent read (the loop it times)."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs",
+        "xing4-29b-a4b-pp.json")
+    with open(path) as f:
+        return re.compile(json.load(f)["latent_read_ops"]["pattern"])
+
+
+def _on_tpu(monkeypatch):
+    """Kernels that pick interpret mode off the TPU pick the chip's
+    compiler here, where the programs are compiled for a described v5e
+    from a CPU process."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
 @pytest.mark.parametrize("which", ["decode", "prefill"])
-def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which):
+def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which,
+                                                             monkeypatch):
     """The latent block's stage at the cell's sizes: one leaf a layer of
     576 lanes padded to 640 (a minor dimension off the 128-lane tile is
     stored block-minor, and every program would convert the whole leaf
     there and back: 3.2 GB of temporaries a decode tick when it was 576);
     the donated leaves written in place; nothing but the scatters makes a
-    leaf-sized array; the latent read gathers one step of 128 table
-    entries a row at a time; parameters 8.10 GB in bfloat16, and the
-    program fits the chip."""
+    leaf-sized array; parameters 8.10 GB in bfloat16, and the program
+    fits the chip. A decode tick reads the pool through the Pallas step
+    inside each layer's loop (the loop the benchmark's
+    ``latent_read_ops`` names), with no gathered copy of a step's rows; a
+    prefill chunk still gathers one step of 128 table entries at a time."""
+    _on_tpu(monkeypatch)
     jitted, args, leaves = _xing_paged_program(topo, which)
     assert len(leaves) == 5 and {l.shape[1:] for l in leaves} == {
         (_BLOCK_TOKENS, 640)}
@@ -377,5 +398,41 @@ def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which):
     assert made and set(made) <= {"scatter", "fusion", "parameter",
                                   "get-tuple-element", "bitcast"}, set(made)
     rows = f"{_XING_SLOTS}," if which == "decode" else ""  # [1, ...] squeezed
-    assert re.search(rf"= bf16\[{rows}128,{_BLOCK_TOKENS},640\]\S* gather\(",
-                     text)
+    gathers = re.findall(
+        rf"= bf16\[{rows}128,{_BLOCK_TOKENS},640\]\S* gather\(", text)
+    if which == "prefill":
+        assert gathers and "paged_latent_step" not in text
+        return
+    assert not gathers
+    loops = [line for line in text.splitlines()
+             if _latent_read_pattern().search(line)]
+    assert len(loops) == 5, loops  # one a layer
+    bodies = {re.search(r"body=%?([\w.\-]+)", line).group(1)
+              for line in loops}
+    calls = {}  # computation -> the kernel's calls in it
+    for block in re.split(r"\n(?=\S)", text):
+        name = re.match(r"(?:ENTRY )?%?([\w.\-]+)", block)
+        if name:
+            calls[name.group(1)] = block.count("custom_call_target="
+                                               "\"tpu_custom_call\"")
+    kernel = [line for line in text.splitlines()
+              if "paged_latent_step" in line and "custom-call(" in line]
+    assert kernel and all(calls.get(b) for b in bodies), (bodies, kernel[:1])
+
+
+@pytest.mark.parametrize("model", ["gpt2-small", "command-a-plus-tp8ep8",
+                                   "xing4-29b-a4b-pp"])
+def test_only_the_latent_decode_program_calls_the_latent_kernel(
+        topo, model, monkeypatch):
+    """The other blocks' decode programs (lowered, at their cells' flags)
+    hold no call to the latent read's kernel; the latent block's does,
+    which shows the check sees one."""
+    _on_tpu(monkeypatch)
+    if model == "gpt2-small":
+        jitted, args, _ = _paged_program(topo, "decode", 1, 50257, 12)
+    elif model == "command-a-plus-tp8ep8":
+        jitted, args, _ = _moe_paged_program(topo, "decode")
+    else:
+        jitted, args, _ = _xing_paged_program(topo, "decode")
+    text = jitted.lower(*args).as_text()
+    assert ("paged_latent_step" in text) == (model == "xing4-29b-a4b-pp")

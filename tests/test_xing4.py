@@ -160,6 +160,8 @@ def test_paged_prefill_then_decode_matches_the_reference_logits(rng, chunks):
             assert counts["moe_pairs"] == 2 * 2 * live.sum()  # 2 expert layers
             assert counts["moe_pairs_held"] == counts["moe_pairs"]  # all held
             assert counts["kv_positions_resident"] == 3 * (pos[live] + 1).sum()
+            # the blocks the decode read copied: those up to each position
+            assert counts["latent_blocks_read"] == 3 * (pos[live] // BT + 1).sum()
             for r in (0, 1):
                 if live[r]:
                     got[r].append(np.asarray(logits[r]))
@@ -311,7 +313,8 @@ def test_the_engine_serves_it_with_prefix_hits_on_latent_blocks(rng):
     20-token prefix lie on the reference's best logit (float32: to
     rounding) whether their prefix was computed or handed over as latent
     blocks; the decode step compiles once with the auditor armed; the
-    tick's counters and the prefill counters arrive."""
+    tick's counters (the latent blocks the decode read copied among them)
+    and the prefill counters arrive."""
     from distkeras_tpu import run
     from distkeras_tpu.telemetry import RecompileAuditor
 
@@ -335,7 +338,14 @@ def test_the_engine_serves_it_with_prefix_hits_on_latent_blocks(rng):
     assert ticks >= 9
     assert counters["moe_pairs_total"] >= 2 * 2 * ticks
     assert counters["moe_pairs_held_total"] == counters["moe_pairs_total"]
-    assert counters["kv_positions_resident_total"] > 0
+    # the decode read's blocks hold the live rows' positions and no more
+    # than the part of a block past each row's last one (3 layers; a live
+    # row makes 2 x 2 pairs a tick)
+    resident = counters["kv_positions_resident_total"]
+    row_layers = 3 * counters["moe_pairs_total"] // 4
+    assert resident > 0
+    assert resident <= counters["latent_blocks_read_total"] * BT < (
+        resident + BT * row_layers)
     # what the prefill programs computed: none of the hit positions
     computed = counters["serving_prefill_tokens_total"]
     assert computed == sum(map(len, prompts)) - engine.kv_pool.hit_tokens
