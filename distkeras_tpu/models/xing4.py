@@ -1,7 +1,8 @@
-"""A decoder with multi-head latent attention, hyper-connected residual
-streams and bias-corrected sigmoid routing over whole experts
-(``model_type`` ``xing4_0``), as the first stage of a pipeline of whole
-layers.
+"""A decoder with multi-head latent attention and sigmoid routing over
+experts, in two residual forms: hyper-connected residual streams with a
+bias-corrected choice (``model_type`` ``xing4_0``, as the first stage of a
+pipeline of whole layers), or one plain residual stream with sandwich norms
+(``pangu_ultra_moe``, as one chip's share of an expert-parallel stage).
 
 Per token, hidden size ``C`` and ``n = hc_mult`` residual streams ``X``
 (``n x C``, float32):
@@ -28,6 +29,13 @@ Per token, hidden size ``C`` and ``n = hc_mult`` residual streams ``X``
   ``experts_per_token`` largest of ``s + b_corr`` chosen, weighted ``s_k /
   sum s_k * routed_scaling_factor``, ``sum_k w_k E_k(u) + sum E_shared(u)``,
   every expert a gated SiLU of width ``expert_dim``.
+
+With ``sandwich_norm`` the residual is one stream ``x`` (float32), each
+sublayer between two RMSNorms of its own: ``x <- x + RMSNorm_post_attn(
+MLA(RMSNorm_in(x)))``, ``x <- x + RMSNorm_post_mlp(FFN(RMSNorm_pre_mlp(
+x)))``; after the last layer the final RMSNorm and the untied head.
+Without ``router_bias`` the choice is the ``experts_per_token`` largest of
+``s`` itself.
 
 Parameters are held in ``dtype`` (bfloat16); the streams, the coefficient
 path, the norms and the router are float32.
@@ -61,7 +69,8 @@ from distkeras_tpu.ops.attention import (
 )
 from distkeras_tpu.ops.moe import dropless_experts, sigmoid_topk_route
 
-__all__ = ["Xing4Config", "Xing4", "xing4_tiny", "xing4_pp_stage"]
+__all__ = ["Xing4Config", "Xing4", "xing4_tiny", "xing4_pp_stage",
+           "openpangu_tiny", "openpangu_ultra_moe_ep16_stage"]
 
 # What a decode tick counts, in the order the module sows it (the engine
 # publishes ``<name>_total`` counters; serving/engine.py, tick counters).
@@ -88,6 +97,10 @@ class Xing4Config:
     held_experts: tuple = (0, 64)    # (first, count) of the routed experts here
     num_shared_experts: int = 1
     routed_scaling_factor: float = 2.0
+    router_bias: bool = True         # a correction bias on the choice (noaux_tc)
+    # The residual: hc_mult hyper-connected streams (False), or one plain
+    # stream with an RMSNorm before and after each sublayer (True)
+    sandwich_norm: bool = False
     hc_mult: int = 4
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -375,19 +388,42 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, X, positions=None, block_tables=None, live=None):
         cfg = self.cfg
-        B, S, n, C = X.shape
+        C = cfg.hidden_size
+
+        def norm(name, x):
+            scale = self.param(name, nn.initializers.ones, (C,), cfg.dtype)
+            return _rms_norm(x, scale, cfg.rms_norm_eps)
+
+        def attention(a):
+            return LatentAttention(cfg, name="attention")(
+                a, positions=positions, block_tables=block_tables, live=live)
+
+        if cfg.sandwich_norm:
+            with jax.named_scope("sandwich_norm"):
+                a = norm("attention_norm_scale", X).astype(cfg.dtype)
+            f, counts = attention(a)
+            with jax.named_scope("sandwich_norm"):
+                X = X + norm("post_attention_norm_scale", f)
+                n32 = norm("ffn_norm_scale", X)
+            f, counts = self._ffn(n32, live, counts)
+            with jax.named_scope("sandwich_norm"):
+                return X + norm("post_ffn_norm_scale", f), counts
+
         u, h_res, h_post = HyperConnection(cfg, name="attention_hc")(X)
-        scale = self.param("attention_norm_scale", nn.initializers.ones, (C,),
-                           cfg.dtype)
-        a = _rms_norm(u, scale, cfg.rms_norm_eps).astype(cfg.dtype)
-        f, counts = LatentAttention(cfg, name="attention")(
-            a, positions=positions, block_tables=block_tables, live=live)
+        a = norm("attention_norm_scale", u).astype(cfg.dtype)
+        f, counts = attention(a)
         X = _merge(X, h_res, h_post, f)
 
         u, h_res, h_post = HyperConnection(cfg, name="ffn_hc")(X)
-        scale = self.param("ffn_norm_scale", nn.initializers.ones, (C,),
-                           cfg.dtype)
-        n32 = _rms_norm(u, scale, cfg.rms_norm_eps)
+        f, counts = self._ffn(norm("ffn_norm_scale", u), live, counts)
+        return _merge(X, h_res, h_post, f), counts
+
+    def _ffn(self, n32, live, counts):
+        """The FFN of the normed ``n32`` ``[B, S, C]`` (float32): returns
+        ``f`` ``[B, S, C]`` float32 and the counts with the expert layer's
+        added."""
+        cfg = self.cfg
+        B, S, C = n32.shape
         tokens = n32.astype(cfg.dtype).reshape(B * S, C)
         if self.dense:
             gate, up, down = (w[0] for w in _Experts(
@@ -399,8 +435,9 @@ class DecoderLayer(nn.Module):
                         preferred_element_type=jnp.float32)
         else:
             w_router = _Kernel(cfg, (C, cfg.num_experts), name="router")()
-            bias = self.param("router_bias", nn.initializers.zeros,
-                              (cfg.num_experts,), cfg.dtype)
+            bias = (self.param("router_bias", nn.initializers.zeros,
+                               (cfg.num_experts,), cfg.dtype)
+                    if cfg.router_bias else None)
             logits = jnp.dot(n32.reshape(B * S, C), w_router.astype(jnp.float32),
                              precision=lax.Precision.HIGHEST)
             ids, weights = sigmoid_topk_route(
@@ -426,7 +463,7 @@ class DecoderLayer(nn.Module):
                           "moe_pairs_held": stats["pairs_held"],
                           "moe_experts_touched": stats["experts_touched"],
                           "moe_expert_tokens_max": stats["expert_rows_max"]}
-        return _merge(X, h_res, h_post, f.reshape(B, S, C)), counts
+        return f.reshape(B, S, C), counts
 
 
 class Xing4(nn.Module):
@@ -455,8 +492,8 @@ class Xing4(nn.Module):
         embed = self.param("token_embed", nn.initializers.normal(0.02),
                            (cfg.vocab_size, cfg.hidden_size), cfg.dtype)
         e = embed[token_ids.astype(jnp.int32)].astype(jnp.float32)
-        X = jnp.broadcast_to(e[:, :, None, :],
-                             (B, S, cfg.hc_mult, cfg.hidden_size))
+        X = e if cfg.sandwich_norm else jnp.broadcast_to(
+            e[:, :, None, :], (B, S, cfg.hc_mult, cfg.hidden_size))
         # A decode tick's rows that hold a request (a free slot's table row
         # is all sentinel, ids >= paged_blocks): only those are counted, and
         # only those reach the routed experts.
@@ -474,7 +511,7 @@ class Xing4(nn.Module):
         if totals:
             self.sow("counters", "tick", jnp.stack(
                 [jnp.asarray(totals[k], jnp.int32) for k in TICK_COUNTERS]))
-        x = jnp.sum(X, axis=-2)
+        x = X if cfg.sandwich_norm else jnp.sum(X, axis=-2)
         if return_hidden:
             return x
         with jax.named_scope("head"):
@@ -488,18 +525,19 @@ class Xing4(nn.Module):
 
 def _flops(cfg: Xing4Config, seq_len: int) -> float:
     """Forward operations of one ``seq_len`` row: every projection, the
-    hyper-connections' coefficients, the chosen and shared experts, and
+    hyper-connections' coefficients (where the model has them), the shared
+    experts and the chosen ones held here (at their expectation), and
     causal attention at ``2 H (nope + rope + v)`` a key."""
     C, H, R = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
     nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     n = cfg.hc_mult
     attn = (C * cfg.q_lora_rank + cfg.q_lora_rank * H * (nope + rope)
             + C * (R + rope) + R * H * (nope + dv) + H * dv * C)
-    hc = 2 * n * C * (2 * n + n * n)
+    hc = 0 if cfg.sandwich_norm else 2 * n * C * (2 * n + n * n)
     expert = 3 * C * cfg.expert_dim
     dense = 3 * C * cfg.dense_dim
-    moe = ((cfg.experts_per_token + cfg.num_shared_experts) * expert
-           + C * cfg.num_experts)
+    held = cfg.experts_per_token * cfg.held_experts[1] / cfg.num_experts
+    moe = (held + cfg.num_shared_experts) * expert + C * cfg.num_experts
     k_dense = cfg.first_dense_layers
     per_token = 2 * (cfg.num_layers * (attn + hc) + k_dense * dense
                      + (cfg.num_layers - k_dense) * moe + C * cfg.vocab_size)
@@ -552,3 +590,46 @@ def xing4_pp_stage(seq_len: int = 36864, vocab_size: int = 131072) -> Model:
     in bfloat16."""
     cfg = Xing4Config(vocab_size=vocab_size, max_seq_len=seq_len)
     return _make(cfg, seq_len, "xing4_pp_stage")
+
+
+def openpangu_tiny(seq_len: int = 64, vocab_size: int = 256,
+                   held_experts=(0, 4), dtype="bfloat16") -> Model:
+    """The sandwich-normed block at test size: hidden 64, 4 heads (nope 16,
+    rope 8, v 16), ranks 48/32, one dense layer of 96 then two expert
+    layers of 8 experts (top-2, width 32, no correction bias, scaled 2.5)
+    and one shared, plain RoPE; the routed experts held in two shares of 4
+    (``held_experts``); the latent read in steps of 8 positions.
+    ``dtype="float32"`` is for comparisons to rounding."""
+    cfg = Xing4Config(
+        vocab_size=vocab_size, hidden_size=64, num_heads=4, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, num_hidden_layers=3, first_dense_layers=1,
+        dense_dim=96, expert_dim=32, num_experts=8, experts_per_token=2,
+        held_experts=tuple(held_experts), num_shared_experts=1,
+        routed_scaling_factor=2.5, router_bias=False, sandwich_norm=True,
+        rms_norm_eps=1e-5, rope_theta=25600000.0, rope_factor=1.0,
+        max_seq_len=max(seq_len, 64), latent_key_block=8,
+        dtype=jnp.dtype(dtype))
+    return _make(cfg, seq_len, "openpangu_tiny")
+
+
+def openpangu_ultra_moe_ep16_stage(seq_len: int = 36864,
+                                   vocab_size: int = 19200) -> Model:
+    """One chip's share of the first stage of ``openPangu-Ultra-MoE-718B``
+    served with data-parallel attention and 16-way expert parallelism, at
+    the published widths: one leading dense layer (18,432) and four expert
+    layers (routed experts 0-15 of 256, top-8, width 2,048, one shared,
+    no correction bias, scaled 2.5), 128 latent heads over ranks
+    1536/512, sandwich norms, plain RoPE at theta 25.6 M, and vocabulary
+    rows 0-19,199 (an eighth) for the embedding and the untied head.
+    ~4.92 G parameters, 9.84 GB in bfloat16."""
+    cfg = Xing4Config(
+        vocab_size=vocab_size, hidden_size=7680, num_heads=128,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=5,
+        first_dense_layers=1, dense_dim=18432, expert_dim=2048,
+        num_experts=256, experts_per_token=8, held_experts=(0, 16),
+        num_shared_experts=1, routed_scaling_factor=2.5, router_bias=False,
+        sandwich_norm=True, rms_norm_eps=1e-5, rope_theta=25600000.0,
+        rope_factor=1.0, max_seq_len=seq_len)
+    return _make(cfg, seq_len, "openpangu_ultra_moe_ep16_stage")

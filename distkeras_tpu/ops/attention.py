@@ -21,7 +21,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distkeras_tpu.ops.pallas.paged_latent import paged_latent_step
+from distkeras_tpu.ops.pallas.paged_latent import (
+    paged_latent_chunk,
+    paged_latent_step,
+)
 
 __all__ = [
     "constrain_heads",
@@ -327,33 +330,6 @@ def latent_attention(q_nope, q_rope, latent, w_ukv, scale: float):
     return jnp.einsum("bhsl,blhd->bshd", weights, v)
 
 
-def _gathered_latent_step(query, pool, ids, positions, j, acc, m, denom, *,
-                          scale: float):
-    """One step of :func:`paged_latent_attention` for a window of ``S``
-    queries a row (a prefill chunk): the step's rows gathered once into a
-    copy ``[B, E * bt, lanes]`` that the scores and the values both read.
-    ``query`` ``[B, S, H, lanes]``; ``acc`` ``[B, S, H, R]``, ``m`` and
-    ``denom`` ``[B, H, S]``, float32."""
-    B, S = query.shape[:2]
-    C, bt = pool.shape[:2]
-    E, R = ids.shape[1], acc.shape[-1]
-    q_pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
-    rows = pool[jnp.minimum(ids, C - 1)].reshape(B, E * bt, -1)
-    scores = jnp.einsum("bshc,blc->bhsl", query, rows,
-                        preferred_element_type=jnp.float32)
-    k_pos = j * E * bt + jnp.arange(E * bt)
-    seen = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]  # [B,1,S,L]
-    scores = jnp.where(seen, scores * scale, -1e30)
-    m_new = jnp.maximum(m, jnp.max(scores, axis=-1))  # [B, H, S]
-    p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
-    fade = jnp.exp(m - m_new)
-    denom = denom * fade + jnp.sum(p, axis=-1)
-    part = jnp.einsum("bhsl,blr->bshr", p.astype(pool.dtype), rows[..., :R],
-                      preferred_element_type=jnp.float32)
-    acc = acc * jnp.transpose(fade, (0, 2, 1))[..., None] + part
-    return acc, m_new, denom
-
-
 @jax.named_scope("latent_attention")
 def paged_latent_attention(q_nope, q_rope, pool, tables, positions, w_ukv,
                            scale: float, key_block: int):
@@ -374,20 +350,23 @@ def paged_latent_attention(q_nope, q_rope, pool, tables, positions, w_ukv,
     row itself, ``score = q~_h . c + q_rope_h . k_rope``; the values are
     summed over the same rows, ``o~_h = sum p c``; ``o_h = o~_h W_UV_h``
     once at the end. No per-head key or value of a cached position is
-    built. The rows are read in steps of ``key_block`` positions, a loop
-    whose trip count is the longest row's reach (no step past it), with
-    the softmax kept online in float32. A step's body follows the query
-    window: for one query a row (a decode tick) it is the Pallas kernel
+    built. The softmax is kept online in float32. For one query a row (a
+    decode tick) the rows are read in steps of ``key_block`` positions, a
+    loop whose trip count is the longest row's reach (no step past it),
+    its body the Pallas kernel
     :func:`~distkeras_tpu.ops.pallas.paged_latent.paged_latent_step`,
     which copies each row's blocks of the step, and only those in its
     reach, into fast memory once and reads that copy for the scores and
-    the values; for a longer window (a prefill chunk) it gathers the
-    step's rows once into a copy and reads it for both. (For a prefill
-    chunk of 512 queries at position 32,768 this took 79.5 ms on a v5e
-    where building each step's per-head keys and values took 112 ms:
-    PERF.md.) The loop stays either way: it is the read that the
-    benchmark's ``latent_attention_roofline`` times, by its carried
-    ``f32[rows, 1, heads, R]`` accumulator.
+    the values; the loop is the read that the benchmark's
+    ``latent_attention_roofline`` times, by its carried ``f32[rows, 1,
+    heads, R]`` accumulator. For a longer window (a prefill chunk) the
+    read is one Pallas kernel,
+    :func:`~distkeras_tpu.ops.pallas.paged_latent.paged_latent_chunk`:
+    flash attention over each row's gathered rows, whose scores never
+    leave fast memory. (An XLA body in the same loop, which wrote each
+    step's float32 scores ``[rows, heads, S, key_block]`` to HBM and read
+    them back, took ~330 ms for the five layers of a 512-query chunk at
+    position ~33 k at 128 heads on a v5e: PERF.md.)
     """
     B, S, H, nope = q_nope.shape
     R, rope = w_ukv.shape[0], q_rope.shape[-1]
@@ -404,11 +383,17 @@ def paged_latent_attention(q_nope, q_rope, pool, tables, positions, w_ukv,
                        preferred_element_type=jnp.float32)
     query = jnp.concatenate([q_abs.astype(pool.dtype), q_rope], axis=-1)
     query = jnp.pad(query, ((0, 0),) * 3 + ((0, pool.shape[-1] - R - rope),))
+    if S > 1:
+        out = paged_latent_chunk(query, pool, tables, positions, scale=scale,
+                                 rank=R)
+        out = jnp.einsum("bhsr,rhd->bshd", out, w_uv,
+                         preferred_element_type=jnp.float32)
+        return out.astype(q_nope.dtype)
 
     def step(j, carry):
         ids = lax.dynamic_slice_in_dim(tables, j * E, E, axis=1)
-        body = paged_latent_step if S == 1 else _gathered_latent_step
-        return body(query, pool, ids, positions, j, *carry, scale=scale)
+        return paged_latent_step(query, pool, ids, positions, j, *carry,
+                                 scale=scale)
 
     acc, _, denom = lax.fori_loop(
         0, steps, step,

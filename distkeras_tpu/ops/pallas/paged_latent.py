@@ -34,7 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_latent_step"]
+__all__ = ["paged_latent_step", "paged_latent_chunk"]
 
 _NEG = -1e30  # the masked score, as the XLA body has it
 
@@ -182,3 +182,114 @@ def _step(query, pool, ids, positions, step, acc, m, denom, *, scale,
         interpret=interpret,
     )(ids.reshape(-1), positions, jnp.reshape(step, (1,)).astype(jnp.int32),
       query, pool, acc, m, denom)
+
+
+# A prefill chunk's read: queries a tile of one head, and keys a tile.
+_CHUNK_QUERIES = 512
+_CHUNK_KEYS = 1024
+
+
+def _chunk_kernel(pos_ref, q_ref, k_ref, o_ref, acc, m, d, *, q_tile: int,
+                  k_tile: int, scale: float, rank: int):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    first = pos_ref[b] + i * q_tile  # the tile's first query's position
+
+    @pl.when(j == 0)
+    def _start():
+        acc[...] = jnp.zeros_like(acc)
+        m[...] = jnp.full_like(m, _NEG)
+        d[...] = jnp.zeros_like(d)
+
+    @pl.when(j * k_tile <= first + q_tile - 1)  # a key the tile can see
+    def _read():
+        keys = k_ref[...]  # [k_tile, lanes]
+        scores = lax.dot_general(
+            q_ref[...], keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [q_tile, k_tile]
+        q_pos = first + lax.broadcasted_iota(jnp.int32, (q_tile, 1), 0)
+        k_pos = j * k_tile + lax.broadcasted_iota(jnp.int32, (1, k_tile), 1)
+        seen = k_pos <= q_pos
+        scores = jnp.where(seen, scores * scale, _NEG)
+        m_new = jnp.maximum(m[...], jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(scores - m_new), 0.0)
+        fade = jnp.exp(m[...] - m_new)
+        d[...] = d[...] * fade + jnp.sum(p, axis=-1, keepdims=True)
+        m[...] = m_new
+        acc[...] = acc[...] * fade + jnp.dot(
+            p.astype(keys.dtype), keys[:, :rank],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        o_ref[...] = (acc[...] / jnp.maximum(d[...], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_latent_chunk(query, pool, tables, positions, *, scale: float,
+                       rank: int):
+    """The whole latent read of a window of queries a row (a prefill
+    chunk), normalised: ``[B, H, S, rank]`` in the pool's dtype, the
+    values summed over a row's first ``rank`` lanes (its ``c_kv``).
+
+    ``query`` ``[B, S, H, lanes]`` (as for :func:`paged_latent_step`, query
+    ``s`` of row ``b`` at ``positions[b] + s``); ``tables`` int32 ``[B,
+    T]``, ids ``>= C`` unallocated. Each row's blocks are gathered once, in
+    table order, into ``[B, T * bt, lanes]``; the kernel then runs flash
+    attention over them, a grid over rows, heads, tiles of queries and
+    tiles of keys with the softmax online in fast memory. A key tile past
+    what every query of a tile can see is neither copied nor computed.
+    The arithmetic is the decode step's: scores in float32, masked and
+    scaled, the probabilities rounded to the pool's dtype for the values,
+    the sum divided by the denominator (at least 1e-30) at the end."""
+    return _chunk(query, pool, tables, positions, scale=scale, rank=rank,
+                  interpret=jax.default_backend() != "tpu")
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def _chunk(query, pool, tables, positions, *, scale, rank, interpret):
+    B, S, H, lanes = query.shape
+    C, bt = pool.shape[:2]
+    T = tables.shape[1]
+    k_tile = bt * min(T, max(1, _CHUNK_KEYS // bt))
+    entries = k_tile // bt
+    steps = -(-T // entries)
+    tables = jnp.pad(tables, ((0, 0), (0, steps * entries - T)),
+                     constant_values=C)
+    keys = pool[jnp.minimum(tables, C - 1)].reshape(B, steps * k_tile, lanes)
+    q_tile = min(S, _CHUNK_QUERIES)
+    if S % q_tile:
+        q_tile = S
+    kernel = functools.partial(_chunk_kernel, q_tile=q_tile, k_tile=k_tile,
+                               scale=scale, rank=rank)
+
+    def key_block(b, h, i, j, pos):
+        # past the tile's last query: the last tile it reads, no new copy
+        return b, jnp.minimum(j, (pos[b] + (i + 1) * q_tile - 1) // k_tile), 0
+
+    itemsize = jnp.dtype(pool.dtype).itemsize
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, S // q_tile, steps),
+            in_specs=[
+                pl.BlockSpec((None, None, q_tile, lanes),
+                             lambda b, h, i, j, pos: (b, h, i, 0)),
+                pl.BlockSpec((None, k_tile, lanes), key_block)],
+            out_specs=pl.BlockSpec((None, None, q_tile, rank),
+                                   lambda b, h, i, j, pos: (b, h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((q_tile, rank), jnp.float32),
+                            pltpu.VMEM((q_tile, 1), jnp.float32),
+                            pltpu.VMEM((q_tile, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, rank), pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=48 * 2**20),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * B * H * S * steps * k_tile * (lanes + rank),
+            transcendentals=B * H * S * steps * k_tile,
+            bytes_accessed=B * H * (S * (lanes + rank) + S // q_tile * steps
+                                    * k_tile * lanes) * itemsize),
+        name="paged_latent_chunk",
+        interpret=interpret,
+    )(positions, jnp.transpose(query, (0, 2, 1, 3)), keys)

@@ -46,6 +46,9 @@ MODEL_ZOO = {
                               "command_a_plus_tp8ep8"),
     "xing4_tiny": ("distkeras_tpu.models.xing4", "xing4_tiny"),
     "xing4_pp_stage": ("distkeras_tpu.models.xing4", "xing4_pp_stage"),
+    "openpangu_tiny": ("distkeras_tpu.models.xing4", "openpangu_tiny"),
+    "openpangu_ultra_moe_ep16_stage": ("distkeras_tpu.models.xing4",
+                                       "openpangu_ultra_moe_ep16_stage"),
 }
 
 

@@ -189,13 +189,16 @@ PR 30 each such tick drained, and the overlap was mostly lost to
 says how many blocks were chained with the tick left in flight
 (``0``) against behind the barrier (``1``: a dry or spilling pool),
 and ``serving_admissions_total{drained}`` the same of admissions (the
-``admit`` and ``prefill_tick`` spans carry ``drained`` too).
+``admit`` and ``prefill_tick`` spans carry ``drained`` too; a
+``prefill_tick`` also carries its chunk's first position ``offset`` and
+its positions ``tokens``).
 """
 
 from __future__ import annotations
 
 import asyncio
 import collections
+import concurrent.futures
 import contextvars
 import dataclasses
 import functools
@@ -1267,6 +1270,9 @@ class ServingEngine:
             tenant_labeler=getattr(self.metrics, "tenant_labeler", None))
         self._min_bucket = int(min_prefill_bucket)
         self._chunk = None if prefill_chunk is None else int(prefill_chunk)
+        # program (a chunk width, or "decode") -> Future of the thread
+        # compiling it (_await_width); None until the first chunk
+        self._behind: dict | None = None
         self._prefill_rr = 0  # round-robin cursor over prefilling slots
         self._key = jax.random.PRNGKey(seed)
 
@@ -3297,6 +3303,9 @@ class ServingEngine:
                         # chunk's own token is waited for instead.
                         with span("prefill_tick", slot=i,
                                   offset=st.prefill.pos,
+                                  tokens=self._chunk_size(len(
+                                      self._resident_tokens(st.request)),
+                                      st.prefill.pos)[0],
                                   drained=int(st.drained)):
                             tok0 = await self._in_executor(
                                 loop, self._prefill_step, st, i,
@@ -3964,22 +3973,11 @@ class ServingEngine:
                 prefill_chunks=job.chunks_done,
                 cache_hit_tokens=job.matched_tokens)
 
-    def _prefill_step(self, st: _SlotState, slot: int, defer: bool = False):
-        """Run ONE prefill chunk for the slot (executor thread; device
-        work only). Returns None while the prompt is still incomplete;
-        on the final chunk there is nothing to move — the chunks already
-        wrote into the slot's pool blocks — so only the sampling state
-        (first token, temperature) is set and the request's first token
-        comes back. With ``defer`` (a plain ``generate`` slot off the
-        barrier route) the chunk is only ENQUEUED: nothing waits for its
-        token, and the final chunk hands back a
-        :class:`_FirstTokenPending` for the loop's next harvest. The
-        programs and the arguments they are entered with are the same
-        either way, so a warm-up through one route warms the other."""
-        req, job = st.request, st.prefill
-        tokens = self._resident_tokens(req)
-        s0 = len(tokens)
-        rem = s0 - job.pos
+    def _chunk_size(self, s0: int, pos: int) -> tuple[int, int]:
+        """``(c, P)``: the positions the next prefill chunk of a prompt of
+        ``s0`` resident tokens computes from ``pos``, and the width it is
+        padded to."""
+        rem = s0 - pos
         c = rem if self._chunk is None else min(self._chunk, rem)
         if self._chunk is None:
             P = self._bucket(c)
@@ -3997,13 +3995,93 @@ class ServingEngine:
         # matched length, shrink to the largest power of two that fits
         # and let the NEXT call(s) finish the remainder — the compile
         # set stays pow2-bounded and no token is prefilled twice.
-        # (Monolithic admission loops on this method until it returns a
-        # token, so near-context-limit prompts just take an extra
+        # (Monolithic admission loops on _prefill_step until it returns
+        # a token, so near-context-limit prompts just take an extra
         # sub-chunk or two.)
-        room = self.limit - job.pos
+        room = self.limit - pos
         if P > room:
             P = self._pow2_fit(P, room)
             c = min(c, P)  # room >= rem >= 1, so P >= 1 and c >= 1
+        return c, P
+
+    def _await_width(self, P: int, args: tuple) -> None:
+        """Before a plain prefill chunk of width ``P`` entered with
+        ``args``. The first chunk of all starts threads (with a chunk size
+        and a persistent compile cache) that compile the programs the
+        engine will enter next from the same arguments: each other width a
+        chunk can be padded to, and the plain decode tick. The first entry
+        of a program still compiling waits for it (``_await_behind``) and
+        then finds it compiled. A cold start then compiles these programs
+        side by side, ~30 s each at the latent blocks' sizes, where it
+        compiled each when it was first entered. A thread that fails
+        leaves its program to be compiled when entered, as before."""
+        if self._behind is None:
+            self._behind = {}
+            if (self._chunk is not None and self._pp == 1
+                    and jax.config.jax_compilation_cache_dir):
+                self._compile_behind(P, args)
+        self._await_behind(P)
+
+    def _compile_behind(self, P: int, args: tuple) -> None:
+        def spec(x):
+            if isinstance(x, jax.Array):
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding
+                    if getattr(x, "_committed", True) else None)
+            return x
+
+        args = jax.tree.map(spec, args)
+        widths = {min(b, self._chunk, self.limit) for b in (
+            self._min_bucket << k for k in range(
+                max(self._chunk // self._min_bucket, 1).bit_length()))}
+        jobs = {w: (self._prefill, (*args[:2], np.zeros((1, w), np.int32),
+                                    *args[3:]))
+                for w in sorted(widths | {min(self._chunk, self.limit)})
+                if w != P}
+        if (self.mesh is None and not self._constrained_mode
+                and not self._spec):
+            index = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
+            jobs["decode"] = (self._decode_step, (
+                *args[:2], spec(self._tokens), spec(self._temps), index,
+                jax.ShapeDtypeStruct(self._tables.shape, jnp.int32),
+                args[-1]))
+        if not jobs:
+            return
+
+        def compile_one(fn, fn_args):
+            try:
+                fn.lower(*fn_args).compile()
+            except Exception:  # noqa: BLE001 (compiled when entered)
+                pass
+
+        pool = concurrent.futures.ThreadPoolExecutor(len(jobs))
+        self._behind = {k: pool.submit(compile_one, *job)
+                        for k, job in jobs.items()}
+        pool.shutdown(wait=False)
+
+    def _await_behind(self, program) -> None:
+        """Wait for the thread compiling ``program`` (a chunk width, or
+        ``"decode"``), if one is."""
+        behind = (self._behind or {}).pop(program, None)
+        if behind is not None:
+            behind.result()
+
+    def _prefill_step(self, st: _SlotState, slot: int, defer: bool = False):
+        """Run ONE prefill chunk for the slot (executor thread; device
+        work only). Returns None while the prompt is still incomplete;
+        on the final chunk there is nothing to move — the chunks already
+        wrote into the slot's pool blocks — so only the sampling state
+        (first token, temperature) is set and the request's first token
+        comes back. With ``defer`` (a plain ``generate`` slot off the
+        barrier route) the chunk is only ENQUEUED: nothing waits for its
+        token, and the final chunk hands back a
+        :class:`_FirstTokenPending` for the loop's next harvest. The
+        programs and the arguments they are entered with are the same
+        either way, so a warm-up through one route warms the other."""
+        req, job = st.request, st.prefill
+        tokens = self._resident_tokens(req)
+        s0 = len(tokens)
+        c, P = self._chunk_size(s0, job.pos)
         padded = np.zeros((1, P), np.int32)
         padded[0, :c] = tokens[job.pos:job.pos + c]
         self._key, sub = jax.random.split(self._key)
@@ -4066,10 +4144,10 @@ class ServingEngine:
                 # (the table row is handed over as a COPY: the upload
                 # may still be reading it when growth next writes the
                 # row, now that nothing waits for the chunk)
-                self._cache, tok = self._prefill(
-                    self._params, self._cache, padded,
-                    np.int32(job.pos), np.int32(c),
-                    self._tables[slot].copy(), temp, sub)
+                args = (self._params, self._cache, padded, np.int32(job.pos),
+                        np.int32(c), self._tables[slot].copy(), temp, sub)
+                self._await_width(P, args)
+                self._cache, tok = self._prefill(*args)
                 if defer:
                     # (a chunk nobody reads is not booked as dispatched:
                     # the tracker pairs each dispatch with a harvest)
@@ -4277,6 +4355,7 @@ class ServingEngine:
         else:
             # (a counting module's step hands back a fourth array,
             # tokens + counts: the handle the harvest reads)
+            self._await_behind("decode")
             self._cache, self._tokens, self._positions_dev, *harvest = (
                 self._decode_step(
                     self._params, self._cache, self._tokens,

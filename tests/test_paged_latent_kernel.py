@@ -1,11 +1,14 @@
-"""The decode body of the latent read (``ops/pallas/paged_latent.py``), run
-in interpret mode on the CPU: one step against the gathered XLA body that a
-prefill chunk keeps (``ops/attention.py:_gathered_latent_step``), and the
-whole decode read against the expanded attention of the same rows
-(``ops/attention.py:latent_attention``).
+"""The latent read's kernels (``ops/pallas/paged_latent.py``), run in
+interpret mode on the CPU: a decode step, and a prefill chunk's whole read,
+against the plain XLA body of one step (``_gathered_step`` below: the step's
+rows gathered once, scored and summed with the softmax online in float32);
+and the whole read, decode and prefill, against the expanded attention of
+the same rows (``ops/attention.py:latent_attention``).
 
 The state is compared in float32 to float32 rounding; the read's output in
-bfloat16 to bfloat16 rounding.
+bfloat16 to bfloat16 rounding. The kernels are checked at the latent blocks'
+widths, 32 heads (Xing4) and 128 (openPangu-Ultra-MoE), as well as at a
+test size.
 """
 
 import jax
@@ -14,9 +17,37 @@ import numpy as np
 import pytest
 
 from distkeras_tpu.ops import attention
-from distkeras_tpu.ops.pallas.paged_latent import paged_latent_step
+from distkeras_tpu.ops.pallas.paged_latent import (
+    paged_latent_chunk,
+    paged_latent_step,
+)
 
 SCALE = 0.3
+
+
+def _gathered_step(query, pool, ids, positions, j, acc, m, denom, *, scale):
+    """One step of the read in plain XLA for a window of ``S`` queries a
+    row: the step's rows gathered once into ``[B, E * bt, lanes]``, read
+    for the scores and the values. ``query`` ``[B, S, H, lanes]``; ``acc``
+    ``[B, S, H, R]``, ``m`` and ``denom`` ``[B, H, S]``, float32."""
+    B, S = query.shape[:2]
+    C, bt = pool.shape[:2]
+    E, R = ids.shape[1], acc.shape[-1]
+    q_pos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
+    rows = pool[jnp.minimum(ids, C - 1)].reshape(B, E * bt, -1)
+    scores = jnp.einsum("bshc,blc->bhsl", query, rows,
+                        preferred_element_type=jnp.float32)
+    k_pos = j * E * bt + jnp.arange(E * bt)
+    seen = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]  # [B,1,S,L]
+    scores = jnp.where(seen, scores * scale, -1e30)
+    m_new = jnp.maximum(m, jnp.max(scores, axis=-1))  # [B, H, S]
+    p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
+    fade = jnp.exp(m - m_new)
+    denom = denom * fade + jnp.sum(p, axis=-1)
+    part = jnp.einsum("bhsl,blr->bshr", p.astype(pool.dtype), rows[..., :R],
+                      preferred_element_type=jnp.float32)
+    acc = acc * jnp.transpose(fade, (0, 2, 1))[..., None] + part
+    return acc, m_new, denom
 
 
 def _tables(rng, positions, pool_blocks, entries, bt):
@@ -48,7 +79,8 @@ def _close(got, want, rtol):
 # step's edge (23: the last position of step 1), and a free slot (its table
 # all unallocated, its position left at 30).
 @pytest.mark.parametrize("H,R,rope,lanes", [(4, 32, 8, 128),
-                                            (32, 512, 64, 640)])
+                                            (32, 512, 64, 640),
+                                            (128, 512, 64, 640)])
 def test_each_step_is_the_gathered_body(H, R, rope, lanes):
     """Step by step over four steps of three 4-token blocks (12
     positions), the last past every row's reach: the same running max,
@@ -71,8 +103,8 @@ def test_each_step_is_the_gathered_body(H, R, rope, lanes):
         before = kernel
         kernel = paged_latent_step(query, pool, ids, positions, jnp.int32(j),
                                    *kernel, scale=SCALE)
-        gathered = attention._gathered_latent_step(
-            query, pool, ids, positions, j, *gathered, scale=SCALE)
+        gathered = _gathered_step(query, pool, ids, positions, j, *gathered,
+                                  scale=SCALE)
         live = np.array([True, True, True, True, False])
         _close([s[live] for s in kernel], [s[live] for s in gathered], 2e-4)
         # nothing in this step: row 1 (position 0) after step 0, rows 0 and
@@ -114,6 +146,75 @@ def test_the_decode_read_is_the_expanded_attention_in_bfloat16():
         SCALE, key_block=key_block)
     want = take(plain, idx)
     assert got.shape == want.shape == (B, 1, H, dv)
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
+    assert err.max() < 2e-2 * max(1.0, float(np.abs(np.asarray(
+        want, np.float32)).max()))
+
+
+@pytest.mark.parametrize("H,R,rope,lanes,bt,S", [(4, 32, 8, 128, 4, 8),
+                                                 (32, 512, 64, 640, 16, 32),
+                                                 (128, 512, 64, 640, 16, 16)])
+def test_a_chunk_read_is_the_gathered_body_over_every_step(H, R, rope, lanes,
+                                                           bt, S):
+    """A prefill chunk's whole read (``paged_latent_chunk``) against the XLA
+    body run over every step and divided by its denominator: rows whose
+    windows start at 0, mid-block, past a key tile's edge, and deep in the
+    table (their last queries reach its end)."""
+    rng = np.random.default_rng(38)
+    T = 80 // bt * 2  # table entries: 160 positions
+    positions = [0, 13, 70, 160 - S]
+    C = len(positions) * T + 3
+    tables = _tables(rng, [p + S - 1 for p in positions], C, T, bt)
+    pool = np.zeros((C, bt, lanes), np.float32)
+    pool[..., :R + rope] = rng.standard_normal((C, bt, R + rope))
+    query = np.zeros((len(positions), S, H, lanes), np.float32)
+    query[..., :R + rope] = rng.standard_normal(query[..., :R + rope].shape)
+    pool, query = jnp.asarray(pool), jnp.asarray(query)
+    tables, positions = jnp.asarray(tables), jnp.asarray(positions, jnp.int32)
+    got = paged_latent_chunk(query, pool, tables, positions, scale=SCALE,
+                             rank=R)
+    B = len(positions)
+    state = (jnp.zeros((B, S, H, R), jnp.float32),
+             jnp.full((B, H, S), -1e30, jnp.float32),
+             jnp.zeros((B, H, S), jnp.float32))
+    E = 2
+    for j in range(T // E):
+        state = _gathered_step(query, pool, tables[:, j * E:(j + 1) * E],
+                               positions, j, *state, scale=SCALE)
+    acc, _, denom = state
+    want = acc / jnp.maximum(jnp.transpose(denom, (0, 2, 1)), 1e-30)[..., None]
+    assert got.shape == (B, H, S, R) and got.dtype == pool.dtype
+    _close([got], [jnp.transpose(want, (0, 2, 1, 3))], 2e-4)
+
+
+def test_the_prefill_read_is_the_expanded_attention_in_bfloat16():
+    """A prefill chunk's read (``paged_latent_attention`` with a window of
+    queries a row) at the latent block's widths against the plain expanded
+    causal attention of the same rows, from bfloat16 rows and weights:
+    windows at the start of a row and deep in it."""
+    rng = np.random.default_rng(5)
+    B, H, nope, rope, dv, R, bt, lanes = 2, 32, 128, 64, 128, 512, 16, 640
+    L, S = 160, 16
+    T = L // bt
+    k = jax.random.PRNGKey(38)
+    latent = jax.random.normal(k, (B, L, R + rope)).astype(jnp.bfloat16)
+    w_ukv = (jax.random.normal(jax.random.fold_in(k, 1), (R, H, nope + dv))
+             * R ** -0.5).astype(jnp.bfloat16)
+    q_nope = jax.random.normal(jax.random.fold_in(k, 2), (B, L, H, nope),
+                               jnp.bfloat16)
+    q_rope = jax.random.normal(jax.random.fold_in(k, 3), (B, L, H, rope),
+                               jnp.bfloat16)
+    plain = attention.latent_attention(q_nope, q_rope, latent, w_ukv, SCALE)
+    tables = jnp.asarray(rng.permutation(B * T).reshape(B, T), jnp.int32)
+    pool = jnp.zeros((B * T, bt, lanes), jnp.bfloat16).at[
+        tables.reshape(-1), :, :R + rope].set(latent.reshape(B * T, bt, -1))
+    positions = jnp.asarray([L - S, 0])
+    take = jax.vmap(lambda x, p: jax.lax.dynamic_slice_in_dim(x, p, S))
+    got = attention.paged_latent_attention(
+        take(q_nope, positions), take(q_rope, positions), pool, tables,
+        positions, w_ukv, SCALE, key_block=64)
+    want = take(plain, positions)
+    assert got.shape == want.shape == (B, S, H, dv)
     err = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
     assert err.max() < 2e-2 * max(1.0, float(np.abs(np.asarray(
         want, np.float32)).max()))

@@ -362,6 +362,33 @@ def test_chunked_prefill_parity_and_ttft_split(lm, rng):
     assert snap["serving_queue_wait_seconds"]["count"] == len(prompts)
 
 
+def test_the_first_chunk_compiles_the_other_widths_behind_it(lm, rng):
+    """With a chunk size and a persistent compile cache (the suite's), the
+    first prefill chunk starts a thread for each other width a chunk can
+    be padded to (here 4 and 8 beside the full 16) and one for the decode
+    tick, each compiling that program; the program's first entry waits for
+    it, and the engine decodes as generate() does."""
+    import jax
+
+    assert jax.config.jax_compilation_cache_dir
+    model, variables = lm
+    engine = ServingEngine(model, variables, slots=2, max_queue=8,
+                           prefill_chunk=16, min_prefill_bucket=4)
+    prompts = [_prompt(rng, 16), _prompt(rng, 7)]
+
+    async def work():
+        first = await engine.submit(prompts[0], 3).result()
+        behind = dict(engine._behind)
+        return first, behind, await engine.submit(prompts[1], 3).result()
+
+    first, behind, second = asyncio.run(_run_engine(engine, work()))
+    assert sorted(behind) == [4, 8]  # the first request entered decode
+    assert all(f.result() is None for f in behind.values())
+    assert sorted(engine._behind) == [4]  # the 7-token chunk took 8
+    assert first == _want(lm, prompts[0], 3)
+    assert second == _want(lm, prompts[1], 3)
+
+
 def test_scheduler_cache_aware_pop_prefers_hits_within_class():
     async def go():
         scores = {(7, 7): 8, (1, 1): 0, (2, 2): 4}

@@ -310,20 +310,21 @@ def test_window_layers_gather_their_window_and_no_more_on_v5e(topo, which,
 _XING_CONTEXT, _XING_SLOTS = 36864, 32
 
 
-def _xing_paged_program(topo, which):
+def _xing_paged_program(topo, which, preset="xing4_pp_stage",
+                        slots=_XING_SLOTS):
     from distkeras_tpu.inference.generate import _decode_module
-    from distkeras_tpu.models.xing4 import xing4_pp_stage
+    from distkeras_tpu.models import xing4
     from distkeras_tpu.serving import engine
 
     table = _XING_CONTEXT // _BLOCK_TOKENS
-    model = xing4_pp_stage()
+    model = getattr(xing4, preset)()
     # the cell's --kv-pool-mb 3072
     blocks = 3072 * 2**20 // (_BLOCK_TOKENS * model.config.kv_token_bytes)
     module, cfg = _decode_module(
         model, slots=True, paged_blocks=blocks, page_tokens=_BLOCK_TOKENS,
         page_table_blocks=table)
     abstract = jax.eval_shape(
-        lambda r: module.init(r, jnp.zeros((_XING_SLOTS, 1), jnp.int32),
+        lambda r: module.init(r, jnp.zeros((slots, 1), jnp.int32),
                               train=False), jax.random.PRNGKey(0))
     rep = SingleDeviceSharding(topo.devices[0])
 
@@ -337,9 +338,8 @@ def _xing_paged_program(topo, which):
     key = arg(jnp.uint32, 2)
     if which == "decode":
         fn = functools.partial(engine._paged_decode_fn, module, 0, blocks)
-        args = (arg(jnp.int32, _XING_SLOTS), arg(jnp.float32, _XING_SLOTS),
-                arg(jnp.int32, _XING_SLOTS),
-                arg(jnp.int32, _XING_SLOTS, table), key)
+        args = (arg(jnp.int32, slots), arg(jnp.float32, slots),
+                arg(jnp.int32, slots), arg(jnp.int32, slots, table), key)
     else:  # one prefill chunk of 512 tokens
         fn = functools.partial(engine._paged_prefill_fn, module, 0)
         args = (arg(jnp.int32, 1, 512), arg(jnp.int32), arg(jnp.int32),
@@ -350,13 +350,12 @@ def _xing_paged_program(topo, which):
             leaves)
 
 
-def _latent_read_pattern():
-    """The benchmark's name for the decode latent read (the loop it times)."""
+def _latent_read_pattern(config="xing4-29b-a4b-pp", key="latent_read_ops"):
+    """The benchmark's name for a latent read (the loop it times)."""
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "chipbench", "configs",
-        "xing4-29b-a4b-pp.json")
+        os.path.abspath(__file__))), "chipbench", "configs", config + ".json")
     with open(path) as f:
-        return re.compile(json.load(f)["latent_read_ops"]["pattern"])
+        return re.compile(json.load(f)[key]["pattern"])
 
 
 def _on_tpu(monkeypatch):
@@ -378,7 +377,8 @@ def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which,
     fits the chip. A decode tick reads the pool through the Pallas step
     inside each layer's loop (the loop the benchmark's
     ``latent_read_ops`` names), with no gathered copy of a step's rows; a
-    prefill chunk still gathers one step of 128 table entries at a time."""
+    prefill chunk gathers a row's whole table once and reads it through
+    the Pallas chunk kernel, one call a layer."""
     _on_tpu(monkeypatch)
     jitted, args, leaves = _xing_paged_program(topo, which)
     assert len(leaves) == 5 and {l.shape[1:] for l in leaves} == {
@@ -401,7 +401,12 @@ def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which,
     gathers = re.findall(
         rf"= bf16\[{rows}128,{_BLOCK_TOKENS},640\]\S* gather\(", text)
     if which == "prefill":
-        assert gathers and "paged_latent_step" not in text
+        table = re.findall(rf"= bf16\[{_XING_CONTEXT // _BLOCK_TOKENS},"
+                           rf"{_BLOCK_TOKENS},640\]\S* gather\(", text)
+        chunks = [line for line in text.splitlines()
+                  if "paged_latent_chunk" in line and "custom-call(" in line]
+        assert not gathers and len(table) == 5 and len(chunks) == 5, chunks
+        assert "paged_latent_step" not in text
         return
     assert not gathers
     loops = [line for line in text.splitlines()
@@ -420,19 +425,56 @@ def test_the_latent_pool_is_read_and_written_in_place_on_v5e(topo, which,
     assert kernel and all(calls.get(b) for b in bodies), (bodies, kernel[:1])
 
 
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_pangu_share_fits_the_chip_at_128_latent_heads_on_v5e(
+        topo, which, monkeypatch):
+    """openPangu-Ultra-MoE's chip share at its cell's flags (64 slots, a
+    3 GiB latent pool): parameters 9.84 GB in bfloat16; the program with
+    the pool and its temporaries fits the chip's 16 GB; the donated pool is
+    written in place; each layer's latent read is the one loop (decode) or
+    the one call of the Pallas chunk kernel (prefill) that the benchmark's
+    ``latent_read_ops`` or ``latent_prefill_ops`` names, and only the
+    decode program calls the Pallas step."""
+    _on_tpu(monkeypatch)
+    jitted, args, leaves = _xing_paged_program(
+        topo, which, "openpangu_ultra_moe_ep16_stage", 64)
+    assert len(leaves) == 5 and {l.shape[1:] for l in leaves} == {
+        (_BLOCK_TOKENS, 640)}
+    params = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                 for a in jax.tree.leaves(args[0]))
+    assert round(params / 1e9, 2) == 9.84
+    compiled = jitted.lower(*args).compile()
+    memory = compiled.memory_analysis()
+    leaf_bytes = int(np.prod(leaves[0].shape)) * leaves[0].dtype.itemsize
+    assert memory.alias_size_in_bytes >= len(leaves) * leaf_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    text = compiled.as_text()
+    pattern = _latent_read_pattern(
+        "openpangu-ultra-moe-ep16",
+        "latent_read_ops" if which == "decode" else "latent_prefill_ops")
+    loops = [line for line in text.splitlines() if pattern.search(line)]
+    assert len(loops) == 5, loops  # one a layer
+    assert ("paged_latent_step" in text) == (which == "decode")
+
+
 @pytest.mark.parametrize("model", ["gpt2-small", "command-a-plus-tp8ep8",
-                                   "xing4-29b-a4b-pp"])
+                                   "xing4-29b-a4b-pp",
+                                   "openpangu-ultra-moe-ep16"])
 def test_only_the_latent_decode_program_calls_the_latent_kernel(
         topo, model, monkeypatch):
     """The other blocks' decode programs (lowered, at their cells' flags)
-    hold no call to the latent read's kernel; the latent block's does,
+    hold no call to the latent read's kernel; the latent blocks' do,
     which shows the check sees one."""
     _on_tpu(monkeypatch)
     if model == "gpt2-small":
         jitted, args, _ = _paged_program(topo, "decode", 1, 50257, 12)
     elif model == "command-a-plus-tp8ep8":
         jitted, args, _ = _moe_paged_program(topo, "decode")
-    else:
+    elif model == "xing4-29b-a4b-pp":
         jitted, args, _ = _xing_paged_program(topo, "decode")
+    else:
+        jitted, args, _ = _xing_paged_program(
+            topo, "decode", "openpangu_ultra_moe_ep16_stage", 64)
     text = jitted.lower(*args).as_text()
-    assert ("paged_latent_step" in text) == (model == "xing4-29b-a4b-pp")
+    assert ("paged_latent_step" in text) == (model in (
+        "xing4-29b-a4b-pp", "openpangu-ultra-moe-ep16"))
