@@ -46,8 +46,8 @@ from distkeras_tpu.ops.attention import (
     dot_product_attention,
     paged_attention,
     paged_kv_update,
-    window_table_entries,
 )
+from distkeras_tpu.ops.pallas.paged_decode import paged_decode_span
 from distkeras_tpu.ops.moe import dropless_experts, sigmoid_topk_route
 
 __all__ = ["Cohere2MoeConfig", "Cohere2Moe", "command_a_plus_tiny",
@@ -59,7 +59,7 @@ LAYER_TYPES = ("sliding_attention", "full_attention")
 # publishes ``<name>_total`` counters; serving/engine.py, tick counters).
 TICK_COUNTERS = ("moe_pairs", "moe_pairs_held", "moe_experts_touched",
                  "moe_expert_tokens_max", "kv_window_positions_read",
-                 "kv_positions_resident")
+                 "kv_positions_resident", "kv_blocks_read")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,13 +227,18 @@ class Attention(nn.Module):
             pv.value = paged_kv_update(pv.value, v, block_tables, positions, bt)
             out = paged_attention(q, pk.value, pv.value, block_tables,
                                   positions, kv_heads=Hkv, window=window)
-            if sliding and live is not None:
-                read = bt * min(block_tables.shape[1],
-                                window_table_entries(window, S, bt))
-                counts = {
-                    "kv_window_positions_read": read * jnp.sum(live),
-                    "kv_positions_resident": jnp.sum(
-                        jnp.where(live, positions + S, 0))}
+            if live is not None:
+                # the pool blocks the decode read copied: those that cover
+                # what a row can see (ops/pallas/paged_decode.py)
+                _, count = paged_decode_span(block_tables, positions, bt, C,
+                                             window)
+                blocks = jnp.sum(jnp.where(live, count, 0))
+                counts = {"kv_blocks_read": blocks}
+                if sliding:
+                    counts.update(
+                        kv_window_positions_read=bt * blocks,
+                        kv_positions_resident=jnp.sum(
+                            jnp.where(live, positions + S, 0)))
         else:
             mask = None
             if sliding:
@@ -333,7 +338,8 @@ class Cohere2Moe(nn.Module):
                 totals[k] = totals.get(k, 0) + v
         if totals:
             self.sow("counters", "tick", jnp.stack(
-                [jnp.asarray(totals[k], jnp.int32) for k in TICK_COUNTERS]))
+                [jnp.asarray(totals.get(k, 0), jnp.int32)
+                 for k in TICK_COUNTERS]))
         if return_hidden:
             return x
         with jax.named_scope("head"):

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distkeras_tpu.ops.pallas.paged_decode import paged_decode_attention
 from distkeras_tpu.ops.pallas.paged_latent import (
     paged_latent_chunk,
     paged_latent_step,
@@ -254,10 +255,10 @@ def paged_attention(q, pool_k, pool_v, tables, positions,
     One compiled program for every table layout.
 
     The heads are split on the GATHERED array and never on the pool. A
-    short window (decode, speculative verify: ``S`` x a group's heads
-    within ``_FOLDED_QUERY_ROWS``) does not split them at all
-    (:func:`_folded_heads_attention`); a long one (a prefill chunk, one
-    row) reshapes its own small gather to ``[B, T*bt, H, D]``.
+    short window (speculative verify, a tensor-parallel decode tick: ``S``
+    x a group's heads within ``_FOLDED_QUERY_ROWS``) does not split them
+    at all (:func:`_folded_heads_attention`); a long one (a prefill chunk,
+    one row) reshapes its own small gather to ``[B, T*bt, H, D]``.
 
     ``kv_heads`` (fewer K/V heads than query heads: the pool's rows
     are ``kv_heads * D`` lanes wide) and ``window`` (query ``i`` sees key
@@ -275,7 +276,31 @@ def paged_attention(q, pool_k, pool_v, tables, positions,
     same apply — which makes the logits at each window offset identical
     to what one-token-at-a-time decode would have produced given the
     same prefix, the property speculative acceptance depends on.
+
+    One query a row on one group of heads (a decode tick on one device)
+    reads the pool in place instead, and only the blocks each row can see:
+    the Pallas kernel
+    :func:`~distkeras_tpu.ops.pallas.paged_decode.paged_decode_attention`,
+    with the same mask and arithmetic. Every other window — a prefill
+    chunk, a speculative verify window, heads split for tensor parallelism
+    — keeps the XLA read above.
     """
+    if q.shape[1] == 1 and head_groups == 1:
+        # the scores as the XLA read forms them: the folded heads' product
+        # in q's dtype, the grouped-query product in float32
+        grouped = kv_heads is not None or window is not None
+        return paged_decode_attention(
+            q, pool_k, pool_v, tables, positions,
+            kv_heads=kv_heads or q.shape[2], window=window,
+            score_dtype=jnp.float32 if grouped else q.dtype)
+    return _gathered_attention(q, pool_k, pool_v, tables, positions,
+                               head_groups, kv_heads, window)
+
+
+def _gathered_attention(q, pool_k, pool_v, tables, positions, head_groups,
+                        kv_heads, window):
+    """:func:`paged_attention`'s XLA read: each row's table entries
+    gathered from the pool, then masked attention over the copy."""
     B, S, H, D = q.shape
     bt = pool_k.shape[1]
     T = tables.shape[1]

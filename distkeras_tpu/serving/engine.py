@@ -3583,8 +3583,8 @@ class ServingEngine:
         prefill, and tear the row down if that token was all it was
         owed. The prefills ran behind whatever tick was in flight when
         they were enqueued, so this is called once that tick has been
-        harvested and streamed; a read that would still block takes the
-        executor hop, like a tick's harvest."""
+        harvested and streamed, whose hop gave the event loop its turns;
+        a read that would still block takes the executor hop too."""
         if not self._pending_admits:
             return
         pending, self._pending_admits = self._pending_admits, []
@@ -3616,18 +3616,18 @@ class ServingEngine:
         has refilled since. Last, the first tokens of the admissions
         enqueued behind this tick are read: their prefills ran while the
         host streamed."""
-        # Readiness fast path: when the device already finished the
-        # tick (the pipelined steady state — the whole host iteration
-        # ran while it computed), the harvest is a ready-buffer memcpy
-        # and the executor round trip would cost more than the read.
-        # Only a harvest that would genuinely BLOCK takes the thread
-        # hop, keeping the event loop responsive through real waits.
+        # The read always takes the executor hop, even when the device
+        # has already finished the tick (``ready`` on the span says
+        # which): the event loop's turns during the hop are where the
+        # front door reads new requests and writes streamed tokens. Read
+        # inline, a ready harvest left them one turn an iteration, and
+        # once the device outran the host (GPT-2 small on a TPU v5e: a
+        # 2.6 ms tick under ~7 ms of host work) the p95 time to first
+        # token doubled and the inter-token p99 rose by a third.
         harvest = (self._harvest_spec if tick.kind == "spec"
                    else self._harvest_decode)
-        ready = _tick_ready(tick)
-        with span("harvest", kind=tick.kind, ready=ready):
-            got = (harvest(tick) if ready
-                   else await self._in_executor(loop, harvest, tick))
+        with span("harvest", kind=tick.kind, ready=_tick_ready(tick)):
+            got = await self._in_executor(loop, harvest, tick)
         if tick.kind == "spec":
             out, commit, caps = got
             self._spec_owe_fallback = any(

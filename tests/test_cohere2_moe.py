@@ -128,6 +128,12 @@ def test_paged_prefill_then_decode_matches_the_reference_logits(rng):
             assert counts["moe_pairs"] == 4 * 2 * live.sum()
             assert counts["moe_pairs_held"] == counts["moe_pairs"]  # all held
             assert counts["kv_positions_resident"] == 3 * (pos[live] + 1).sum()
+            # the blocks the decode read copied: those that cover what a
+            # row sees, 0..pos (full) or pos - WINDOW + 1..pos (window)
+            seen = pos // BT - np.clip((pos - WINDOW + 1) // BT, 0, None) + 1
+            assert counts["kv_window_positions_read"] == 3 * BT * seen[live].sum()
+            assert counts["kv_blocks_read"] == (
+                3 * seen[live].sum() + (pos[live] // BT + 1).sum())
             for r in (0, 1):
                 if live[r]:
                     got[r].append(np.asarray(logits[r]))
@@ -184,9 +190,14 @@ def test_engine_serves_it_through_the_paged_pool_and_counts_its_ticks(rng):
     assert counters["moe_pairs_total"] >= 4 * 2 * ticks
     assert 0 < counters["moe_pairs_held_total"] < counters["moe_pairs_total"]
     assert counters["moe_experts_touched_total"] <= 4 * 4 * ticks
-    assert counters["kv_window_positions_read_total"] > 0
-    # a window layer reads window / block + 1 = 3 blocks of 4 a row, whatever it holds
-    assert counters["kv_window_positions_read_total"] % (3 * BT) == 0
+    read = counters["kv_window_positions_read_total"]
+    assert read > 0 and read % BT == 0
+    # a window layer reads the blocks that cover what a row sees: at most
+    # window / block + 1 = 3 blocks of 4 a row; a full layer all a row holds
+    row_ticks = counters["moe_pairs_total"] // (4 * 2)
+    assert read <= 3 * 3 * BT * row_ticks
+    full = counters["kv_blocks_read_total"] * BT - read
+    assert 3 * full >= counters["kv_positions_resident_total"]
 
 
 def test_gpt_tiny_ticks_carry_no_counters(rng):

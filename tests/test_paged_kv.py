@@ -329,6 +329,45 @@ def test_paged_ops_match_dense_attention_over_the_same_kv(S, head_groups):
                                np.asarray(want)[[0, 2]], rtol=2e-6, atol=2e-6)
 
 
+@pytest.mark.parametrize("S,head_groups,kv_heads,window,kernel", [
+    (1, 1, None, None, True),    # a decode tick on one device: folded heads
+    (1, 1, 1, 8, True),          # the same, grouped query heads in a window
+    (64, 1, None, None, False),  # a prefill chunk
+    (16, 1, 1, 8, False),        # a prefill chunk of a window layer
+    (5, 1, None, None, False),   # a speculative verify window
+    (1, 2, None, None, False),   # a decode tick under tp=2
+])
+def test_only_one_token_decode_on_one_device_takes_the_kernel(
+        monkeypatch, S, head_groups, kv_heads, window, kernel):
+    """``paged_attention`` reads the pool through the Pallas decode kernel
+    exactly when a row has one query and the heads are one group: the
+    kernel's custom call is in the text lowered for a TPU, and absent from
+    every other window's, which keeps the XLA read's gather of the pool."""
+    import jax.numpy as jnp
+    from jax import export
+
+    from distkeras_tpu.ops.attention import paged_attention
+
+    # the kernel picks the chip's compiler, not interpret mode
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, D, bt, T, C = 3, 4, 32, 4, 6, 20
+    lanes = (kv_heads or H) * D
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((B, S, H, D), jnp.bfloat16), ((C, bt, lanes), jnp.bfloat16),
+        ((C, bt, lanes), jnp.bfloat16), ((B, T), jnp.int32),
+        ((B,), jnp.int32))]
+    read = jax.jit(lambda *a: paged_attention(
+        *a, head_groups=head_groups, kv_heads=kv_heads, window=window))
+    text = export.export(read, platforms=["tpu"])(*args).mlir_module()
+    calls = [line for line in text.splitlines()
+             if "@tpu_custom_call" in line and "paged_decode_attention" in line]
+    assert len(calls) == kernel
+    pool = f"tensor<{C}x{bt}x{lanes}xbf16>"
+    gathers = [line for line in text.splitlines()
+               if "stablehlo.gather" in line and pool in line]
+    assert bool(gathers) != kernel  # the XLA read gathers from the pool
+
+
 def test_engine_pool_leaves_fold_heads_into_the_minor_dimension(lm):
     model, variables = lm
     engine = ServingEngine(model, variables, slots=2, kv_pool_blocks=12,

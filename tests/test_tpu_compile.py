@@ -204,7 +204,8 @@ def _paged_program(topo, which, tp, vocab, layers):
         strict=True, reason="192 lanes a shard do not fill 128-lane tiles")),
 ])
 def test_paged_programs_touch_the_pool_in_place_on_v5e(topo, which, tp, vocab,
-                                                       layers):
+                                                       layers, monkeypatch):
+    _on_tpu(monkeypatch)
     jitted, args, leaves = _paged_program(topo, which, tp, vocab, layers)
     compiled = jitted.lower(*args).compile()
     memory = compiled.memory_analysis()
@@ -226,6 +227,11 @@ def test_paged_programs_touch_the_pool_in_place_on_v5e(topo, which, tp, vocab,
     assert len(made["fusion"]) == len(made["scatter"]) == len(leaves)
     # (c) and the program's temporaries are smaller than one leaf
     assert memory.temp_size_in_bytes < leaf_bytes
+    # (d) a decode tick on one chip reads the pool through the Pallas
+    # kernel, one call a layer; a prefill bucket and a tp tick gather
+    calls = [line for line in compiled.as_text().splitlines()
+             if "paged_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == (layers if (which, tp) == ("decode", 1) else 0)
 
 
 # -- the same programs for the second block, at command-a-plus-tp8ep8's flags --
@@ -277,15 +283,18 @@ def _moe_paged_program(topo, which):
 
 @pytest.mark.parametrize("which,rows,queries", [("decode", _SLOTS, 1),
                                                 ("prefill", 1, 512)])
-def test_window_layers_gather_their_window_and_no_more_on_v5e(topo, which,
-                                                              rows, queries):
-    """In the compiled step the three window layers gather the block-table
-    entries that cover their window (4096 / 16 + 1 = 257 a decode row; 289
-    for a chunk of 512 queries), the full layer all 1,024; the donated pool
-    is written in place; parameters are bfloat16 (8.47 GB) and the program
-    fits the chip."""
+def test_window_layers_gather_their_window_and_no_more_on_v5e(
+        topo, which, rows, queries, monkeypatch):
+    """In the compiled prefill chunk the three window layers gather the
+    block-table entries that cover their window (4096 / 16 + 1 = 289 for a
+    chunk of 512 queries), the full layer all 1,024. The decode tick
+    gathers no pool leaf: each layer reads the pool in place through one
+    call of the Pallas kernel, which copies only the blocks a row can see.
+    The donated pool is written in place; parameters are bfloat16 (8.47
+    GB) and the program fits the chip."""
     from distkeras_tpu.ops.attention import window_table_entries
 
+    _on_tpu(monkeypatch)
     jitted, args, leaves = _moe_paged_program(topo, which)
     assert len(leaves) == 8 and {l.shape for l in leaves} == {
         (_MOE_POOL_BLOCKS, _BLOCK_TOKENS, 128)}
@@ -300,11 +309,16 @@ def test_window_layers_gather_their_window_and_no_more_on_v5e(topo, which,
             < 13e9)  # of the chip's 16 GB
     entries = window_table_entries(_MOE_WINDOW, queries, _BLOCK_TOKENS)
     assert entries == {1: 257, 512: 289}[queries]
-    lead = f"{rows}," if rows > 1 else ""
-    gathered = re.findall(r"= bf16\[([\d,]+),16,128\]\S* gather\(",
-                          compiled.as_text())
+    text = compiled.as_text()
+    gathered = re.findall(r"= bf16\[([\d,]+),16,128\]\S* gather\(", text)
+    calls = [line for line in text.splitlines()
+             if "paged_decode_attention" in line and "custom-call(" in line]
+    if which == "decode":
+        assert rows == _SLOTS and not gathered and len(calls) == 4, calls
+        return
+    assert not calls
     assert sorted(gathered) == sorted(
-        [f"{lead}{entries}"] * 6 + [f"{lead}{_MOE_CONTEXT // _BLOCK_TOKENS}"] * 2)
+        [f"{entries}"] * 6 + [f"{_MOE_CONTEXT // _BLOCK_TOKENS}"] * 2)
 
 
 _XING_CONTEXT, _XING_SLOTS = 36864, 32
