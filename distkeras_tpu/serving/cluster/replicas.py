@@ -212,9 +212,13 @@ class LocalReplica(ReplicaHandle):
         requests error out, exactly as a device failure would) and close
         the listener. Existing handler connections flush their terminal
         error lines — the router treats those the same as a dropped
-        connection (retryable iff zero tokens streamed)."""
-        self._killed = True
+        connection (retryable iff zero tokens streamed).
+
+        ``alive`` turns False only once the engine task has ended, so a
+        supervisor that polls it meanwhile never declares the replica
+        dead before the task's failure path has written its last words."""
         if self.server is None:
+            self._killed = True
             return
         if self.server._server is not None:
             self.server._server.close()
@@ -225,6 +229,7 @@ class LocalReplica(ReplicaHandle):
                 await task
             except (asyncio.CancelledError, Exception):
                 pass
+        self._killed = True
 
     async def terminate(self) -> None:
         if self._killed or self.server is None:

@@ -57,14 +57,18 @@ def _engine_section(dz: dict, indent: str = "") -> list[str]:
             f"{k}={kinds[k]}" for k in sorted(kinds)))
     pl = dz.get("pipeline")
     if isinstance(pl, dict):
-        gap = pl.get("host_gap_p50_s")
-        idle = pl.get("device_idle_ratio")
+        # Who paces the ticks (a harvest that found its tick done waited
+        # on the host, not the device) and the engine's waits for the
+        # event loop: after a harvest's read, and in its yield.
+        harvests = pl.get("harvests")
         lines.append(
             f"{indent}pipeline: depth={pl.get('depth')} "
             f"inflight={pl.get('inflight') or '-'}"
-            + (f" host_gap_p50={gap * 1e3:.3f}ms" if gap is not None
-               else "")
-            + (f" device_idle={idle:.1%}" if idle is not None else ""))
+            + (f" harvests_ready={pl.get('harvests_ready', 0):.0f}/"
+               f"{harvests:.0f}" if harvests else "")
+            + (f" loop_wait harvest={pl['loop_wait_harvest_s']:.3f}s"
+               f" yield={pl['loop_wait_yield_s']:.3f}s"
+               if "loop_wait_yield_s" in pl else ""))
         if pl.get("stages"):
             bf = pl.get("bubble_fraction")
             lines.append(
@@ -492,9 +496,9 @@ def _fmt_event(ts: float, source: str, name: str, attrs) -> str:
 
 def _tick_lane(ticks) -> list[str]:
     """The engine's dispatch→harvest tick timeline as its own lane:
-    per tick, kind, live rows, how long the harvest blocked on the
-    device (device-bound time the pipeline hid host work behind) and
-    the measured host gap (device-idle time it failed to hide)."""
+    per tick, kind, live rows, how long the harvest's read blocked on
+    the device, and how long the engine then waited for the event loop
+    to resume it."""
     if not ticks:
         return []
     lines = [f"tick lane ({len(ticks)} most recent):"]
@@ -506,7 +510,7 @@ def _tick_lane(ticks) -> list[str]:
         lines.append(
             f"  {tk.get('kind', '?'):<6} rows={tk.get('rows', '-')}"
             f" harvest_wait={tk.get('harvest_wait_s', '-')}s"
-            f" host_gap={tk.get('host_gap_s', '-')}s{span_s}")
+            f" loop_wait={tk.get('loop_wait_s', '-')}s{span_s}")
     return lines
 
 

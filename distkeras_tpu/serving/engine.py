@@ -175,11 +175,14 @@ Pipelined, all of that runs while the device executes the next tick:
   every mode: the same ticks run in the same order over the same
   state, only the host's read of each tick's result is deferred.
 
-Per-tick ``serving_host_gap_seconds`` / ``serving_device_idle_ratio``
-(:class:`~distkeras_tpu.serving.metrics.HostGapTracker`) measure what
-the pipeline hides, and :meth:`ServingEngine.tick_timeline` keeps a
-bounded dispatch→harvest lane for tracez/debugz. What it does NOT hide
-is counted: every barrier that found a tick in flight adds to
+``serving_harvests_total{ready}`` says who paces the ticks (``1``: the
+device had finished the tick before its harvest began, so the host is
+the slower side), ``serving_loop_wait_seconds_total{at}`` how long the
+engine task waited for the event loop (after a harvest's read, and in
+the end-of-iteration yield where the front door takes its turn), and
+:meth:`ServingEngine.tick_timeline` keeps a bounded dispatch→harvest
+lane for tracez/debugz. What the pipeline does NOT hide is counted:
+every barrier that found a tick in flight adds to
 ``serving_pipeline_barriers_total{reason}`` and is a ``barrier`` span
 (on the device's clock whenever a profiler session is live, see
 :mod:`distkeras_tpu.telemetry.spans`). An engine with many rows
@@ -761,6 +764,12 @@ def _is_ready(x) -> bool:
         return False
 
 
+def _read_admits(toks) -> list:
+    """The admissions' first tokens, read on the executor thread."""
+    with span("harvest_read", kind="admit"):
+        return jax.device_get(toks)
+
+
 def _tick_ready(tick) -> bool:
     """:func:`_is_ready` of every buffer the tick's harvest will read."""
     if tick.kind == "spec":
@@ -787,6 +796,8 @@ class _InflightTick:
     rows: tuple                   # decodable slots at dispatch
     states: tuple                 # their _SlotState, row for row
     t_dispatch: float
+    t_read: float = 0.0           # when the harvest's read returned
+    log: dict | None = None       # its tick_timeline entry, once harvested
     tokens: object = None         # plain: device token vector to harvest
     out: object = None            # spec: device [B, K] committed tokens
     commit: object = None         # spec: device per-row commit counts
@@ -1938,8 +1949,9 @@ class ServingEngine:
     def tick_timeline(self, n: int | None = None) -> list[dict]:
         """The bounded dispatch→harvest tick lane (most recent last):
         per tick, its kind, dispatch/harvest stamps, how long the
-        harvest blocked on the device, and the measured host gap — the
-        tracez view of what the pipeline is (or is not) hiding."""
+        harvest's read blocked on the device, and (pipelined ticks) how
+        long the engine then waited for the event loop — the tracez view
+        of what the pipeline is (or is not) hiding."""
         log = list(self._tick_log)
         return log if n is None else log[-int(n):]
 
@@ -2140,8 +2152,7 @@ class ServingEngine:
                              if self._inflight else None),
                 "inflight_ticks": len(self._inflight),
                 "ticks_logged": len(self._tick_log),
-                "host_gap_p50_s": self.metrics.host_gap.gap_p50,
-                "device_idle_ratio": self.metrics.host_gap.idle_ratio,
+                **self.metrics.host_tick(),
             },
         }
         if self._pp > 1:
@@ -3397,8 +3408,12 @@ class ServingEngine:
                     len(self.scheduler), self.active_slots, self.slots,
                     rows_decoded=rows)
                 self.kv_pool.note_decode_tick()
-                # Yield so the server can read sockets between iterations.
-                await asyncio.sleep(0)
+                # Yield so the server can read sockets between iterations:
+                # the front door's turn, the loop's wait at ``yield``.
+                t = time.monotonic()
+                with span("loop_yield"):
+                    await asyncio.sleep(0)
+                self.metrics.record_loop_wait("yield", time.monotonic() - t)
         except BaseException as e:
             # A device failure — or the embedder cancelling the run()
             # task directly (CancelledError is a BaseException) — must
@@ -3589,15 +3604,12 @@ class ServingEngine:
             return
         pending, self._pending_admits = self._pending_admits, []
         toks = [p.tok for p in pending]
-        hg = self.metrics.host_gap
         ready = all(_is_ready(t) for t in toks)
         with span("harvest", kind="admit", ready=ready):
-            hg.harvest_started()
             vals = (jax.device_get(toks) if ready
-                    else await self._in_executor(loop, jax.device_get, toks))
+                    else await self._in_executor(loop, _read_admits, toks))
             t = time.monotonic()
         for p, tok0 in zip(pending, vals):
-            hg.harvest_ended()
             # What the series can honestly hold off the barrier route:
             # the host's dispatch time of every chunk, plus the wait
             # from the last dispatch to this read (device time of the
@@ -3626,8 +3638,13 @@ class ServingEngine:
         # token doubled and the inter-token p99 rose by a third.
         harvest = (self._harvest_spec if tick.kind == "spec"
                    else self._harvest_decode)
-        with span("harvest", kind=tick.kind, ready=_tick_ready(tick)):
+        ready = _tick_ready(tick)
+        self.metrics.record_harvest(ready)
+        with span("harvest", kind=tick.kind, ready=ready):
             got = await self._in_executor(loop, harvest, tick)
+        t = time.monotonic()
+        self.metrics.record_loop_wait("harvest", t - tick.t_read)
+        tick.log["loop_wait_s"] = round(t - tick.t_read, 9)
         if tick.kind == "spec":
             out, commit, caps = got
             self._spec_owe_fallback = any(
@@ -3636,7 +3653,6 @@ class ServingEngine:
         else:
             nxt = got
             self._spec_owe_fallback = False
-        t = time.monotonic()
         with span("stream", active=self.active_slots):
             for i, owner in zip(tick.rows, tick.states):
                 st = self._slot_state[i]
@@ -3791,7 +3807,6 @@ class ServingEngine:
         accumulated host-side — per-position next-token logprobs for
         score, the hidden-state sum for embed."""
         job, req = st.prefill, st.request
-        hg = self.metrics.host_gap
         table_row = jnp.asarray(self._tables[slot])
         if req.kind == "score":
             targets = np.zeros((padded.shape[1],), np.int32)
@@ -3803,9 +3818,7 @@ class ServingEngine:
                 self._params, self._cache, jnp.asarray(padded),
                 jnp.int32(job.pos), jnp.int32(c), table_row,
                 jnp.asarray(targets))
-            hg.harvest_started()
             vals = np.asarray(picked)
-            hg.harvest_ended()
             if st.score_acc is None:
                 st.score_acc = []
             for j in range(c):
@@ -3815,9 +3828,7 @@ class ServingEngine:
             self._cache, vec = self._embed_chunk(
                 self._params, self._cache, jnp.asarray(padded),
                 jnp.int32(job.pos), jnp.int32(c), table_row)
-            hg.harvest_started()
             v = np.asarray(vec, dtype=np.float64)
-            hg.harvest_ended()
             st.embed_acc = (v if st.embed_acc is None
                             else st.embed_acc + v)
 
@@ -4091,11 +4102,6 @@ class ServingEngine:
         # admitting iteration adds to every row's gap between tokens).
         temp = np.float32(req.temperature)
         t0 = time.monotonic()
-        # The chunk counts in the host-gap tracker as dispatched device
-        # work: without this, admission phases would book their (device-
-        # busy) prefill time as "device idle" in the gap window between
-        # a decode harvest and the next decode dispatch.
-        hg = self.metrics.host_gap
         final = job.pos + c >= s0
         special = None
         with span("prefill", bucket=P, offset=job.pos, prompt_len=s0):
@@ -4105,7 +4111,6 @@ class ServingEngine:
                 # host-side per chunk.
                 tok = tok0 = None
                 self._scorelike_chunk(st, slot, padded, c, s0)
-                hg.tick_dispatched()
             elif final and (req.kind == "sample" or st.dfa is not None):
                 # The final chunk hands the LOGITS row back instead of a
                 # sampled token: the fork fan-out samples n first tokens
@@ -4114,8 +4119,6 @@ class ServingEngine:
                     self._params, self._cache, jnp.asarray(padded),
                     jnp.int32(job.pos), jnp.int32(c),
                     jnp.asarray(self._tables[slot]))
-                hg.tick_dispatched()
-                hg.harvest_started()
                 if req.kind == "sample":
                     self._key, sub = jax.random.split(self._key)
                     temps_n = jnp.full((req.n,), req.temperature,
@@ -4139,7 +4142,6 @@ class ServingEngine:
                     else:
                         tok0 = int(np.argmax(row))
                     tok = np.int32(tok0)
-                hg.harvest_ended()
             else:
                 # (the table row is handed over as a COPY: the upload
                 # may still be reading it when growth next writes the
@@ -4148,17 +4150,9 @@ class ServingEngine:
                         np.int32(c), self._tables[slot].copy(), temp, sub)
                 self._await_width(P, args)
                 self._cache, tok = self._prefill(*args)
-                if defer:
-                    # (a chunk nobody reads is not booked as dispatched:
-                    # the tracker pairs each dispatch with a harvest)
-                    tok0 = None
-                    if final:
-                        hg.tick_dispatched()
-                else:
-                    hg.tick_dispatched()
-                    hg.harvest_started()
-                    tok0 = int(tok)  # blocks: honest device time per chunk
-                    hg.harvest_ended()
+                # int() blocks, so chunk_s is the chunk's device time; a
+                # deferred chunk's token is read at the next harvest.
+                tok0 = None if defer else int(tok)
         chunk_s = time.monotonic() - t0
         self.metrics.record_prefill_chunk(job.pos, c)
         job.device_s += chunk_s
@@ -4365,9 +4359,9 @@ class ServingEngine:
         # device advances its own positions copy identically).
         for i in rows:
             self._lens[i] += 1
-        t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="decode", rows=rows,
-                             states=self._states_of(rows), t_dispatch=t,
+                             states=self._states_of(rows),
+                             t_dispatch=time.monotonic(),
                              tokens=harvest[0] if harvest else self._tokens,
                              advanced=set(rows))
 
@@ -4425,32 +4419,32 @@ class ServingEngine:
         for i in rows:
             self._lens[i] += 1
         self._tokens[mb] = nxt
-        t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="decode", rows=rows,
-                             states=self._states_of(rows), t_dispatch=t,
-                             tokens=nxt, advanced=set(rows),
+                             states=self._states_of(rows),
+                             t_dispatch=time.monotonic(), tokens=nxt,
+                             advanced=set(rows),
                              mb=mb, mb_start=lo)
 
     def _harvest_decode(self, tick: _InflightTick) -> np.ndarray:
         """The one D2H per plain tick (executor thread): blocks until
         the device finishes the tick, then hands its token vector to
         the loop thread for streaming."""
-        hg = self.metrics.host_gap
-        hg.harvest_started()
-        nxt = np.asarray(tick.tokens)
-        t = hg.harvest_ended()
+        t0 = time.monotonic()
+        with span("harvest_read"):
+            nxt = np.asarray(tick.tokens)
+        t = tick.t_read = time.monotonic()
         if len(nxt) > self.slots:  # tokens, then the tick's counts
             self.metrics.record_tick_counters(self._tick_counters,
                                               nxt[self.slots:])
             nxt = nxt[:self.slots]
         if self._pp > 1:
             self.metrics.bubble.record(tick.t_dispatch, t, self._pp)
-        self._tick_log.append({
+        tick.log = {
             "kind": tick.kind, "rows": len(tick.rows),
             "t_dispatch": tick.t_dispatch, "t_harvest": t,
-            "harvest_wait_s": round(hg.last_harvest_wait, 9),
-            "host_gap_s": round(hg.last_gap, 9),
-        })
+            "harvest_wait_s": round(t - t0, 9),
+        }
+        self._tick_log.append(tick.log)
         return nxt
 
     def _decode_sync(self) -> np.ndarray:
@@ -4535,22 +4529,21 @@ class ServingEngine:
             self._params, self._cache, self._tokens, drafts,
             self._temps, jnp.asarray(spec_ok), jnp.asarray(remaining),
             jnp.asarray(caps), start, tables_dev, sub)
-        t = self.metrics.host_gap.tick_dispatched()
         return _InflightTick(kind="spec", rows=tuple(decodable),
                              states=self._states_of(decodable),
-                             t_dispatch=t, out=out, commit=commit,
-                             caps=caps)
+                             t_dispatch=time.monotonic(), out=out,
+                             commit=commit, caps=caps)
 
     def _harvest_spec(self, tick: _InflightTick):
         """Spec-tick harvest (executor thread): the one D2H reads the
         committed-token matrix and commit counts, then the position
         watermarks advance by each row's ACTUAL commit — the part a
         plain tick can do at dispatch and a spec tick cannot."""
-        hg = self.metrics.host_gap
-        hg.harvest_started()
-        out = np.asarray(tick.out)
-        commit = np.asarray(tick.commit)
-        t = hg.harvest_ended()
+        t0 = time.monotonic()
+        with span("harvest_read"):
+            out = np.asarray(tick.out)
+            commit = np.asarray(tick.commit)
+        t = tick.t_read = time.monotonic()
         if self._pp > 1:
             self.metrics.bubble.record(tick.t_dispatch, t, self._pp)
         for i in tick.rows:
@@ -4558,12 +4551,12 @@ class ServingEngine:
         # Commits are variable-width: the cached device positions
         # no longer match _lens.
         self._positions_dirty = True
-        self._tick_log.append({
+        tick.log = {
             "kind": tick.kind, "rows": len(tick.rows),
             "t_dispatch": tick.t_dispatch, "t_harvest": t,
-            "harvest_wait_s": round(hg.last_harvest_wait, 9),
-            "host_gap_s": round(hg.last_gap, 9),
-        })
+            "harvest_wait_s": round(t - t0, 9),
+        }
+        self._tick_log.append(tick.log)
         return out, commit, tick.caps
 
     def _spec_sync(self):

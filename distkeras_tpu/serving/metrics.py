@@ -37,7 +37,7 @@ from distkeras_tpu.telemetry.registry import (
 )
 from distkeras_tpu.tracing import MetricStream
 
-__all__ = ["BARRIER_REASONS", "BubbleTracker", "HostGapTracker",
+__all__ = ["BARRIER_REASONS", "LOOP_WAITS", "BubbleTracker",
            "ServingMetrics", "percentile"]
 
 # Why the engine drained its decode pipeline before a tick: the label of
@@ -47,6 +47,12 @@ BARRIER_REASONS = (
     "admission", "prefill_chunk", "paged_growth", "cancel_expire",
     "param_swap", "kv_transfer", "spec", "idle", "depth0", "shutdown",
 )
+
+# Where the engine task waited for the event loop, the label of
+# ``serving_loop_wait_seconds_total``: ``harvest``, from a tick's read
+# returning on the executor thread to the task resuming; ``yield``, the
+# end-of-iteration ``sleep(0)`` in which the front door takes its turn.
+LOOP_WAITS = ("harvest", "yield")
 
 # Decode ticks and inter-token gaps sit well under the default buckets'
 # upper range; keep a finer low end for them.
@@ -63,114 +69,9 @@ def percentile(values: Iterable[float], q: float) -> float:
     return _percentile(values, q)
 
 
-class HostGapTracker:
-    """Per-tick device-idle accounting for the decode pipeline.
-
-    Three observable instants exist per tick: **dispatch** (the jit call
-    returned — work is queued on the device), **harvest start** (the
-    host began the tick's one D2H read) and **harvest end** (the read
-    returned — the device has certainly finished the tick). From those:
-
-    - ``host gap``: how long the device queue sat EMPTY before a
-      dispatch. It is measurable exactly when the previous tick was
-      already harvested (the queue has been empty since at latest that
-      harvest's end): ``gap = max(0, t_dispatch - t_prev_harvest_end)``.
-      When a tick is still in flight at dispatch time (the pipelined
-      steady state) the queue was never empty and the gap is 0 by
-      construction. At ``pipeline_depth=0`` — harvest immediately after
-      every dispatch — the gap is the full serialized host window: every
-      microsecond of streaming/admission/socket work the device waited
-      through. (When the harvest returned instantly the device had
-      finished somewhere before harvest start, so the measured gap is a
-      slight *under*-estimate; it can never over-report idleness.)
-    - ``device idle ratio``: windowed ``sum(gaps) / sum(dispatch
-      intervals)`` — the fraction of wall time between ticks the device
-      was provably idle. The pipelined engine drives this toward 0.
-
-    ``clock`` is injectable (a fake clock makes the accounting exactly
-    testable); the optional histogram/gauge mirror the window into the
-    registry (``serving_host_gap_seconds`` /
-    ``serving_device_idle_ratio``)."""
-
-    def __init__(self, histogram=None, idle_gauge=None,
-                 clock=time.monotonic, window: int = 4096):
-        self._clock = clock
-        self._hist = histogram
-        self._gauge = idle_gauge
-        self._pending = 0           # dispatched, not yet harvested
-        self._last_dispatch: float | None = None
-        self._last_harvest_end: float | None = None
-        self._harvest_start: float | None = None
-        self.last_gap = 0.0
-        self.last_harvest_wait = 0.0
-        self.gaps = collections.deque(maxlen=window)
-        self.intervals = collections.deque(maxlen=window)
-
-    def tick_dispatched(self, t: float | None = None) -> float:
-        t = self._clock() if t is None else t
-        if self._pending == 0 and self._last_harvest_end is not None:
-            gap = max(0.0, t - self._last_harvest_end)
-        else:
-            # Either the first tick ever, or a tick was still in
-            # flight: the device queue was never observed empty.
-            gap = 0.0
-        self.last_gap = gap
-        self.gaps.append(gap)
-        if self._hist is not None:
-            self._hist.observe(gap)
-        if self._last_dispatch is not None:
-            self.intervals.append(max(0.0, t - self._last_dispatch))
-        self._last_dispatch = t
-        self._pending += 1
-        return t
-
-    def harvest_started(self, t: float | None = None) -> float:
-        self._harvest_start = self._clock() if t is None else t
-        return self._harvest_start
-
-    def harvest_ended(self, t: float | None = None) -> float:
-        t = self._clock() if t is None else t
-        self._pending = max(0, self._pending - 1)
-        self._last_harvest_end = t
-        self.last_harvest_wait = (max(0.0, t - self._harvest_start)
-                                  if self._harvest_start is not None
-                                  else 0.0)
-        self._harvest_start = None
-        if self._gauge is not None:
-            self._gauge.set(self.idle_ratio or 0.0)
-        return t
-
-    @property
-    def idle_ratio(self) -> float | None:
-        """Windowed device-idle fraction; None until two ticks ran."""
-        total = sum(self.intervals)
-        if total <= 0:
-            return None
-        # gaps has one more entry than intervals (the first dispatch
-        # has no interval); drop the first gap for a matched window.
-        gaps = list(self.gaps)[-len(self.intervals):]
-        return min(1.0, sum(gaps) / total)
-
-    @property
-    def gap_p50(self) -> float | None:
-        return percentile(self.gaps, 50) if self.gaps else None
-
-    def summary(self) -> dict[str, float]:
-        out: dict[str, float] = {}
-        if self.gaps:
-            out["host_gap_p50_s"] = percentile(self.gaps, 50)
-            out["host_gap_p99_s"] = percentile(self.gaps, 99)
-            out["host_gap_mean_s"] = sum(self.gaps) / len(self.gaps)
-        ratio = self.idle_ratio
-        if ratio is not None:
-            out["device_idle_ratio"] = ratio
-        return out
-
-
 class BubbleTracker:
-    """Pipeline-stage idle ("bubble") accounting — the ``HostGapTracker``
-    family's pp sibling, built from the SAME two host instants every
-    tick already stamps (dispatch, harvest end).
+    """Pipeline-stage idle ("bubble") accounting, built from the two
+    host instants every tick already stamps (dispatch, harvest end).
 
     A decode tick on a ``pp=S`` mesh occupies each stage for roughly
     ``span / S`` of its dispatch→harvest span (the micro-batch flows
@@ -186,8 +87,7 @@ class BubbleTracker:
     idle at S=2). ``pipeline_depth>=S`` overlaps micro-batches until
     wall ≈ sum(spans)/S and the bubble approaches 0 — the
     ramp-up/drain-only residue. Host-side stalls inside a span bias the
-    estimate toward LESS reported idleness, never more (same
-    conservative direction as the host-gap tracker), and the fraction
+    estimate toward LESS reported idleness, never more, and the fraction
     is clamped to [0, 1]."""
 
     def __init__(self, gauge=None, window: int = 4096):
@@ -345,21 +245,28 @@ class ServingMetrics:
                 "serving_request_latency_seconds",
                 help="submit-to-done latency", buckets=_LATENCY_BUCKETS),
         }
-        # Decode-pipeline accounting: the per-tick host gap (device
-        # provably idle before a dispatch) and the windowed device-idle
-        # fraction — what the overlapped pipeline exists to drive to 0.
-        self.host_gap = HostGapTracker(
-            histogram=reg.histogram(
-                "serving_host_gap_seconds",
-                help="host-side gap the device sat idle before a decode "
-                     "tick dispatch (pipeline_depth=0 pays this every "
-                     "tick; depth 1 hides it behind the in-flight tick)",
-                buckets=_LATENCY_BUCKETS),
-            idle_gauge=reg.gauge(
-                "serving_device_idle_ratio",
-                help="windowed fraction of inter-tick wall time the "
-                     "device was provably idle (host gap / dispatch "
-                     "interval)"))
+        # Who paces a tick, and who holds the event loop: tick harvests
+        # by whether the device had already finished the tick when the
+        # harvest began (ready=1: the host paces), and the engine task's
+        # waits for the loop (LOOP_WAITS).
+        self._c_harvests = {
+            ready: reg.counter(
+                "serving_harvests_total",
+                help="decode and speculative tick harvests: ready=1 "
+                     "when the device had already finished the tick as "
+                     "the harvest began (the host paced it), ready=0 "
+                     "when the harvest waited for the device",
+                ready=str(int(ready)))
+            for ready in (False, True)}
+        self._c_loop_wait = {
+            at: reg.counter(
+                "serving_loop_wait_seconds_total",
+                help="seconds the engine task waited for the event loop: "
+                     "at=harvest from a tick's read returning on the "
+                     "executor thread to the task resuming, at=yield the "
+                     "end-of-iteration turn the front door runs in",
+                at=at)
+            for at in LOOP_WAITS}
         # Pipeline-parallel stage-idle accounting: the fraction of
         # stage-time provably wasted to pipeline bubbles (1 - 1/pp at
         # depth<=1; driven toward 0 by depth>=pp micro-batch overlap).
@@ -860,6 +767,22 @@ class ServingMetrics:
         """One pipeline barrier that drained a tick (``BARRIER_REASONS``)."""
         self._c_barriers[reason].inc()
 
+    def record_harvest(self, ready: bool) -> None:
+        """One tick harvest, by whether its tick was done as it began."""
+        self._c_harvests[ready].inc()
+
+    def record_loop_wait(self, at: str, seconds: float) -> None:
+        """``seconds`` the engine task waited for the loop (``LOOP_WAITS``)."""
+        self._c_loop_wait[at].inc(seconds)
+
+    def host_tick(self) -> dict[str, float]:
+        """The two families above as totals, for healthz and debugz."""
+        return {"harvests": float(sum(c.value for c in
+                                      self._c_harvests.values())),
+                "harvests_ready": float(self._c_harvests[True].value),
+                **{f"loop_wait_{at}_s": float(self._c_loop_wait[at].value)
+                   for at in LOOP_WAITS}}
+
     def record_paged_growth(self, blocks: int, drained: bool) -> None:
         """``blocks`` tail blocks chained by one pass of paged growth."""
         self._c_growth[drained].inc(blocks)
@@ -928,7 +851,6 @@ class ServingMetrics:
                 out[f"{name}_p95_s"] = percentile(xs, 95)
                 out[f"{name}_p99_s"] = percentile(xs, 99)
                 out[f"{name}_mean_s"] = sum(xs) / len(xs)
-        out.update(self.host_gap.summary())
         out.update(self.bubble.summary())
         if self.prefill_chunks:
             out["prefill_chunks_mean"] = (

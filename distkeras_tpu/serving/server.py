@@ -45,6 +45,7 @@ from distkeras_tpu.serving.engine import ServingEngine
 from distkeras_tpu.serving.kv_transfer import KVTransferError, fetch_blocks
 from distkeras_tpu.serving.scheduler import Request, ServingError
 from distkeras_tpu.telemetry.request_trace import sanitize_trace_id
+from distkeras_tpu.telemetry.spans import span
 
 __all__ = ["ServingServer"]
 
@@ -262,31 +263,44 @@ class ServingServer:
                 if not line:
                     break
                 spec: dict = {}
+                req = None
                 try:
-                    spec = json.loads(line)
-                    if isinstance(spec, dict) and spec.get("cmd") == "hello":
-                        # Protocol negotiation: a bin1-capable client's
-                        # upgrade offer. Unknown peers never send it, so
-                        # the JSONL path below stays byte-for-byte.
-                        proto = (wire.PROTO_JSONL
-                                 if self.wire_mode == "jsonl"
-                                 else wire.choose_proto(spec.get("proto")))
-                        await self._send(writer, {"hello": {
-                            "proto": proto,
-                            "fastwire": wire.native_available()}})
-                        if proto == wire.PROTO_BIN1:
-                            await self._handle_bin1(reader, writer)
-                            return  # the frame loop owned the connection
-                        continue
-                    if isinstance(spec, dict) and "cmd" in spec:
-                        await self._send(writer, await self._control(spec))
-                        continue
-                    kv_info = None
-                    if isinstance(spec, dict) and ("kv_from" in spec
-                                                   or "kv_wait" in spec):
-                        kv_info = await self._kv_prepare(spec)
-                    req = self._submit_spec(spec)
-                    self._note_migration(req, kv_info)
+                    # A plain request is parsed and submitted in one span;
+                    # the verbs and a KV pull await, and stay outside it.
+                    with span("front_read"):
+                        spec = json.loads(line)
+                        if isinstance(spec, dict) and not (
+                                spec.keys() & {"cmd", "kv_from", "kv_wait"}):
+                            req = self._submit_spec(spec)
+                    if req is None:  # a verb, a KV pull or a bad line
+                        if isinstance(spec, dict) \
+                                and spec.get("cmd") == "hello":
+                            # Protocol negotiation: a bin1-capable
+                            # client's upgrade offer. Unknown peers never
+                            # send it, so the JSONL path below stays
+                            # byte-for-byte.
+                            proto = (wire.PROTO_JSONL
+                                     if self.wire_mode == "jsonl"
+                                     else wire.choose_proto(
+                                         spec.get("proto")))
+                            await self._send(writer, {"hello": {
+                                "proto": proto,
+                                "fastwire": wire.native_available()}})
+                            if proto == wire.PROTO_BIN1:
+                                await self._handle_bin1(reader, writer)
+                                return  # the frame loop owned it
+                            continue
+                        if isinstance(spec, dict) and "cmd" in spec:
+                            await self._send(writer,
+                                             await self._control(spec))
+                            continue
+                        kv_info = None
+                        if isinstance(spec, dict) and ("kv_from" in spec
+                                                       or "kv_wait" in spec):
+                            kv_info = await self._kv_prepare(spec)
+                        with span("front_read"):
+                            req = self._submit_spec(spec)
+                        self._note_migration(req, kv_info)
                 except ServingError as e:
                     await self._send(writer, self._error(e, spec))
                     continue
@@ -343,102 +357,106 @@ class ServingServer:
                 data = await reader.read(2 ** 18)
                 if not data:
                     break
-                try:
-                    frames = decoder.feed(data)
-                except wire.WireError as e:
-                    sink.send_error(0, {"error": str(e),
-                                        "code": "bad_request"})
-                    break
-                batch: list[tuple[int, dict]] = []
-                precancelled: set[int] = set()
-                for ftype, sid, payload in frames:
-                    if ftype == wire.T_REQ:
-                        try:
-                            batch.append((sid, wire.decode_request(payload)))
-                        except wire.WireError as e:
-                            sink.send_error(sid, {"error": str(e),
-                                                  "code": "bad_request"})
-                    elif ftype == wire.T_CANCEL:
-                        req = live.get(sid)
-                        if req is not None:
-                            req.cancel()
-                        elif sid in kv_wait:
-                            # The REQ is mid-KV-pull in a deferred
-                            # admission task — remember the cancel for
-                            # when it submits.
-                            kv_cancelled.add(sid)
-                        else:
-                            # The REQ may sit in THIS read's batch,
-                            # not yet submitted — remember, or a
-                            # same-tick cancel is silently lost and the
-                            # slot decodes for nobody.
-                            precancelled.add(sid)
-                    elif ftype == wire.T_CTRL:
-                        # As a task: a slow verb (reload waits for the
-                        # engine's quiet moment, up to its timeout) must
-                        # not stall every multiplexed stream on this
-                        # connection.
-                        ctrl = asyncio.get_running_loop().create_task(
-                            self._ctrl_bin1(sid, payload, sink,
-                                            ctrls, telem))
-                        ctrls.add(ctrl)
-                        ctrl.add_done_callback(ctrls.discard)
-                    elif ftype == wire.T_KVBLK:
-                        # A pushed KV block chain: adopting it IS the
-                        # kv_import verb. Multi-frame chains reassemble
-                        # through a per-stream FrameJoiner (a bare KVX1
-                        # payload passes straight through); the adopt
-                        # runs as a task — it waits for the engine
-                        # loop's next iteration.
-                        from distkeras_tpu.serving.kv_transfer import (
-                            FrameJoiner,
-                            KVTransferError,
-                        )
+                # One read's frames, validated and admitted: no await
+                # in here (a KV pull or a verb is a task of its own).
+                with span("front_read", bytes=len(data)):
+                    try:
+                        frames = decoder.feed(data)
+                    except wire.WireError as e:
+                        sink.send_error(0, {"error": str(e),
+                                            "code": "bad_request"})
+                        break
+                    batch: list[tuple[int, dict]] = []
+                    precancelled: set[int] = set()
+                    for ftype, sid, payload in frames:
+                        if ftype == wire.T_REQ:
+                            try:
+                                batch.append(
+                                    (sid, wire.decode_request(payload)))
+                            except wire.WireError as e:
+                                sink.send_error(sid, {"error": str(e),
+                                                      "code": "bad_request"})
+                        elif ftype == wire.T_CANCEL:
+                            req = live.get(sid)
+                            if req is not None:
+                                req.cancel()
+                            elif sid in kv_wait:
+                                # The REQ is mid-KV-pull in a deferred
+                                # admission task — remember the cancel for
+                                # when it submits.
+                                kv_cancelled.add(sid)
+                            else:
+                                # The REQ may sit in THIS read's batch,
+                                # not yet submitted — remember, or a
+                                # same-tick cancel is silently lost and the
+                                # slot decodes for nobody.
+                                precancelled.add(sid)
+                        elif ftype == wire.T_CTRL:
+                            # As a task: a slow verb (reload waits for the
+                            # engine's quiet moment, up to its timeout) must
+                            # not stall every multiplexed stream on this
+                            # connection.
+                            ctrl = asyncio.get_running_loop().create_task(
+                                self._ctrl_bin1(sid, payload, sink,
+                                                ctrls, telem))
+                            ctrls.add(ctrl)
+                            ctrl.add_done_callback(ctrls.discard)
+                        elif ftype == wire.T_KVBLK:
+                            # A pushed KV block chain: adopting it IS the
+                            # kv_import verb. Multi-frame chains reassemble
+                            # through a per-stream FrameJoiner (a bare KVX1
+                            # payload passes straight through); the adopt
+                            # runs as a task — it waits for the engine
+                            # loop's next iteration.
+                            from distkeras_tpu.serving.kv_transfer import (
+                                FrameJoiner,
+                                KVTransferError,
+                            )
 
-                        try:
-                            whole = kv_joiners.setdefault(
-                                sid, FrameJoiner()).feed(payload)
-                        except KVTransferError as e:
+                            try:
+                                whole = kv_joiners.setdefault(
+                                    sid, FrameJoiner()).feed(payload)
+                            except KVTransferError as e:
+                                kv_joiners.pop(sid, None)
+                                sink.send_error(sid, {
+                                    "error": str(e), "code": e.code})
+                                continue
+                            if whole is None:
+                                continue  # more chunk frames owed
                             kv_joiners.pop(sid, None)
+                            ctrl = asyncio.get_running_loop().create_task(
+                                self._kv_import_frame(sid, whole, sink))
+                            ctrls.add(ctrl)
+                            ctrl.add_done_callback(ctrls.discard)
+                        else:
                             sink.send_error(sid, {
-                                "error": str(e), "code": e.code})
-                            continue
-                        if whole is None:
-                            continue  # more chunk frames owed
-                        kv_joiners.pop(sid, None)
-                        ctrl = asyncio.get_running_loop().create_task(
-                            self._kv_import_frame(sid, whole, sink))
-                        ctrls.add(ctrl)
-                        ctrl.add_done_callback(ctrls.discard)
-                    else:
-                        sink.send_error(sid, {
-                            "error": f"unexpected frame type {ftype}",
-                            "code": "bad_request"})
-                if batch:
-                    # Disaggregated handoff: specs naming a KV source
-                    # pull + adopt their blocks BEFORE admission — in a
-                    # DEFERRED task (all of one read batch's pulls run
-                    # concurrently there), so a slow or dead peer can
-                    # never head-of-line-block this read loop: other
-                    # streams' REQ/CANCEL frames keep processing while
-                    # the pull waits out its timeout. Plain specs admit
-                    # inline through ONE submit_many as before.
-                    plain = [(sid, spec) for sid, spec in batch
-                             if "kv_from" not in spec
-                             and "kv_wait" not in spec]
-                    kv_batch = [(sid, spec) for sid, spec in batch
-                                if "kv_from" in spec
-                                or "kv_wait" in spec]
-                    self._admit_bin1(plain, precancelled, {},
-                                     live, pumps, sink)
-                    if kv_batch:
-                        kv_wait.update(sid for sid, _ in kv_batch)
-                        task = asyncio.get_running_loop().create_task(
-                            self._kv_admit_bin1(kv_batch, kv_wait,
-                                                kv_cancelled, live,
-                                                pumps, sink))
-                        ctrls.add(task)
-                        task.add_done_callback(ctrls.discard)
+                                "error": f"unexpected frame type {ftype}",
+                                "code": "bad_request"})
+                    if batch:
+                        # Disaggregated handoff: specs naming a KV source
+                        # pull + adopt their blocks BEFORE admission — in a
+                        # DEFERRED task (all of one read batch's pulls run
+                        # concurrently there), so a slow or dead peer can
+                        # never head-of-line-block this read loop: other
+                        # streams' REQ/CANCEL frames keep processing while
+                        # the pull waits out its timeout. Plain specs admit
+                        # inline through ONE submit_many as before.
+                        plain = [(sid, spec) for sid, spec in batch
+                                 if "kv_from" not in spec
+                                 and "kv_wait" not in spec]
+                        kv_batch = [(sid, spec) for sid, spec in batch
+                                    if "kv_from" in spec
+                                    or "kv_wait" in spec]
+                        self._admit_bin1(plain, precancelled, {},
+                                         live, pumps, sink)
+                        if kv_batch:
+                            kv_wait.update(sid for sid, _ in kv_batch)
+                            task = asyncio.get_running_loop().create_task(
+                                self._kv_admit_bin1(kv_batch, kv_wait,
+                                                    kv_cancelled, live,
+                                                    pumps, sink))
+                            ctrls.add(task)
+                            task.add_done_callback(ctrls.discard)
         finally:
             # Client gone (EOF, reset, or corrupt framing): release every
             # in-flight slot instead of decoding for nobody.
@@ -846,13 +864,11 @@ class ServingServer:
                 # counters — the "is one tenant starving the fleet"
                 # page (refreshes the labeled tenant gauges too).
                 "tenants": engine.tenant_snapshot(),
-                # Decode-pipeline vitals: configured depth + the
-                # windowed host-gap view (what depth 1 is hiding).
+                # Decode-pipeline vitals: configured depth, who paces
+                # the ticks and the engine's waits for the event loop.
                 "pipeline": {
                     "depth": engine.pipeline_depth,
-                    "host_gap_p50_s": engine.metrics.host_gap.gap_p50,
-                    "device_idle_ratio":
-                        engine.metrics.host_gap.idle_ratio,
+                    **engine.metrics.host_tick(),
                 },
             }
             if engine._pp > 1:
@@ -1067,5 +1083,6 @@ class ServingServer:
 
     @staticmethod
     async def _send(writer: asyncio.StreamWriter, obj: dict) -> None:
-        writer.write((json.dumps(obj) + "\n").encode())
+        with span("front_write"):
+            writer.write((json.dumps(obj) + "\n").encode())
         await writer.drain()

@@ -80,6 +80,8 @@ import subprocess
 
 import numpy as np
 
+from distkeras_tpu.telemetry.spans import span
+
 __all__ = [
     "PROTO_BIN1",
     "PROTO_JSONL",
@@ -728,7 +730,8 @@ class FrameSink:
                 self.closed = True
                 self._writer.close()
                 return
-            self._writer.write(data)
+            with span("front_write", bytes=len(data)):
+                self._writer.write(data)
         except (ConnectionResetError, BrokenPipeError, OSError,
                 RuntimeError):
             self.closed = True

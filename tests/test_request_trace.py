@@ -288,6 +288,43 @@ def test_engine_crash_dumps_flight_recorder(lm, rng, tmp_path):
     assert "engine_start" in kinds and "crash" in kinds
 
 
+def test_killed_local_replica_is_alive_until_its_last_words_are_written(
+        lm, rng, tmp_path):
+    """A supervisor polls ``alive`` while a chaos kill runs: the replica
+    may read dead only once its engine task has ended, and that task's
+    failure path writes the flight-recorder dump first. Reading dead
+    earlier is the race in which the supervisor logs "no dump found"."""
+    model, variables = lm
+    path = tmp_path / "flight-k.json"
+    replica = LocalReplica(lambda: ServingEngine(
+        model, variables, slots=1, max_queue=4,
+        flight_recorder=FlightRecorder(32, dump_path=str(path),
+                                       source="k")))
+
+    async def go():
+        await replica.start()
+        req = replica.engine.submit(_prompt(rng, 6), 20)
+        async for _ in req.tokens():
+            break  # mid-stream
+        seen = []
+
+        async def poll():
+            while True:
+                seen.append((replica.alive, path.exists()))
+                await asyncio.sleep(0)
+
+        poller = asyncio.create_task(poll())
+        await asyncio.sleep(0)
+        await replica.kill()
+        await asyncio.sleep(0)
+        poller.cancel()
+        return seen
+
+    seen = asyncio.run(go())
+    assert seen[0] == (True, False) and seen[-1] == (False, True)
+    assert all(dumped for alive, dumped in seen if not alive)
+
+
 # -- cluster: trace continuity across retry -----------------------------------
 
 def _traced_factory(lm_pair, recorders, dump_dir, **engine_kwargs):
